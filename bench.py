@@ -1,11 +1,11 @@
 """Headline benchmark: 64-node federated MNIST, time to 98% test accuracy.
 
-BASELINE.md north star: 64 federated MNIST nodes converge to >=98% test
+North star (BASELINE.json): 64 federated MNIST nodes converge to >=98% test
 accuracy in <60 s wall-clock with zero gRPC traffic (weights over ICI).
 The reference publishes no numbers (SURVEY §6); the target is the driver's
 BASELINE.json bound, so ``vs_baseline = 60 / measured_seconds`` (>1 beats it).
 
-Honesty notes (VERDICT r1 #2):
+Honesty notes:
 - the JSON records data provenance (``data``: "idx" = real MNIST files,
   "synthetic-hard" = the Gaussian-mixture stand-in);
 - the synthetic task uses 8 prototype modes per class at prototype scale
@@ -15,8 +15,8 @@ Honesty notes (VERDICT r1 #2):
 - ``mfu`` is model-FLOPs-utilization of the steady-state round (compiled
   XLA FLOPs / wall-clock / chip peak), null off-TPU.
 
-Runs the SPMD federation on whatever devices are available (the real TPU
-chip under the driver; the virtual CPU mesh under tests). One compile
+Runs the SPMD federation on whatever devices ``jax.devices()`` shows (the
+TPU chips on a chip machine; the virtual CPU mesh under tests). One compile
 warm-up phase runs first and is excluded — state is fully reset afterwards.
 
 Prints exactly ONE JSON line on stdout; progress goes to stderr.
@@ -48,6 +48,9 @@ def log(msg: str) -> None:
 
 
 def main() -> None:
+    from p2pfl_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from p2pfl_tpu.learning.dataset import FederatedDataset
     from p2pfl_tpu.management.profiling import force_execution, mfu
     from p2pfl_tpu.models import mlp
@@ -70,8 +73,7 @@ def main() -> None:
 
     # compile warm-up, then reset state in place (same mesh → same
     # executables). Both fused variants (eval curve + steady state) and the
-    # single-round program are warmed; a D2H fetch is the only thing that
-    # truly forces execution on some remote-attached platforms.
+    # single-round program are warmed; each warm call ends on a D2H fetch.
     t0 = time.monotonic()
     # eval chunk twice: round-1 (fresh) and rounds>=2 (evolved) input
     # layouts compile separately — one warm call would leave the second

@@ -1058,6 +1058,9 @@ ALL_SECTIONS = (
 
 
 def main() -> int:
+    from p2pfl_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     smoke = "--smoke" in sys.argv
     out_path = "BENCH_ASYNC.json"
     if "--out" in sys.argv:

@@ -564,6 +564,9 @@ def bench_dcn(rounds: int = 2) -> dict:
 
 
 def main() -> int:
+    from p2pfl_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="small run + invariant asserts (CI)")
     ap.add_argument("--out", default="BENCH_GOSSIP.json")

@@ -209,6 +209,9 @@ def smoke() -> dict:
 
 
 def main() -> int:
+    from p2pfl_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true", help="tiny run + asserts (CI)")
     ap.add_argument("--no-autotune", action="store_true", help="defaults table only")

@@ -8,9 +8,8 @@ CI runs this as the `ci.yml` telemetry step:
 
 The overhead assertion here is a SMOKE bound (default 20%, plus an
 absolute floor for protocol-tick quantization) — shared-runner wall-clock
-noise swamps the real figure; the honest ≤5% measurement lives in
-bench_suite config1's `telemetry` split (BENCH_SUITE.json), averaged over
-more rounds on a quiet machine. This step exists to catch a regression
+noise swamps the real figure; the ≤5% measurement is bench_suite
+config1's `telemetry` split, averaged over more rounds on a quiet machine. This step exists to catch a regression
 that makes the recorder *expensive*, not to re-measure the budget.
 """
 
@@ -54,6 +53,9 @@ def run_federation(rounds: int, telemetry_on: bool) -> float:
 
 
 def main() -> int:
+    from p2pfl_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="/tmp/telemetry-smoke", help="trace/report output dir")
     ap.add_argument("--rounds", type=int, default=4)

@@ -35,13 +35,8 @@ from p2pfl_tpu.analysis.findings import Finding
 _JIT_NAMES = {"jit", "jax.jit", "pjit", "jax.pjit", "pjit.pjit"}
 _PARTIAL_NAMES = {"partial", "functools.partial"}
 #: call wrappers whose first argument is traced as a device program —
-#: pallas kernels and shard_map bodies (incl. the repo's compat shims)
-_KERNEL_WRAPPER_LASTS = {
-    "pallas_call",
-    "shard_map",
-    "shard_map_compat",
-    "shard_map_unchecked",
-}
+#: pallas kernels and shard_map bodies
+_KERNEL_WRAPPER_LASTS = {"pallas_call", "shard_map"}
 _HOST_SYNC_CALLS = {
     "np.asarray",
     "np.array",
@@ -64,7 +59,7 @@ def _is_jit_decorator(dec: ast.AST) -> bool:
             inner = dotted_name(dec.args[0])
             if inner in _JIT_NAMES:
                 return True
-            # @partial(shard_map, mesh=…) / @partial(shard_map_compat, …):
+            # @partial(shard_map, mesh=…):
             # the decorated def IS the per-shard device program
             inner_last = inner.rsplit(".", 1)[-1] if inner else None
             if inner_last in _KERNEL_WRAPPER_LASTS:

@@ -114,6 +114,9 @@ def main(argv=None) -> int:
             if args.name not in examples:
                 print(f"unknown experiment {args.name!r}; try: {', '.join(sorted(examples))}")
                 return 1
+            from p2pfl_tpu.compile_cache import configure_compile_cache
+
+            configure_compile_cache()
             mod = importlib.import_module(f"p2pfl_tpu.examples.{args.name}")
             mod.main(args.extra)
             return 0
@@ -121,8 +124,15 @@ def main(argv=None) -> int:
         return 1
     if args.command == "bench":
         import runpy
+        from pathlib import Path
 
-        runpy.run_path("bench.py", run_name="__main__")
+        # bench.py sits beside the package in a checkout (it is not
+        # installed with it); resolve it from there, not from the cwd
+        bench = Path(__file__).resolve().parent.parent / "bench.py"
+        if not bench.is_file():
+            print(f"bench: {bench} not found (run from a source checkout)")
+            return 1
+        runpy.run_path(str(bench), run_name="__main__")
         return 0
     parser.print_help()
     return 1

@@ -7,7 +7,7 @@ seconds; every second tick it evicts neighbors whose last beat is older than
 discovers every other node as a *non-direct* neighbor within roughly one
 heartbeat period (reference ``grpc_neighbors.py:34-55``).
 
-Three hardenings over the reference:
+Four hardenings over the reference:
 
 - **Origin-time validation**: beats carry the origin's wall clock, and a
   beat whose origin stamp is older than ``HEARTBEAT_TIMEOUT`` is rejected
@@ -25,6 +25,16 @@ Three hardenings over the reference:
   reachability is useless to the overlay, and inbound beats would
   otherwise keep the unreachable peer a member forever. (The reference
   evicted on the FIRST failed send, losing the message with it.)
+- **Local-pause discount**: silence only counts while this process was
+  awake to hear. Whatever the heartbeater's own ``HEARTBEAT_PERIOD`` sleep
+  overruns by — the whole process was frozen (VM pause, a C call holding
+  the GIL, a long GC) — is added to every neighbor's silence clock before
+  the sweep; an overrun of more than a period counts as ``local_pause``.
+  Without it the first heartbeater to wake after a freeze longer than
+  ``HEARTBEAT_TIMEOUT`` evicts every peer for beats that could not have
+  been delivered; with N in-process nodes the peers were frozen too. A
+  dead peer's eviction is delayed by at most the length of the freeze
+  (only the sleep is measured, so a slow broadcast defers nothing).
 """
 
 from __future__ import annotations
@@ -130,5 +140,18 @@ class Heartbeater:
                     )
                     logger.log_comm_metric(self.self_addr, "breaker_unreachable_evict")
                     self._protocol.neighbors.evict(addr, quarantine=True)
-            if self._stop.wait(timeout=Settings.HEARTBEAT_PERIOD):
+            period, asleep = Settings.HEARTBEAT_PERIOD, time.monotonic()
+            if self._stop.wait(timeout=period):
                 return
+            # the process was not listening while this sleep overran, so
+            # that much of every neighbor's silence is its own
+            overrun = time.monotonic() - asleep - period
+            if overrun > 0:
+                self._protocol.neighbors.discount_silence(overrun)
+            if overrun > period:  # a whole tick missed
+                logger.log_comm_metric(self.self_addr, "local_pause")
+                logger.debug(
+                    self.self_addr,
+                    f"Heartbeater woke {overrun:.2f}s late — that much "
+                    "silence discounted for every neighbor",
+                )

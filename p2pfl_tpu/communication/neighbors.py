@@ -131,6 +131,15 @@ class Neighbors:
                 return
             info.last_beat = time.monotonic() if t is None else t
 
+    def discount_silence(self, seconds: float) -> None:
+        """Move every neighbor's silence clock forward by ``seconds`` — the
+        heartbeater calls this after it found its own process was frozen
+        that long (no beat could have been recorded meanwhile)."""
+        now = time.monotonic()
+        with self._lock:
+            for info in self._neis.values():
+                info.last_beat = min(now, info.last_beat + seconds)
+
     def evict_stale(self, timeout: float, only: Optional[set] = None) -> list[str]:
         """Drop neighbors whose last beat is older than ``timeout`` seconds.
 

@@ -1,9 +1,12 @@
 """Native codec bindings (ctypes) with transparent numpy fallback.
 
-Loads ``libp2tw.so`` (built from ``codec.cpp`` by ``build.sh``); if the
-library is missing and a compiler is available it is built once on first
-import. Every entry point has a numpy fallback so the framework never
-*requires* the native layer — it's the fast path, not a dependency.
+Loads ``libp2tw.so``, built from ``codec.cpp`` on first import when a
+compiler is available. The library is git-ignored and travels with a
+copied tree, so it is only loaded when the source hash recorded beside it
+(``libp2tw.so.sha256``) matches the present ``codec.cpp`` — a stale or
+foreign binary is rebuilt, never trusted. Every entry point has a numpy
+fallback so the framework never *requires* the native layer — it's the
+fast path, not a dependency; :data:`NATIVE` says which one is in use.
 
 API:
 - :func:`quantize`   — fp32 array → (int8 array, scale)
@@ -15,6 +18,7 @@ API:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -22,9 +26,29 @@ from typing import Optional
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_DIR, "codec.cpp")
 _SO = os.path.join(_DIR, "libp2tw.so")
+_STAMP = _SO + ".sha256"  # hex sha256 of the codec.cpp the .so was built from
 
 _lib: Optional[ctypes.CDLL] = None
+
+
+def _src_hash() -> Optional[str]:
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()
+    except OSError:
+        return None
+
+
+def _fresh() -> bool:
+    """The .so exists and was built from the present ``codec.cpp``."""
+    try:
+        with open(_STAMP) as f:
+            stamp = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(_SO) and stamp == _src_hash()
 
 
 def _try_build() -> None:
@@ -36,8 +60,7 @@ def _try_build() -> None:
     (atomic on POSIX), and an ``fcntl`` lockfile serializes builders: the
     loser of the race wakes up, sees the finished .so, and skips its build.
     """
-    src = os.path.join(_DIR, "codec.cpp")
-    if not os.path.exists(src):
+    if not os.path.exists(_SRC):
         return
     try:
         import fcntl
@@ -49,14 +72,14 @@ def _try_build() -> None:
     tmp = f"{_SO}.tmp.{os.getpid()}"
     try:
         if fcntl is None:
-            _compile(src, tmp)
+            _compile(tmp)
             return
         with open(f"{_SO}.lock", "w") as lockf:
             fcntl.flock(lockf, fcntl.LOCK_EX)
             try:
-                if os.path.exists(_SO):
+                if _fresh():
                     return  # another process built it while we waited
-                _compile(src, tmp)
+                _compile(tmp)
             finally:
                 fcntl.flock(lockf, fcntl.LOCK_UN)
     except (OSError, subprocess.SubprocessError):
@@ -69,21 +92,27 @@ def _try_build() -> None:
                 pass
 
 
-def _compile(src: str, tmp: str) -> None:
+def _compile(tmp: str) -> None:
+    digest = _src_hash()
     subprocess.run(
-        ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, src],
+        ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
         check=True,
         capture_output=True,
         timeout=120,
     )
     os.replace(tmp, _SO)
+    # stamp AFTER the .so: a crash between the two leaves a mismatch, which
+    # only costs a rebuild
+    with open(f"{tmp}.sha256", "w") as f:
+        f.write(f"{digest}\n")
+    os.replace(f"{tmp}.sha256", _STAMP)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_SO):
+    if not _fresh():
         _try_build()
-    if not os.path.exists(_SO):
-        return None
+    if not _fresh():
+        return None  # no compiler (or no source): numpy path, NATIVE False
     try:
         lib = ctypes.CDLL(_SO)
     except OSError:

@@ -71,11 +71,8 @@ def _ring_attention_sharded(q, k, v, *, axis_name: str, causal: bool = True):
     den = jnp.zeros((b, h, tl), jnp.float32)
     m = jnp.full((b, h, tl), NEG_INF, jnp.float32)
     # mark accumulators device-varying so the loop carry types line up with
-    # the sharded K/V blocks (jax>=0.8 shard_map vma typing; identity on
-    # older jax — parallel/compat.py)
-    from p2pfl_tpu.parallel.compat import device_varying
-
-    acc, den, m = device_varying((acc, den, m), axis_name)
+    # the sharded K/V blocks (shard_map's varying-axes typing)
+    acc, den, m = lax.pcast((acc, den, m), (axis_name,), to="varying")
     perm = [(j, (j + 1) % ring) for j in range(ring)]
 
     def body(i, carry):
@@ -118,9 +115,7 @@ def _ring_flash_sharded(q, k, v, *, axis_name: str, config, interpret: bool):
     # lse rides the kernels' block-size-independent [B, H, 1, T_local] row
     # layout, so hop merges never depend on the configured block shapes
     lse = jnp.full((b, h, 1, tl), NEG_INF, jnp.float32)
-    from p2pfl_tpu.parallel.compat import device_varying
-
-    out, lse = device_varying((out, lse), axis_name)
+    out, lse = lax.pcast((out, lse), (axis_name,), to="varying")
 
     kb, vb = k, v
     for i in range(ring):  # ring size is static: plain python loop
@@ -161,8 +156,6 @@ def ring_attention(
     """
     from jax.sharding import PartitionSpec as P
 
-    from p2pfl_tpu.parallel.compat import shard_map_compat, shard_map_unchecked
-
     spec = P(None, axis_name, None, None)
     if impl == "flash":
         if not causal:
@@ -182,10 +175,11 @@ def ring_attention(
         )
         # pallas_call's out_shape carries no vma typing — disable the check
         # for the flash body (the collectives are still the same ring)
-        fn = shard_map_unchecked(
-            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+        fn = jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )
         return fn(q, k, v)
     body = partial(_ring_attention_sharded.__wrapped__, axis_name=axis_name, causal=causal)
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

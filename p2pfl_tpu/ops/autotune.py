@@ -53,13 +53,10 @@ _DISK_LOADED: set[str] = set()  # cache paths already merged into _MEM_CACHE
 
 def device_kind() -> str:
     """The tuning-cache platform key: TPU device kind, else backend name."""
-    try:
-        dev = jax.devices()[0]
-        if dev.platform == "tpu":
-            return dev.device_kind
-        return dev.platform  # "cpu" / "gpu" — interpret-mode territory
-    except Exception:  # pragma: no cover — no backend at all
-        return "cpu"
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return dev.device_kind
+    return dev.platform  # "cpu" / "gpu" — interpret-mode territory
 
 
 def _dtype_tag(dtype) -> str:
@@ -110,14 +107,24 @@ DEFAULTS = {
 }
 
 
+# exact ``device_kind`` strings (as ``jax.devices()[0].device_kind`` reports
+# them) → defaults-table family; anything non-TPU runs interpret-mode recipes
+_TPU_FAMILIES = {"TPU v4": "v4", "TPU v5 lite": "v5e"}
+
+
 def _family(kind: str) -> str:
-    k = kind.lower()
-    if "v5 lite" in k or "v5e" in k or "v5lite" in k:
-        return "v5e"
-    if "v4" in k:
-        return "v4"
-    if "tpu" in k:  # unknown TPU generation: the v5e recipe is the safer bet
-        return "v5e"
+    """Defaults-table family of a device kind. A TPU kind with no measured
+    recipe raises — block shapes tuned for another generation's VMEM are a
+    guess, not a default."""
+    family = _TPU_FAMILIES.get(kind)
+    if family is not None:
+        return family
+    if "tpu" in kind.lower():
+        raise ValueError(
+            f"no flash defaults for TPU device_kind {kind!r} "
+            f"(known: {sorted(_TPU_FAMILIES)}); add a measured recipe to "
+            "autotune.DEFAULTS or pass an explicit FlashConfig"
+        )
     return "cpu"
 
 
@@ -176,20 +183,29 @@ def pin_flash_config(
     _PINNED[_key(kind or device_kind(), d, t, dtype, causal)] = config
 
 
+def flash_config_source(
+    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None
+) -> tuple[FlashConfig, str]:
+    """Trace-safe config lookup plus where it came from:
+    ``"pin"`` → ``"tune"`` (memory → disk) → ``"defaults"``."""
+    kind = kind or device_kind()
+    key = _key(kind, d, t, dtype, causal)
+    got = _PINNED.get(key)
+    if got is not None:
+        return got, "pin"
+    if key not in _MEM_CACHE:
+        _load_disk(cache_path())
+    got = _MEM_CACHE.get(key)
+    if got is not None:
+        return got, "tune"
+    return default_flash_config(t, d, dtype, causal, kind), "defaults"
+
+
 def get_flash_config(
     t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None
 ) -> FlashConfig:
     """Trace-safe config lookup: pinned → tuned (memory → disk) → defaults."""
-    kind = kind or device_kind()
-    key = _key(kind, d, t, dtype, causal)
-    got = _PINNED.get(key) or _MEM_CACHE.get(key)
-    if got is not None:
-        return got
-    _load_disk(cache_path())
-    got = _MEM_CACHE.get(key)
-    if got is not None:
-        return got
-    return default_flash_config(t, d, dtype, causal, kind)
+    return flash_config_source(t, d, dtype, causal, kind)[0]
 
 
 def candidate_configs(t: int, d: int, max_candidates: int = 12) -> list[FlashConfig]:

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -124,16 +125,11 @@ def _resolve(config: Optional[FlashConfig], t: int, d: int, dtype, causal: bool)
     return get_flash_config(t, d, dtype=dtype, causal=causal)
 
 
-def _compiler_params(*dims: str):
+def _compiler_params(*dims: str) -> pltpu.CompilerParams:
     """Pin grid ``dimension_semantics`` ('parallel' dims may be split across
-    megacore; 'arbitrary' dims MUST run sequentially on one core). Returns
-    None on non-TPU pallas builds (and is ignored in interpret mode)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.TPUCompilerParams(dimension_semantics=dims)
-    except (ImportError, AttributeError, TypeError):  # pragma: no cover
-        return None
+    megacore; 'arbitrary' dims MUST run sequentially on one core). Ignored
+    in interpret mode."""
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
@@ -430,14 +426,8 @@ def _bwd_use_fused(t: int, d: int, mode: str) -> bool:
 
 
 def _dq_scratch(t: int, d: int):
-    """The fused backward's persistent fp32 [T, D] dQ accumulator — the one
-    place the VMEM-scratch spec (and its non-TPU fallback) is defined."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return [pltpu.VMEM((t, d), jnp.float32)]
-    except ImportError:  # pragma: no cover — non-TPU pallas build
-        return [pl.MemorySpace.ANY((t, d), jnp.float32)]
+    """The fused backward's persistent fp32 [T, D] dQ accumulator."""
+    return [pltpu.VMEM((t, d), jnp.float32)]
 
 
 def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interpret, bwd_mode):
@@ -612,12 +602,7 @@ flash_attention.defvjp(_fwd, _bwd)
 # kernels as int32 scalars in SMEM — the causal frontier becomes a traced
 # fori_loop bound and the mask compares global row/col indices.
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
-except ImportError:  # non-TPU pallas build
-    _SMEM_SPEC = pl.BlockSpec(memory_space=None)
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _fwd_tile_offs(q, k_ref, v_ref, qi, q_off, k_off, *, block_q, block_k, scale, t):

@@ -1382,7 +1382,6 @@ def run_fleet_program_sharded(
     """
     from jax.sharding import PartitionSpec
 
-    from p2pfl_tpu.parallel.compat import shard_map_compat
     from p2pfl_tpu.parallel.fleet_mesh import shard_capacity
 
     axis = mesh.axis_names[0]
@@ -1473,7 +1472,7 @@ def run_fleet_program_sharded(
     seg = PartitionSpec(None, axis)
     repl = PartitionSpec()
     program = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body_fn,
             mesh=mesh,
             in_specs=(shard, repl, repl, seg),

@@ -46,8 +46,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from p2pfl_tpu.parallel.compat import shard_map_compat, shard_map_unchecked
-
 Pytree = Any
 
 #: leading axis of the transfer pair mesh (block 0 = sender's slice,
@@ -187,16 +185,15 @@ def _pallas_exchange(v, sub_axes: tuple):
         rdma.start()
         rdma.wait()
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=params_cls(has_side_effects=True, collective_id=0),
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True, collective_id=0
+        ),
     )(v)
 
 
@@ -228,9 +225,12 @@ def _exchange_program(pair_mesh: Mesh, gspecs: tuple, backend: str):
                 for v in leaves
             )
 
-    wrap = shard_map_unchecked if backend == "pallas" else shard_map_compat
+    # pallas_call's out_shape carries no vma typing — check off for it
     prog = jax.jit(
-        wrap(body, mesh=pair_mesh, in_specs=gspecs, out_specs=gspecs)
+        jax.shard_map(
+            body, mesh=pair_mesh, in_specs=gspecs, out_specs=gspecs,
+            check_vma=backend != "pallas",
+        )
     )
     _programs[key] = prog
     return prog

@@ -30,8 +30,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from p2pfl_tpu.parallel.compat import device_varying, shard_map_compat
-
 Pytree = Any
 
 
@@ -48,9 +46,10 @@ def stack_layers(per_layer_params: list[Pytree]) -> Pytree:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer_params)
 
 
-# jax>=0.8 shard_map typing: scan carries must be device-varying to match
-# values produced by axis_index/ppermute; identity on older jax (compat.py)
-_varying = device_varying
+def _varying(x, axis: str):
+    """shard_map typing: scan carries must be device-varying to match the
+    values produced by axis_index/ppermute."""
+    return lax.pcast(x, (axis,), to="varying")
 
 
 def _pipeline_body(stage_params, xs, apply_layer: Callable, axis: str, n_stages: int):
@@ -129,7 +128,7 @@ def pipeline_apply(
         def layer_fn(p_layer, act):
             return apply_layer(p_layer, act), jnp.zeros((), jnp.float32)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(_pipeline_body, apply_layer=layer_fn, axis=axis, n_stages=n_stages),
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -29,6 +29,35 @@ from p2pfl_tpu.parallel.spmd import SpmdFederation, _aggregate
 Pytree = Any
 
 
+def _on_own_nodes(fn, sharding: Optional[NamedSharding], n_stacked: int):
+    """``fn`` over node-stacked arguments, run by each device on its OWN nodes.
+
+    ``fn``'s first ``n_stacked`` arguments and all its results carry the node
+    axis in front; its last argument is shared. Per-node work needs no
+    communication, and ``shard_map`` over the nodes axis says so: under plain
+    ``jit`` GSPMD has to partition whatever ``fn`` contains, and it refuses
+    Mosaic kernels ("cannot be automatically partitioned" — the flash
+    kernels, first seen on four chips). Mosaic lowers only with EVERY mesh
+    axis manual, so the other axes join when they are trivial; when they are
+    not (a tensor-parallel base) they stay automatic, which XLA ops accept.
+    """
+    if sharding is None:
+        return fn
+    mesh, axis = sharding.mesh, sharding.spec[0]
+    if mesh.shape[axis] == 1:
+        return fn
+    others = [a for a in mesh.axis_names if a != axis]
+    manual = {axis} if any(mesh.shape[a] > 1 for a in others) else {axis, *others}
+    return jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=(P(axis),) * n_stacked + (P(),),
+        out_specs=P(axis),
+        axis_names=manual,
+        check_vma=False,  # pallas_call results carry no varying-axes typing
+    )
+
+
 def _lora_round_core(
     stacked_lora,  # [N, ...] adapters
     opt_states,  # [N, ...]
@@ -56,11 +85,15 @@ def _lora_round_core(
     memory scales with nodes-in-flight, so chunking buys HBM headroom for
     a richer selective-remat policy (``TransformerConfig.remat_policy``) —
     the 0.98B bench row trades 4× fewer nodes in flight for skipping the
-    FFN recompute entirely, a net model-MFU win. 0 = single vmap.
+    FFN recompute entirely, a net model-MFU win. 0 = single vmap. On a
+    mesh of k devices each device scans its own N/k nodes, ``node_chunk //
+    k`` at a time (at least one), so the count in flight is the same.
     """
     n = mask.shape[0]
+    if node_chunk and node_chunk < n and n % node_chunk:
+        raise ValueError(f"node_chunk {node_chunk} must divide n_nodes {n}")
 
-    def node_fn(lora, opt_state, x, y, idx):
+    def node_fn(lora, opt_state, x, y, idx, base):
         def epoch_body(carry, ep_idx):
             lo, o = carry
             xs = jnp.take(x, ep_idx, axis=0)
@@ -90,36 +123,30 @@ def _lora_round_core(
         (lora, opt_state), losses = jax.lax.scan(epoch_body, (lora, opt_state), idx)
         return lora, opt_state, jnp.mean(losses)
 
-    vmapped = jax.vmap(node_fn, in_axes=(0, 0, 0, 0, 0))
-    if node_chunk and node_chunk < n:
-        if n % node_chunk:
-            raise ValueError(f"node_chunk {node_chunk} must divide n_nodes {n}")
-        nc = n // node_chunk
+    def train(lora, opt, x, y, idx, base):
+        """Every node on the leading axis — all N, or one device's share of
+        them, then with the same share of ``node_chunk`` in flight."""
+        here = idx.shape[0]
+        in_flight = max(1, node_chunk * here // n)
+        vmapped = jax.vmap(node_fn, in_axes=(0, 0, 0, 0, 0, None))
+        if not node_chunk or in_flight >= here:
+            return vmapped(lora, opt, x, y, idx, base)
+        chunk = next(c for c in range(in_flight, 0, -1) if here % c == 0)
 
         def chunked(tree):
             return jax.tree.map(
-                lambda a: a.reshape(nc, node_chunk, *a.shape[1:]), tree
+                lambda a: a.reshape(here // chunk, chunk, *a.shape[1:]), tree
             )
 
         def chunk_body(_, args):
-            return None, vmapped(*args)
+            return None, vmapped(*args, base)
 
-        _, (trained, trained_opt, losses) = jax.lax.scan(
-            chunk_body,
-            None,
-            (
-                chunked(stacked_lora), chunked(opt_states),
-                chunked(x_all), chunked(y_all), chunked(perm),
-            ),
-        )
-        trained, trained_opt = jax.tree.map(
-            lambda a: a.reshape(n, *a.shape[2:]), (trained, trained_opt)
-        )
-        losses = losses.reshape(n)
-    else:
-        trained, trained_opt, losses = vmapped(
-            stacked_lora, opt_states, x_all, y_all, perm
-        )
+        _, out = jax.lax.scan(chunk_body, None, chunked((lora, opt, x, y, idx)))
+        return jax.tree.map(lambda a: a.reshape(here, *a.shape[2:]), out)
+
+    trained, trained_opt, losses = _on_own_nodes(train, out_sharding, 5)(
+        stacked_lora, opt_states, x_all, y_all, perm, base
+    )
 
     def sel(new, old):
         m = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
@@ -176,14 +203,16 @@ def spmd_lora_rounds_fused(
     return p, o, losses
 
 
-@partial(jax.jit, static_argnames=("module",))
-def spmd_lora_eval(stacked_lora, base, x_test, y_test, *, module):
-    def node_eval(lora, x, y):
+@partial(jax.jit, static_argnames=("module", "sharding"))
+def spmd_lora_eval(stacked_lora, base, x_test, y_test, *, module, sharding=None):
+    def node_eval(lora, x, y, base):
         loss, logits = ce_eval(merge_params(base, lora), module, x, y)
         acc = jnp.mean((jnp.argmax(logits, axis=-1) == y).astype(jnp.float32))
         return loss, acc
 
-    return jax.vmap(node_eval, in_axes=(0, 0, 0))(stacked_lora, x_test, y_test)
+    return _on_own_nodes(jax.vmap(node_eval, in_axes=(0, 0, 0, None)), sharding, 3)(
+        stacked_lora, x_test, y_test, base
+    )
 
 
 class SpmdLoraFederation(SpmdFederation):
@@ -224,38 +253,43 @@ class SpmdLoraFederation(SpmdFederation):
         else:
             self.base = jax.device_put(self._base_template, self._repl)
 
+    def _round_call(self, epochs: int) -> tuple[tuple, dict]:
+        """(args, static kwargs) of the :func:`spmd_lora_round` dispatch for
+        the next round — draws the round's batch permutation."""
+        perm = self._make_perm(epochs)
+        eff = self._effective_mask()
+        mask = jax.device_put(jnp.asarray(eff), self._shard)
+        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
+        args = (
+            self.params, self.opt_state, self.base, self.x_all, self.y_all,
+            perm, mask, self._samples, sel_idx,
+        )
+        statics = dict(
+            module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
+            out_sharding=self._shard, keep_opt_state=self.keep_opt_state,
+            remat=self.remat, node_chunk=self.node_chunk,
+        )
+        return args, statics
+
     def run_round(self, epochs: int = 1) -> dict:
         from p2pfl_tpu.settings import Settings
 
         if self._vote and (self.round == 0 or Settings.VOTE_EVERY_ROUND):
             self.train_mask = self.elect_train_set()
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
-        self.params, self.opt_state, loss = spmd_lora_round(
-            self.params,
-            self.opt_state,
-            self.base,
-            self.x_all,
-            self.y_all,
-            perm,
-            mask,
-            self._samples,
-            sel_idx,
-            module=self.module,
-            tx=self.tx,
-            agg=self.aggregator,
-            trim=self.trim,
-            out_sharding=self._shard,
-            keep_opt_state=self.keep_opt_state,
-            remat=self.remat,
-            node_chunk=self.node_chunk,
-        )
+        args, statics = self._round_call(epochs)
+        self.params, self.opt_state, loss = spmd_lora_round(*args, **statics)
         self.round += 1
         entry = {"round": self.round, "train_loss": loss}
         self.history.append(entry)
         return entry
+
+    def lower_round(self, epochs: int = 1) -> jax.stages.Lowered:
+        """The program :meth:`run_round` dispatches, lowered but not run —
+        for inspecting what the round compiles to (``chip_smoke.py`` looks
+        for the Mosaic kernels in it). State is untouched apart from the
+        batch-order rng draw a round would also make."""
+        args, statics = self._round_call(epochs)
+        return spmd_lora_round.lower(*args, **statics)
 
     def run_fused(self, rounds: int, epochs: int = 1, eval: bool = False) -> list[dict]:  # noqa: A002
         """R adapter-federation rounds as ONE device dispatch.
@@ -284,7 +318,8 @@ class SpmdLoraFederation(SpmdFederation):
 
     def evaluate(self) -> dict:
         loss, acc = spmd_lora_eval(
-            self.params, self.base, self.x_test, self.y_test, module=self.module
+            self.params, self.base, self.x_test, self.y_test, module=self.module,
+            sharding=self._shard,
         )
         return {
             "test_loss": float(jnp.mean(loss)),

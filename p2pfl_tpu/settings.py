@@ -508,12 +508,10 @@ class Settings:
     # Sequence length at/above which attn="auto" picks the Pallas flash
     # kernel over fused dense XLA attention (TPU backends only — anywhere
     # else the kernel runs in interpret mode and "auto" stays dense).
-    # Crossover measured on the real chip by bench config 7 (BASELINE.md
-    # row 7, BENCH_SUITE.json). Round-4 re-measurement (bf16 MXU kernels,
-    # slope-based in-dispatch timing): at block 512 flash beats dense
-    # 1.38x at T=1024, 1.89x at 2048, 4.15x at 4096 on the train step,
-    # and LOSES 0.55x at T=512 — the threshold stays 1024.
-    # Re-tune with `python bench_suite.py 7` if the model shape changes.
+    # The builders' rounds-3–5 account put the crossover at 1024 (flash
+    # ahead from T=1024 up, behind at 512; record deleted at PR 21, see git
+    # history). Not measured on this installation — `python bench_suite.py
+    # 7` on the chip re-measures it.
     FLASH_MIN_SEQ_LEN: int = 1024
     # Autotune the flash-attention kernel schedule at model-build time:
     # tiny_transformer(attn="flash"|"ring_flash") sweeps (block_q, block_k,

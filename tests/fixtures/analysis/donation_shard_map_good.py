@@ -9,7 +9,7 @@ from functools import partial
 import jax
 from jax.sharding import PartitionSpec
 
-from p2pfl_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _body(w, events):

@@ -11,7 +11,7 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from p2pfl_tpu.parallel.compat import shard_map
+from jax import shard_map
 from p2pfl_tpu.settings import Settings
 
 CHUNK_OVERRIDE = 0

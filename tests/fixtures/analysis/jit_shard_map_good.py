@@ -10,7 +10,7 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from p2pfl_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 SCALE = 2.0  # single-assignment module constant: static, fine
 

@@ -346,6 +346,59 @@ def test_stale_beat_rejected_fresh_beat_accepted():
         _stop_all(nodes)
 
 
+def test_local_pause_is_not_peer_silence(monkeypatch):
+    """A process frozen for longer than HEARTBEAT_TIMEOUT (VM pause, a C call
+    holding the GIL) must not evict live peers for the beats it could not
+    hear: the heartbeater's own overrun is discounted from every neighbor's
+    silence clock before the sweep. Seen on the TPU machine, where a freeze
+    during the first round made two in-process nodes evict each other and
+    finish with different params (PR 21)."""
+    from p2pfl_tpu.communication.heartbeater import Heartbeater
+    from p2pfl_tpu.communication.neighbors import Neighbors
+    from p2pfl_tpu.communication.reliability import CircuitBreaker
+
+    monkeypatch.setattr(Settings, "HEARTBEAT_PERIOD", 0.1)
+    monkeypatch.setattr(Settings, "HEARTBEAT_TIMEOUT", 0.5)
+    freeze = 1.0  # two timeouts
+
+    class Proto:
+        neighbors = Neighbors("me")
+        breaker = CircuitBreaker("me")
+
+        def build_msg(self, cmd, args):
+            return None
+
+        def broadcast(self, msg):
+            pass
+
+    class FrozenOnce:
+        """``Event.wait`` stand-in: the second sleep overruns by ``freeze``
+        (so the next sweep tick sees the whole freeze), the fifth ends the run."""
+
+        calls = 0
+
+        def is_set(self):
+            return False
+
+        def wait(self, timeout):
+            self.calls += 1
+            time.sleep(timeout + (freeze if self.calls == 2 else 0.0))
+            return self.calls >= 5
+
+    proto = Proto()
+    proto.neighbors.add("peer", non_direct=True)
+    hb = Heartbeater("me", proto)
+    hb._stop = FrozenOnce()
+    hb._run()  # ticks 1..5; sweeps at 2 and 4; the freeze sits between them
+    # the peer "beat" for the last time before the freeze and is still a
+    # member: freeze + 3 periods of silence, minus the discounted freeze
+    assert proto.neighbors.get("peer") is not None, "live peer evicted after a local pause"
+    assert logger.get_comm_metrics("me").get("local_pause", 0) == 1
+    # the discount is the overrun and no more: real silence still evicts
+    time.sleep(Settings.HEARTBEAT_TIMEOUT)
+    assert proto.neighbors.evict_stale(Settings.HEARTBEAT_TIMEOUT) == ["peer"]
+
+
 # ---------------------------------------------------------------------------
 # mid-round train-set repair
 # ---------------------------------------------------------------------------
