@@ -31,10 +31,21 @@ def configure_compile_cache() -> str:
     total) — and a program compiling in about 1 s was kept in some runs and
     not others, so one warm run's phase compiled for longer than the cold
     run's. At 0 a cold run costs the same and the cache holds 2 MB more.
+
+    The key includes the program's metadata. JAX's default strips locations
+    before hashing — and ``jax.named_scope`` names live there — so two
+    programs that differ only in their scopes share one entry and the later
+    one is handed the earlier one's executable, names and all (seen at PR
+    24: a program scoped ``beta`` came back with every ``op_name`` reading
+    ``alpha``). A profiler trace is read by those names
+    (``management/profiling.DEVICE_SCOPES``), so a cache shared between
+    two versions of this code must not mix them. The price: an edit that
+    moves traced lines misses the cache once.
     """
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

@@ -29,11 +29,45 @@ def trace(log_dir: str = "/tmp/p2pfl_tpu_trace") -> Iterator[None]:
         logger.info("profiler", f"trace written to {log_dir}")
 
 
-@contextlib.contextmanager
-def annotate(name: str, step: Optional[int] = None) -> Iterator[None]:
-    """Label the enclosed device work in the trace timeline."""
-    with jax.profiler.StepTraceAnnotation(name, step_num=step or 0):
-        yield
+# ---- the program's own names on the device timeline ----
+#
+# Every phase of a round's compiled program runs under one of these scopes.
+# A scope is HLO metadata (the ``op_name`` path of every instruction traced
+# inside it): it costs nothing at run time, so there is no switch. The
+# benchmark reads device time by scope from a profiler trace
+# (``benchmark/scope_reduce.py``); forward / remat's re-forward / backward
+# need no scope of their own — under ``grad`` JAX itself writes ``jvp``,
+# ``rematted_computation`` and ``transpose(jvp`` into the path.
+DEVICE_SCOPES = (
+    "grad",  # value_and_grad of the local loss: forward, re-forward, backward
+    "optimizer",  # tx.update + apply_updates (and gradient corrections beside them)
+    "fold",  # everything a round does after the last local step
+    "base_cast",  # LoRADense: the frozen kernel's cast to the compute dtype
+    "base_matmul",  # LoRADense: x @ W
+    "adapter",  # LoRADense: (x @ A) @ B
+    "flash_fwd",  # ops/flash_attention: the forward Mosaic call
+    "flash_bwd",  # ops/flash_attention: the backward Mosaic call(s)
+)
+
+
+def scope(name: str):
+    """``jax.named_scope`` for one of :data:`DEVICE_SCOPES`."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"unknown device scope {name!r} (known: {DEVICE_SCOPES})")
+    return jax.named_scope("p2pfl." + name)
+
+
+def host_annotation(site: str):
+    """``p2pfl:<site>`` on the profiler's host timeline around host work —
+    a ``jax.profiler.TraceAnnotation`` where ``settings.
+    telemetry_jax_annotations`` says so, else nothing. No counter and no
+    telemetry span: for a jit call site use :func:`dispatch_span`, which
+    runs its body under this too."""
+    from p2pfl_tpu.settings import telemetry_jax_annotations
+
+    if telemetry_jax_annotations():
+        return jax.profiler.TraceAnnotation(f"p2pfl:{site}")
+    return contextlib.nullcontext()
 
 
 # bf16 peak matmul FLOP/s per chip by device kind (public spec sheets);
@@ -185,13 +219,9 @@ def dispatch_span(site: str, node: str = "", **attrs) -> Iterator[None]:
     inflate dispatches_per_round with a program that never ran to
     completion (the span still records, with the error in its attrs)."""
     from p2pfl_tpu.management.telemetry import telemetry
-    from p2pfl_tpu.settings import telemetry_jax_annotations
 
     with telemetry.span(node, site, kind="dispatch", attrs=attrs or None):
-        if telemetry_jax_annotations():
-            with jax.profiler.TraceAnnotation(f"p2pfl:{site}"):
-                yield
-        else:
+        with host_annotation(site):
             yield
     record_dispatch(site, node)
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from p2pfl_tpu.management.profiling import scope
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.ops.attention import causal_attention
 from p2pfl_tpu.ops.flash_attention import FlashConfig
@@ -154,15 +155,19 @@ class LoRADense(nn.Module):
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(), (x.shape[-1], self.features)
         )
-        y = jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype))
+        with scope("base_cast"):
+            w = kernel.astype(self.dtype)
+        with scope("base_matmul"):
+            y = jnp.dot(x.astype(self.dtype), w)
         if self.rank > 0:
             a = self.param(
                 "lora_a", nn.initializers.normal(0.02), (x.shape[-1], self.rank)
             )
             b = self.param("lora_b", nn.initializers.zeros, (self.rank, self.features))
-            y = y + jnp.dot(
-                jnp.dot(x.astype(self.dtype), a.astype(self.dtype)), b.astype(self.dtype)
-            ) * (self.alpha / self.rank)
+            with scope("adapter"):
+                y = y + jnp.dot(
+                    jnp.dot(x.astype(self.dtype), a.astype(self.dtype)), b.astype(self.dtype)
+                ) * (self.alpha / self.rank)
         return y
 
 

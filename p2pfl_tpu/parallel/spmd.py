@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import _loss, _prox_term, adam, ce_eval, sgd
+from p2pfl_tpu.management.profiling import dispatch_span, host_annotation, scope
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.settings import Settings
 
@@ -96,13 +97,15 @@ def _local_epoch(
             p, o, k, gs = carry
             x, y = batch
             k, sub = jax.random.split(k)
-            grads, loss = dp_grads(loss_one, p, x, y, dp_clip, dp_noise, sub, remat=remat)
-            if accumulate_grads:
-                gs = jax.tree.map(lambda s, g: s + g.astype(jnp.float32), gs, grads)
-            if corr is not None:
-                grads = jax.tree.map(lambda g, c: g + c.astype(g.dtype), grads, corr)
-            updates, o = tx.update(grads, o, p)
-            p = optax.apply_updates(p, updates)
+            with scope("grad"):
+                grads, loss = dp_grads(loss_one, p, x, y, dp_clip, dp_noise, sub, remat=remat)
+            with scope("optimizer"):
+                if accumulate_grads:
+                    gs = jax.tree.map(lambda s, g: s + g.astype(jnp.float32), gs, grads)
+                if corr is not None:
+                    grads = jax.tree.map(lambda g, c: g + c.astype(g.dtype), grads, corr)
+                updates, o = tx.update(grads, o, p)
+                p = optax.apply_updates(p, updates)
             return (p, o, k, gs), loss
 
         (params, opt_state, _, gsum), losses = jax.lax.scan(
@@ -124,13 +127,15 @@ def _local_epoch(
 
         if remat:
             loss_fn = jax.checkpoint(loss_fn)
-        loss, grads = jax.value_and_grad(loss_fn)(p)
-        if accumulate_grads:
-            gs = jax.tree.map(lambda s, g: s + g.astype(jnp.float32), gs, grads)
-        if corr is not None:
-            grads = jax.tree.map(lambda g, c: g + c.astype(g.dtype), grads, corr)
-        updates, o = tx.update(grads, o, p)
-        p = optax.apply_updates(p, updates)
+        with scope("grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(p)
+        with scope("optimizer"):
+            if accumulate_grads:
+                gs = jax.tree.map(lambda s, g: s + g.astype(jnp.float32), gs, grads)
+            if corr is not None:
+                grads = jax.tree.map(lambda g, c: g + c.astype(g.dtype), grads, corr)
+            updates, o = tx.update(grads, o, p)
+            p = optax.apply_updates(p, updates)
         return (p, o, gs), loss
 
     (params, opt_state, gsum), losses = jax.lax.scan(
@@ -452,91 +457,94 @@ def _round_core(
             node_fn, in_axes=(0, 0, 0, 0, 0, None, None, key_ax)
         )(stacked_params, opt_states, x_all, y_all, perm, None, None, keys)
 
-    # non-train-set nodes contribute their previous params (they don't train)
-    def sel(new, old):
-        m = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
-        return new * m + old * (1 - m)
+    # everything after the last local step — selection, aggregation, the
+    # server step, diffusion, SCAFFOLD's variates — is the round's fold
+    with scope("fold"):
+        # non-train-set nodes contribute their previous params (they don't train)
+        def sel(new, old):
+            m = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
+            return new * m + old * (1 - m)
 
-    p_used = jax.tree.map(sel, trained_p, stacked_params)
-    # clip center = the round's shared starting model. Under normal
-    # diffusion every slot holds it identically; the coordinate-wise median
-    # over the elected rows recovers it exactly in that case AND stays
-    # robust if a slot's incoming copy was tampered with (taking row 0
-    # verbatim would let a poisoned slot choose the center).
-    center = (
-        jax.tree.map(
-            lambda x: jnp.median(
-                jnp.take(x, sel_idx, axis=0).astype(jnp.float32), axis=0
-            ),
-            stacked_params,
-        )
-        if agg == "clip"
-        else None
-    )
-    agg_params = _aggregate(
-        p_used, mask, weights, sel_idx, agg, trim, center=center, clip_tau=clip_tau
-    )
-
-    fedopt_state = ()
-    if server_opt:
-        # FedOpt server step on the pseudo-gradient prev_global − aggregate
-        # (node slot 0's incoming params ARE the previous global — diffusion
-        # left every slot identical)
-        from p2pfl_tpu.ops.aggregation import fedopt_update
-
-        prev_global = jax.tree.map(lambda x: x[0], stacked_params)
-        agg_params, opt_m_out, opt_v_out = fedopt_update(
-            prev_global, agg_params, opt_m, opt_v, opt_t,
-            opt=server_opt, lr=server_lr,
-        )
-        fedopt_state = (opt_m_out, opt_v_out)
-
-    # diffusion: every node receives the aggregate
-    out_params = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n, *a.shape)), agg_params)
-    if out_sharding is not None:
-        # pin the node-stacked layout so round k+1 reuses round k's executable
-        # (otherwise the broadcast's replicated layout forces a relayout+retrace)
-        out_params = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_params
-        )
-    if keep_opt_state:
-        # documented improvement over the reference: carry Adam moments
-        # across rounds (the reference rebuilds its Trainer per round,
-        # losing them — slower convergence)
-        out_opt = trained_o
-    else:
-        out_opt = jax.vmap(tx.init)(out_params)
-    if out_sharding is not None:
-        # vmap(tx.init) outputs otherwise come back replicated, flipping the
-        # opt-state layout between rounds and forcing a recompile per variant
-        out_opt = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_opt
-        )
-    mean_loss = jnp.mean(losses, where=mask.astype(bool))
-
-    scaffold_state = ()
-    if scaffold:
-        # only train-set nodes commit their new control variates; the server
-        # variate moves by |S|/N times the mean train-set delta
-        def selc(new, old):
-            m_ = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
-            return new * m_ + old * (1 - m_)
-
-        c_local_out = jax.tree.map(selc, ci_new, c_local)
-        n_train = jnp.maximum(jnp.sum(mask), 1.0)
-        frac = n_train / n
-
-        def upd(c, cn, co):
-            m_ = mask.reshape((n,) + (1,) * (cn.ndim - 1))
-            delta = jnp.sum((cn - co) * m_, axis=0) / n_train
-            return c + frac * delta
-
-        c_global_out = jax.tree.map(upd, c_global, ci_new, c_local)
-        if out_sharding is not None:
-            c_local_out = jax.tree.map(
-                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), c_local_out
+        p_used = jax.tree.map(sel, trained_p, stacked_params)
+        # clip center = the round's shared starting model. Under normal
+        # diffusion every slot holds it identically; the coordinate-wise median
+        # over the elected rows recovers it exactly in that case AND stays
+        # robust if a slot's incoming copy was tampered with (taking row 0
+        # verbatim would let a poisoned slot choose the center).
+        center = (
+            jax.tree.map(
+                lambda x: jnp.median(
+                    jnp.take(x, sel_idx, axis=0).astype(jnp.float32), axis=0
+                ),
+                stacked_params,
             )
-        scaffold_state = (c_global_out, c_local_out)
+            if agg == "clip"
+            else None
+        )
+        agg_params = _aggregate(
+            p_used, mask, weights, sel_idx, agg, trim, center=center, clip_tau=clip_tau
+        )
+
+        fedopt_state = ()
+        if server_opt:
+            # FedOpt server step on the pseudo-gradient prev_global − aggregate
+            # (node slot 0's incoming params ARE the previous global — diffusion
+            # left every slot identical)
+            from p2pfl_tpu.ops.aggregation import fedopt_update
+
+            prev_global = jax.tree.map(lambda x: x[0], stacked_params)
+            agg_params, opt_m_out, opt_v_out = fedopt_update(
+                prev_global, agg_params, opt_m, opt_v, opt_t,
+                opt=server_opt, lr=server_lr,
+            )
+            fedopt_state = (opt_m_out, opt_v_out)
+
+        # diffusion: every node receives the aggregate
+        out_params = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n, *a.shape)), agg_params)
+        if out_sharding is not None:
+            # pin the node-stacked layout so round k+1 reuses round k's executable
+            # (otherwise the broadcast's replicated layout forces a relayout+retrace)
+            out_params = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_params
+            )
+        if keep_opt_state:
+            # documented improvement over the reference: carry Adam moments
+            # across rounds (the reference rebuilds its Trainer per round,
+            # losing them — slower convergence)
+            out_opt = trained_o
+        else:
+            out_opt = jax.vmap(tx.init)(out_params)
+        if out_sharding is not None:
+            # vmap(tx.init) outputs otherwise come back replicated, flipping the
+            # opt-state layout between rounds and forcing a recompile per variant
+            out_opt = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_opt
+            )
+        mean_loss = jnp.mean(losses, where=mask.astype(bool))
+
+        scaffold_state = ()
+        if scaffold:
+            # only train-set nodes commit their new control variates; the server
+            # variate moves by |S|/N times the mean train-set delta
+            def selc(new, old):
+                m_ = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
+                return new * m_ + old * (1 - m_)
+
+            c_local_out = jax.tree.map(selc, ci_new, c_local)
+            n_train = jnp.maximum(jnp.sum(mask), 1.0)
+            frac = n_train / n
+
+            def upd(c, cn, co):
+                m_ = mask.reshape((n,) + (1,) * (cn.ndim - 1))
+                delta = jnp.sum((cn - co) * m_, axis=0) / n_train
+                return c + frac * delta
+
+            c_global_out = jax.tree.map(upd, c_global, ci_new, c_local)
+            if out_sharding is not None:
+                c_local_out = jax.tree.map(
+                    lambda a: jax.lax.with_sharding_constraint(a, out_sharding), c_local_out
+                )
+            scaffold_state = (c_global_out, c_local_out)
 
     return out_params, out_opt, mean_loss, scaffold_state, fedopt_state, agg_params
 
@@ -948,7 +956,7 @@ class SpmdFederation:
     def _stage_data(self) -> None:
         # node shards are padded (wrap-around) to a common static length so
         # they stack into one [N, S, ...] array, but each node's per-round
-        # shuffle indices are drawn from its OWN sample range (``_make_perm``)
+        # shuffle indices are drawn from its OWN sample range (``_make_perm_np``)
         # — so the FedAvg sample-count weights match the data each node
         # actually trains on (over rounds, every node covers its full shard).
         # Policy (padding/clipping/nb sizing) lives in the shared
@@ -978,8 +986,23 @@ class SpmdFederation:
     def _make_perm_np(self, epochs: int) -> np.ndarray:
         return draw_node_perms(self._rng, self._sizes, self._nb, self.batch_size, epochs)
 
-    def _make_perm(self, epochs: int):
-        return jax.device_put(self._make_perm_np(epochs), self._shard)
+    def _round_inputs(self, epochs: int) -> tuple:
+        """``(perm, mask, sel_idx)`` of the next round, on the device: the
+        host work a round does before its dispatch, in two phases a profiler
+        trace can tell apart (``p2pfl:round_perm`` draws, ``p2pfl:round_put``
+        transfers). Robust aggregators see only the [K] selected rows; K is
+        static per mask pattern, so the executable is reused as long as K
+        is stable."""
+        with host_annotation("round_perm"):
+            perm = self._make_perm_np(epochs)
+            eff = self._effective_mask()
+            sel = np.flatnonzero(eff).astype(np.int32)
+        with host_annotation("round_put"):
+            return (
+                jax.device_put(perm, self._shard),
+                jax.device_put(jnp.asarray(eff), self._shard),
+                jax.device_put(sel, self._repl),
+            )
 
     def _effective_mask(self) -> np.ndarray:
         """Train-set ∩ active nodes, optionally client-sampled per round."""
@@ -1048,14 +1071,7 @@ class SpmdFederation:
             # per-phase breakdown of the round about to run (train /
             # correction / aggregate) — stashed on self.last_profile
             self.profile_round(epochs)
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        # robust aggregators see only the [K] selected rows; K is static per
-        # mask pattern, so the executable is reused as long as K is stable
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
-        from p2pfl_tpu.management.profiling import dispatch_span
-
+        perm, mask, sel_idx = self._round_inputs(epochs)
         try:
             with dispatch_span("spmd_round", "spmd", nodes=self.n, epochs=epochs):
                 result = spmd_round(
@@ -1137,10 +1153,7 @@ class SpmdFederation:
 
         from p2pfl_tpu.management.profiling import force_execution
 
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
+        perm, mask, sel_idx = self._round_inputs(epochs)
         common = dict(
             module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
             clip_tau=self.clip_tau, out_sharding=self._shard,
@@ -1287,8 +1300,6 @@ class SpmdFederation:
         is computed on-device and returned in the history entries.
         """
         perms, mask, sel_idx = self._fused_inputs(rounds, epochs)
-        from p2pfl_tpu.management.profiling import dispatch_span
-
         try:
             with dispatch_span("spmd_rounds_fused", "spmd", nodes=self.n, rounds=rounds):
                 result = spmd_rounds_fused(
@@ -1340,10 +1351,7 @@ class SpmdFederation:
         """
         from p2pfl_tpu.management.profiling import compiled_flops
 
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
+        perm, mask, sel_idx = self._round_inputs(epochs)
         # algorithm knobs change the compiled program — MFU must count the
         # program that actually runs
         base = compiled_flops(

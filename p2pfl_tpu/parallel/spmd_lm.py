@@ -274,10 +274,7 @@ class SpmdLmFederation(SpmdFederation):
     def run_round(self, epochs: int = 1) -> dict:
         if self._vote and (self.round == 0 or Settings.VOTE_EVERY_ROUND):
             self.train_mask = self.elect_train_set()
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
+        perm, mask, sel_idx = self._round_inputs(epochs)
         self.params, self.opt_state, loss = spmd_lm_round(
             self.params,
             self.opt_state,

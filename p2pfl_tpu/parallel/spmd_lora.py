@@ -23,6 +23,7 @@ from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import adam, ce_eval
 from p2pfl_tpu.learning.lora import lora_train_epoch as _node_lora_epoch  # noqa: F401 (shared math)
 from p2pfl_tpu.learning.lora import _lm_loss, merge_params, split_lora
+from p2pfl_tpu.management.profiling import dispatch_span, scope
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.parallel.spmd import SpmdFederation, _aggregate
 
@@ -110,11 +111,13 @@ def _lora_round_core(
                     # recompute transformer activations in the backward
                     # instead of the scan storing every batch's (HBM↔FLOPs)
                     loss_of = jax.checkpoint(loss_of)
-                (loss, _), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                    lo_, bx, by
-                )
-                updates, o_ = tx.update(grads, o_, lo_)
-                lo_ = optax.apply_updates(lo_, updates)
+                with scope("grad"):
+                    (loss, _), grads = jax.value_and_grad(loss_of, has_aux=True)(
+                        lo_, bx, by
+                    )
+                with scope("optimizer"):
+                    updates, o_ = tx.update(grads, o_, lo_)
+                    lo_ = optax.apply_updates(lo_, updates)
                 return (lo_, o_), loss
 
             (lo, o), losses = jax.lax.scan(step, (lo, o), (xs, ys))
@@ -152,17 +155,18 @@ def _lora_round_core(
         m = mask.reshape((n,) + (1,) * (new.ndim - 1)).astype(new.dtype)
         return new * m + old * (1 - m)
 
-    used = jax.tree.map(sel, trained, stacked_lora)
-    agg_lora = _aggregate(used, mask, weights, sel_idx, agg, trim)
-    out = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n, *a.shape)), agg_lora)
-    if out_sharding is not None:
-        out = jax.tree.map(lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out)
-    out_opt = trained_opt if keep_opt_state else jax.vmap(tx.init)(out)
-    if out_sharding is not None:
-        out_opt = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_opt
-        )
-    return out, out_opt, jnp.mean(losses, where=mask.astype(bool))
+    with scope("fold"):  # everything after the last local step
+        used = jax.tree.map(sel, trained, stacked_lora)
+        agg_lora = _aggregate(used, mask, weights, sel_idx, agg, trim)
+        out = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n, *a.shape)), agg_lora)
+        if out_sharding is not None:
+            out = jax.tree.map(lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out)
+        out_opt = trained_opt if keep_opt_state else jax.vmap(tx.init)(out)
+        if out_sharding is not None:
+            out_opt = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_opt
+            )
+        return out, out_opt, jnp.mean(losses, where=mask.astype(bool))
 
 
 _LORA_STATICS = (
@@ -256,10 +260,7 @@ class SpmdLoraFederation(SpmdFederation):
     def _round_call(self, epochs: int) -> tuple[tuple, dict]:
         """(args, static kwargs) of the :func:`spmd_lora_round` dispatch for
         the next round — draws the round's batch permutation."""
-        perm = self._make_perm(epochs)
-        eff = self._effective_mask()
-        mask = jax.device_put(jnp.asarray(eff), self._shard)
-        sel_idx = jax.device_put(np.flatnonzero(eff).astype(np.int32), self._repl)
+        perm, mask, sel_idx = self._round_inputs(epochs)
         args = (
             self.params, self.opt_state, self.base, self.x_all, self.y_all,
             perm, mask, self._samples, sel_idx,
@@ -277,7 +278,8 @@ class SpmdLoraFederation(SpmdFederation):
         if self._vote and (self.round == 0 or Settings.VOTE_EVERY_ROUND):
             self.train_mask = self.elect_train_set()
         args, statics = self._round_call(epochs)
-        self.params, self.opt_state, loss = spmd_lora_round(*args, **statics)
+        with dispatch_span("spmd_lora_round", "spmd", nodes=self.n, epochs=epochs):
+            self.params, self.opt_state, loss = spmd_lora_round(*args, **statics)
         self.round += 1
         entry = {"round": self.round, "train_loss": loss}
         self.history.append(entry)
@@ -301,13 +303,14 @@ class SpmdLoraFederation(SpmdFederation):
         if eval:
             raise ValueError("SpmdLoraFederation.run_fused has no fused eval; call evaluate()")
         perms, mask, sel_idx = self._fused_inputs(rounds, epochs)
-        self.params, self.opt_state, losses = spmd_lora_rounds_fused(
-            self.params, self.opt_state, self.base, self.x_all, self.y_all,
-            perms, mask, self._samples, sel_idx,
-            module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
-            out_sharding=self._shard, keep_opt_state=self.keep_opt_state,
-            remat=self.remat, node_chunk=self.node_chunk,
-        )
+        with dispatch_span("spmd_lora_rounds_fused", "spmd", nodes=self.n, rounds=rounds):
+            self.params, self.opt_state, losses = spmd_lora_rounds_fused(
+                self.params, self.opt_state, self.base, self.x_all, self.y_all,
+                perms, mask, self._samples, sel_idx,
+                module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
+                out_sharding=self._shard, keep_opt_state=self.keep_opt_state,
+                remat=self.remat, node_chunk=self.node_chunk,
+            )
         entries = []
         for r in range(rounds):
             self.round += 1

@@ -113,17 +113,18 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
     updates = []
     monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
     keep_all = ("jax_persistent_cache_min_compile_time_secs", 0.0)
+    by_name = ("jax_compilation_cache_include_metadata_in_key", True)  # scopes are part of the key
     # set: JAX reads the variable itself, the helper sets no directory in code
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.configure_compile_cache() == before
-    assert updates == [keep_all]
+    assert updates == [keep_all, by_name]
     # unset: one fixed path under the checkout, the same on every call
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     del updates[:]
     compile_cache.configure_compile_cache()
     compile_cache.configure_compile_cache()
     placed = ("jax_compilation_cache_dir", str(compile_cache.DEFAULT_CACHE_DIR))
-    assert updates == [placed, keep_all] * 2
+    assert updates == [placed, keep_all, by_name] * 2
     assert compile_cache.DEFAULT_CACHE_DIR == Path(chip_smoke.__file__).resolve().parent / ".jax_cache"
 
 

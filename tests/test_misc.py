@@ -53,11 +53,11 @@ def test_stopwatch_sections():
 
 
 def test_profiler_trace_writes_files(tmp_path):
-    from p2pfl_tpu.management.profiling import annotate, trace
+    from p2pfl_tpu.management.profiling import scope, trace
 
     d = str(tmp_path / "trace")
     with trace(d):
-        with annotate("matmul", step=1):
+        with jax.profiler.TraceAnnotation("p2pfl:matmul"), scope("grad"):
             x = jnp.ones((64, 64))
             jax.block_until_ready(x @ x)
     assert glob.glob(d + "/**/*.pb", recursive=True) or glob.glob(
