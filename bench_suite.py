@@ -528,10 +528,10 @@ def _config3_measure(n_nodes: int) -> None:
     if chunked:
         flops = fed.round_flops()
         round_mfu = _mfu_from(flops, sec_per_round)
-        # EXECUTED flops (remat recompute included) — the numerator the
-        # resident SpmdFederation probes report; chunked-vs-resident MFU
-        # is only comparable on this one (VERDICT r4 #4: the round-4 "2×
-        # MFU gap" compared chunked model-flops against resident hw-flops)
+        # EXECUTED flops: equal to the model's since the whole-loss
+        # checkpoint left the step (no recompute to count); the key stays
+        # for the row's readers (VERDICT r4 #4: the round-4 "2× MFU gap"
+        # compared chunked model-flops against resident hw-flops)
         flops_hw = fed.round_flops(hw=True)
         mfu_hw = _mfu_from(flops_hw, sec_per_round)
         # before/after split for the round-pipeline overhaul: the SERIAL
@@ -577,8 +577,8 @@ def _config3_measure(n_nodes: int) -> None:
                   f"(moment-averaged when chunked), batch {batch}, remat",
         "flops_per_round": flops,
         "mfu": round(round_mfu, 4) if round_mfu is not None else None,
-        # executed-flops utilization (remat recompute counted), the number
-        # comparable with the resident folds' probes
+        # executed-flops utilization; the step recomputes nothing now, so
+        # this reads the same as ``mfu``
         "mfu_hw": round(mfu_hw, 4) if mfu_hw is not None else None,
         # serial vs overlapped chunk pipeline (the round-6 overhaul:
         # fused on-device accumulators + staged-ahead chunk inputs)
@@ -586,8 +586,9 @@ def _config3_measure(n_nodes: int) -> None:
         "gap_attribution": (
             "round-4's '2x MFU gap' vs the 16-node resident proxy was "
             "mostly accounting (chunked reported model flops, resident "
-            "executed flops incl. remat): executed-basis this row runs "
-            "~20% vs resident 21%. The per-chunk staging delta (broadcast "
+            "executed flops incl. the second forward of a whole-loss "
+            "jax.checkpoint, which no step runs any more — mfu_hw now equals "
+            "mfu): executed-basis this row ran ~20% vs resident 21%. The per-chunk staging delta (broadcast "
             "aggregate + fp32 reduce serialized behind compute) is now "
             "measured directly by staging_split: the overlapped path folds "
             "the reduce into the chunk program (donated accumulators) and "

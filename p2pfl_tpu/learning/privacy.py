@@ -42,19 +42,15 @@ def clip_by_global_norm(grads: Pytree, clip: float) -> Pytree:
     return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), grads)
 
 
-def dp_grads(loss_one, params, x, y, clip: float, noise: float, key, remat: bool = False):
+def dp_grads(loss_one, params, x, y, clip: float, noise: float, key):
     """Per-example clipped + noised mean gradient (the DP-SGD estimator).
 
     ``loss_one(params, x_i, y_i) -> scalar`` is the single-example loss;
-    ``x``/``y`` carry the batch dim. ``remat`` rematerializes each
-    example's backward (per-example grads store activations for the whole
-    batch otherwise — the HBM↔FLOPs trade big models need). Returns
+    ``x``/``y`` carry the batch dim. Returns
     ``(grads, mean_loss)`` — the pre-update loss comes free from the grad
     pass, matching what the non-DP paths report.
     """
     batch = x.shape[0]
-    if remat:
-        loss_one = jax.checkpoint(loss_one)
 
     def one(xi, yi):
         loss, g = jax.value_and_grad(loss_one)(params, xi, yi)

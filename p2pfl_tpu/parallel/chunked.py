@@ -72,7 +72,7 @@ def _is_inexact(x) -> bool:
     return jnp.issubdtype(x.dtype, jnp.inexact)
 
 
-def _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx, remat):
+def _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx):
     """One chunk's round contribution (trace-time body).
 
     Broadcast the aggregate to C slots, run each slot's scan-epochs, and
@@ -89,7 +89,7 @@ def _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx, r
             p_, o_ = carry
             xs = jnp.take(x_, ep_idx, axis=0)
             ys = jnp.take(y_, ep_idx, axis=0)
-            p_, o_, loss = _local_epoch(p_, o_, xs, ys, module, tx, remat)
+            p_, o_, loss = _local_epoch(p_, o_, xs, ys, module, tx)
             return (p_, o_), loss
 
         (p, o), losses = lax.scan(epoch, (p, o), idx)
@@ -111,19 +111,19 @@ def _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx, r
     return psum, osum, jnp.sum(w), loss
 
 
-@partial(jax.jit, static_argnames=("module", "tx", "remat"))
-def _chunk_round(agg_params, agg_opt, x, y, perm, mask, weights, *, module, tx, remat):
+@partial(jax.jit, static_argnames=("module", "tx"))
+def _chunk_round(agg_params, agg_opt, x, y, perm, mask, weights, *, module, tx):
     """Serial-path chunk program: contribution only, reduce on host.
 
     Kept verbatim as the reference semantics — the overlapped path's
     bit-parity test (tests/test_chunked.py) compares against it.
     """
-    return _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx, remat)
+    return _chunk_contrib(agg_params, agg_opt, x, y, perm, mask, weights, module, tx)
 
 
 def _chunk_round_acc_impl(
     psum, osum, wsum, loss_sum, agg_params, agg_opt, x, y, perm, mask, weights,
-    *, module, tx, remat,
+    *, module, tx,
 ):
     """Fused-reduce chunk program: train the chunk AND fold its weighted
     contribution into the running accumulators in the same dispatch.
@@ -135,7 +135,7 @@ def _chunk_round_acc_impl(
     through.
     """
     p_c, o_c, w_c, l_c = _chunk_contrib(
-        agg_params, agg_opt, x, y, perm, mask, weights, module, tx, remat
+        agg_params, agg_opt, x, y, perm, mask, weights, module, tx
     )
     psum = jax.tree.map(jnp.add, psum, p_c)
     osum = jax.tree.map(
@@ -148,10 +148,10 @@ def _chunk_round_acc_impl(
 # buffers (no fresh full-model allocation per chunk); the plain variant is
 # the CHUNK_DONATE_BUFFERS=False debugging path
 _chunk_round_acc_donated = partial(
-    jax.jit, static_argnames=("module", "tx", "remat"), donate_argnums=(0, 1, 2, 3)
+    jax.jit, static_argnames=("module", "tx"), donate_argnums=(0, 1, 2, 3)
 )(_chunk_round_acc_impl)
 _chunk_round_acc_plain = partial(
-    jax.jit, static_argnames=("module", "tx", "remat")
+    jax.jit, static_argnames=("module", "tx")
 )(_chunk_round_acc_impl)
 
 
@@ -204,7 +204,8 @@ class ChunkedFederation:
     """N-node FedAvg federation streamed through the chip ``chunk_size``
     nodes at a time. Same round semantics as :class:`SpmdFederation`
     (reference round loop, §3.3) except the moment-averaging divergence
-    documented in the module docstring."""
+    documented in the module docstring. ``remat`` is accepted and has no
+    effect on the step (see :class:`SpmdFederation`)."""
 
     def __init__(
         self,
@@ -398,7 +399,7 @@ class ChunkedFederation:
             for i, ci in enumerate(live):
                 acc = step(
                     *acc, self.params, self.opt_state, *chunk_args(ci),
-                    module=self.module, tx=self.tx, remat=self.remat,
+                    module=self.module, tx=self.tx,
                 )
                 if i + depth < len(live):
                     staged[live[i + depth]] = self._stage_chunk_inputs(
@@ -419,7 +420,7 @@ class ChunkedFederation:
             for i, ci in enumerate(live):
                 p_c, o_c, w_c, l_c = _chunk_round(
                     self.params, self.opt_state, *chunk_args(ci),
-                    module=self.module, tx=self.tx, remat=self.remat,
+                    module=self.module, tx=self.tx,
                 )
                 if i + depth < len(live):
                     staged[live[i + depth]] = self._stage_chunk_inputs(
@@ -472,11 +473,8 @@ class ChunkedFederation:
     def round_flops(self, epochs: int = 1, hw: bool = False) -> Optional[float]:
         """Scan-aware FLOPs of one full round (all N nodes).
 
-        ``hw=False``: model FLOPs (no remat recompute) — the useful-work
-        numerator. ``hw=True``: the step probed WITH the round's actual
-        ``jax.checkpoint``, so XLA's count includes the recompute — the
-        executed-work numerator the resident SpmdFederation probes report
-        (config 3's chunked-vs-resident MFU is only comparable on this one).
+        The step recomputes nothing, so the executed FLOPs are the model's:
+        ``hw`` is accepted and changes nothing.
         """
         from p2pfl_tpu.management.profiling import compiled_flops
 
@@ -484,8 +482,6 @@ class ChunkedFederation:
             def loss_fn(p_):
                 return _loss(p_, self.module, bx, by)[0]
 
-            if hw and self.remat:
-                loss_fn = jax.checkpoint(loss_fn)
             loss, grads = jax.value_and_grad(loss_fn)(p)
             updates, o = self.tx.update(grads, o, p)
             return optax.apply_updates(p, updates), o, loss

@@ -54,17 +54,16 @@ Pytree = Any
 
 
 def _local_epoch(
-    params, opt_state, xs, ys, module, tx, remat: bool = False,
+    params, opt_state, xs, ys, module, tx,
     prox_mu: float = 0.0, anchor=None, corr=None,
     dp_clip: float = 0.0, dp_noise: float = 0.0, key=None,
     accumulate_grads: bool = False,
 ):
     """One node's epoch: scan of SGD steps (identical math to JaxLearner).
 
-    ``remat=True`` wraps the loss in :func:`jax.checkpoint`: the backward
-    pass recomputes activations instead of the scan storing every batch's —
-    the HBM↔FLOPs trade that lets big models (ResNet-50 × many nodes) train
-    on one chip.
+    The gradient is taken inside each scan step, so no activation outlives
+    its step: memory is traded in the model (``TransformerConfig.remat``),
+    never by a checkpoint around the loss here.
 
     ``prox_mu``/``anchor``: FedProx proximal pull toward the round's global
     model. ``corr``: SCAFFOLD control-variate correction ``c − c_i`` added
@@ -98,7 +97,7 @@ def _local_epoch(
             x, y = batch
             k, sub = jax.random.split(k)
             with scope("grad"):
-                grads, loss = dp_grads(loss_one, p, x, y, dp_clip, dp_noise, sub, remat=remat)
+                grads, loss = dp_grads(loss_one, p, x, y, dp_clip, dp_noise, sub)
             with scope("optimizer"):
                 if accumulate_grads:
                     gs = jax.tree.map(lambda s, g: s + g.astype(jnp.float32), gs, grads)
@@ -125,8 +124,6 @@ def _local_epoch(
                 loss = loss + _prox_term(p_, anchor, prox_mu)
             return loss
 
-        if remat:
-            loss_fn = jax.checkpoint(loss_fn)
         with scope("grad"):
             loss, grads = jax.value_and_grad(loss_fn)(p)
         with scope("optimizer"):
@@ -182,7 +179,7 @@ def _node_round_core(
         p, o = carry
         exs, eys = batch
         p, o, loss = _local_epoch(
-            p, o, exs, eys, module, tx, False, prox_mu=prox_mu, anchor=anchor
+            p, o, exs, eys, module, tx, prox_mu=prox_mu, anchor=anchor
         )
         return (p, o), loss
 
@@ -347,7 +344,6 @@ def _round_core(
     clip_tau: float = 1.0,
     out_sharding=None,
     keep_opt_state: bool = False,
-    remat: bool = False,
     prox_mu: float = 0.0,
     scaffold: bool = False,
     scaffold_fused_ci: bool = True,  # ci⁺ from the scan's grad mean (fast path)
@@ -406,7 +402,7 @@ def _round_core(
             if dp_clip > 0.0:
                 k, sub = jax.random.split(k)
             out = _local_epoch(
-                p, o, xs, ys, module, tx, remat,
+                p, o, xs, ys, module, tx,
                 prox_mu=prox_mu, anchor=anchor, corr=corr,
                 dp_clip=dp_clip, dp_noise=dp_noise, key=sub,
                 accumulate_grads=fused_ci,
@@ -562,7 +558,7 @@ def _agg_acc(module, agg_params, x_test, y_test):
 _ROUND_STATICS = (
     # clip_tau is deliberately NOT static: it traces as a scalar operand
     # (ops.centered_clip takes tau traced), so tuning it never recompiles
-    "module", "tx", "agg", "trim", "out_sharding", "keep_opt_state", "remat",
+    "module", "tx", "agg", "trim", "out_sharding", "keep_opt_state",
     "prox_mu", "scaffold", "scaffold_fused_ci", "local_lr", "server_opt",
     "server_lr", "dp_clip", "dp_noise",
 )
@@ -581,7 +577,7 @@ _ROUND_DONATED_STATE = ("c_global", "c_local", "opt_m", "opt_v")
 def spmd_round(
     stacked_params, opt_states, x_all, y_all, perm, mask, weights, sel_idx,
     *, c_global=None, c_local=None, opt_m=None, opt_v=None,
-    x_test=None, y_test=None, **kw,
+    x_test=None, y_test=None, remat=None, **kw,
 ):
     """One federated round for all N nodes.
 
@@ -589,7 +585,8 @@ def spmd_round(
     opt_v'][, test acc]) — the accuracy of the aggregated model is fused
     into the same program when test data is given (one device dispatch for
     train + aggregate + diffuse + eval). See :func:`_round_core` for the
-    algorithm knobs.
+    algorithm knobs. ``remat`` is ignored (an unused operand, pruned from
+    the program): ``benchmark/compile_check.py`` still passes it.
     """
     out_params, out_opt, mean_loss, scaffold_state, fedopt_state, agg_params = _round_core(
         stacked_params, opt_states, x_all, y_all, perm, mask, weights, sel_idx,
@@ -771,6 +768,11 @@ class SpmdFederation:
 
     The drop-in high-throughput alternative to running N ``Node`` objects:
     same round semantics, same aggregators, none of the per-message overhead.
+
+    ``remat`` is accepted and has no effect on the step: the gradient is
+    taken inside each scan step, where a checkpoint around the whole loss
+    only ran the forward twice. Activation memory is traded in the model
+    (``TransformerConfig.remat`` / ``remat_policy``).
     """
 
     def __init__(
@@ -1090,7 +1092,6 @@ class SpmdFederation:
                     clip_tau=self.clip_tau,
                     out_sharding=self._shard,
                     keep_opt_state=self.keep_opt_state,
-                    remat=self.remat,
                     x_test=self.x_test if eval else None,
                     y_test=self.y_test if eval else None,
                     dp_keys=self._dp_round_keys(),
@@ -1157,7 +1158,7 @@ class SpmdFederation:
         common = dict(
             module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
             clip_tau=self.clip_tau, out_sharding=self._shard,
-            keep_opt_state=self.keep_opt_state, remat=self.remat,
+            keep_opt_state=self.keep_opt_state,
         )
 
         def timed(algo_kw: dict) -> float:
@@ -1307,7 +1308,6 @@ class SpmdFederation:
                     self._samples, sel_idx,
                     module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim, clip_tau=self.clip_tau,
                     out_sharding=self._shard, keep_opt_state=self.keep_opt_state,
-                    remat=self.remat,
                     x_test=self.x_test if eval else None,
                     y_test=self.y_test if eval else None,
                     dp_keys=self._dp_round_keys(rounds),
@@ -1360,7 +1360,6 @@ class SpmdFederation:
             self._samples, sel_idx,
             module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim, clip_tau=self.clip_tau,
             out_sharding=self._shard, keep_opt_state=self.keep_opt_state,
-            remat=self.remat,
             dp_keys=self._dp_round_keys(),
             **self._algo_kwargs(self._server_t + 1 if self.server_opt else 0),
         )
@@ -1376,8 +1375,7 @@ class SpmdFederation:
 
         ``loss_fn(params, bx, by) -> scalar``. Shared by the LoRA and
         full-LM federations' ``round_flops`` (scan-trip-count pitfall: the
-        probe is scan-free, so cost analysis counts it exactly once);
-        honors ``remat`` so recompute shows up the same way it executes.
+        probe is scan-free, so cost analysis counts it exactly once).
         """
         import optax
 
@@ -1397,8 +1395,7 @@ class SpmdFederation:
         )
 
         def one_step(p, o, bx_, by_):
-            lf = jax.checkpoint(loss_fn) if self.remat else loss_fn
-            _loss, grads = jax.value_and_grad(lf)(p, bx_, by_)
+            _loss, grads = jax.value_and_grad(loss_fn)(p, bx_, by_)
             updates, o = self.tx.update(grads, o, p)
             return optax.apply_updates(p, updates), o
 
@@ -1407,7 +1404,7 @@ class SpmdFederation:
     def _single_step_flops(self) -> Optional[float]:
         """Compiled FLOPs of ONE node's ONE SGD step (trip-count-1 scan, so
         the cost analysis counts it exactly once). Mirrors the round's
-        per-step math including remat/FedProx/DP variants."""
+        per-step math including FedProx/DP variants."""
         from p2pfl_tpu.management.profiling import compiled_flops
 
         def one(a):
@@ -1426,7 +1423,7 @@ class SpmdFederation:
         def one_epoch(p, o, xs_, ys_, key=None):
             anchor = p if (self.prox_mu > 0.0 or self.scaffold) else None
             return _local_epoch(
-                p, o, xs_, ys_, self.module, self.tx, self.remat,
+                p, o, xs_, ys_, self.module, self.tx,
                 prox_mu=self.prox_mu, anchor=anchor,
                 dp_clip=self.dp_clip, dp_noise=self.dp_noise, key=key,
             )

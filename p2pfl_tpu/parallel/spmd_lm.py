@@ -69,7 +69,6 @@ def _lm_round_core(
     trim: int = 0,
     out_sharding=None,
     keep_opt_state: bool = False,
-    remat: bool = False,
 ):
     """Trace-time body: local scan-epochs per node, then masked aggregation.
 
@@ -97,8 +96,6 @@ def _lm_round_core(
                     ).mean()
                     return ce + aux
 
-                if remat:
-                    loss_of = jax.checkpoint(loss_of)
                 loss, grads = jax.value_and_grad(loss_of)(p__, bx, by)
                 updates, o__ = tx.update(grads, o__, p__)
                 return (optax.apply_updates(p__, updates), o__), loss
@@ -129,7 +126,7 @@ def _lm_round_core(
     return out, out_opt, jnp.mean(losses, where=mask.astype(bool))
 
 
-_LM_STATICS = ("module", "tx", "agg", "trim", "out_sharding", "keep_opt_state", "remat")
+_LM_STATICS = ("module", "tx", "agg", "trim", "out_sharding", "keep_opt_state")
 
 
 @partial(jax.jit, static_argnames=_LM_STATICS, donate_argnums=(0, 1))
@@ -290,7 +287,6 @@ class SpmdLmFederation(SpmdFederation):
             trim=self.trim,
             out_sharding=self._out_sharding_static(),
             keep_opt_state=self.keep_opt_state,
-            remat=self.remat,
         )
         self.round += 1
         entry = {"round": self.round, "train_loss": loss}
@@ -305,7 +301,7 @@ class SpmdLmFederation(SpmdFederation):
             perms, mask, self._samples, sel_idx,
             module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
             out_sharding=self._out_sharding_static(),
-            keep_opt_state=self.keep_opt_state, remat=self.remat,
+            keep_opt_state=self.keep_opt_state,
         )
         entries = []
         for r in range(rounds):
