@@ -8,7 +8,9 @@ non-zero. Phases, each through the public entry points:
 
 - **kernels** (untimed): flash-attention forward AND backward, compiled by
   Mosaic, against fp32 dense attention at the full-width model's shape and
-  at the corners the shipped block selection can produce;
+  at the corners the shipped block selection can produce; the selective
+  scan's forward and backward kernels against the op's plain-XLA path at
+  4096 x 5120 channels x 16 states;
 - **A** the ``bench.py`` path: 64-node MNIST ``SpmdFederation``, fused rounds;
 - **B** the full-width model: TinyLlama-1.1B widths, all 22 layers, LoRA
   federation through ``SpmdLoraFederation`` with the compiled flash kernels;
@@ -58,6 +60,13 @@ FLASH_SHAPES = ((1, SEQ_LEN, 32, 64), (1, 4096, 2, 128), (1, 8192, 2, 128))
 # operand); the kernels round P and dS to bf16 before the MXU.
 FLASH_TOL_FWD = 2e-2
 FLASH_TOL_BWD = 4e-2
+# (B, T, channels, states) of the selective-scan check: one sequence at the
+# widths of the benchmark's hybrid configuration (4096 x 5120 x 16)
+SCAN_SHAPES = ((1, 4096, 5120, 16),)
+# |kernels − XLA path| / |XLA path| (L2) of the output and each gradient: both
+# compute in float32 and differ in summation order and in the bf16 rounding of
+# outputs (read on the chip at PR 27: 1e-4 to 3e-4)
+SCAN_TOL = 2e-3
 
 
 def say(msg: str) -> None:
@@ -196,8 +205,56 @@ def check_flash(b: int, t: int, h: int, d: int, *, interpret: bool) -> dict:
     return res
 
 
-def phase_kernels(shapes, *, interpret: bool) -> dict:
-    return {"checks": [check_flash(*shape, interpret=interpret) for shape in shapes]}
+def check_scan(b: int, t: int, dm: int, n: int, *, interpret: bool) -> dict:
+    """The selective scan's Pallas kernels (forward and backward) at
+    ``[b, t, dm]`` channels and ``n`` states against the op's plain-XLA path on
+    the same inputs — output and all seven gradients — and the XLA path
+    against the recurrence one token at a time on a 512-token prefix."""
+    from p2pfl_tpu.ops import selective_scan as ss
+
+    keys = jax.random.split(jax.random.PRNGKey(t + dm), 7)
+    bf16 = lambda k, shape: jax.random.normal(k, shape).astype(jnp.bfloat16)  # noqa: E731
+    u, z, w = bf16(keys[0], (b, t, dm)), bf16(keys[1], (b, t, dm)), bf16(keys[2], (b, t, dm))
+    # step sizes as a seeded Mamba layer has them: log-uniform in [1e-3, 1e-1], jittered per token
+    base = jnp.exp(jax.random.uniform(keys[3], (dm,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    delta = jax.nn.softplus(jnp.log(jnp.expm1(base)) + 0.5 * jax.random.normal(keys[4], (b, t, dm)))
+    a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (dm, n))
+    args = (u, delta, a, bf16(keys[5], (b, t, n)), bf16(keys[6], (b, t, n)), jnp.ones((dm,)), z)
+
+    def with_grads(impl):
+        def loss(*xs):
+            out = ss.selective_scan(*xs, impl=impl).astype(jnp.float32)
+            return jnp.sum(out * w), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)), has_aux=True))
+
+    kernels = with_grads("pallas")
+    mosaic_calls = kernels.lower(*args).as_text().count("tpu_custom_call")
+    check((mosaic_calls == 2) != interpret, f"{mosaic_calls} Mosaic calls, interpret={interpret}")
+    (_, out), grads = kernels(*args)
+    (_, ref_out), ref_grads = with_grads("xla")(*args)
+
+    def rel_err(got, want) -> float:
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        check(bool(np.isfinite(got).all()), "non-finite scan output")
+        return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+
+    errs = {"y": rel_err(out, ref_out)}
+    errs.update({f"d{name}": rel_err(g, r) for name, g, r in zip(("u", "delta", "A", "B", "C", "D", "z"), grads, ref_grads)})
+    prefix = tuple(x[:, :512] if x.ndim == 3 else x for x in args)
+    errs["xla_vs_loop"] = rel_err(ss.selective_scan(*prefix, impl="xla"), jax.jit(ss.selective_scan_reference)(*prefix))
+    res = {"shape": [b, t, dm, n], "mosaic_calls": mosaic_calls, "rel_err": {k: float(f"{e:.3g}") for k, e in errs.items()}}
+    say(f"scan check: {json.dumps(res)}")
+    worst = max(errs.values())
+    check(worst <= SCAN_TOL, f"selective scan error {worst:.3g} > {SCAN_TOL}")
+    return res
+
+
+def phase_kernels(shapes, *, interpret: bool, scan_shapes=()) -> dict:
+    return {
+        "checks": [check_flash(*shape, interpret=interpret) for shape in shapes],
+        "scan_checks": [check_scan(*shape, interpret=interpret) for shape in scan_shapes],
+    }
 
 
 # ---- phase A: the bench.py path --------------------------------------------
@@ -396,7 +453,7 @@ def main() -> int:
     t0 = time.monotonic()
     report = {}
     report["kernels"] = run_phase(
-        "kernels", clock, phase_kernels, shapes=FLASH_SHAPES, interpret=False
+        "kernels", clock, phase_kernels, shapes=FLASH_SHAPES, interpret=False, scan_shapes=SCAN_SHAPES
     )
     sources = {c["config_source"] for c in report["kernels"]["checks"]}
     check(sources == {"defaults"}, f"flash config from outside the checkout: {sources}")

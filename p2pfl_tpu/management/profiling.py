@@ -47,6 +47,9 @@ DEVICE_SCOPES = (
     "adapter",  # LoRADense: (x @ A) @ B
     "flash_fwd",  # ops/flash_attention: the forward Mosaic call
     "flash_bwd",  # ops/flash_attention: the backward Mosaic call(s)
+    "ssm_conv",  # MambaMixer: the causal depthwise convolution and its silu
+    "ssm_scan_fwd",  # ops/selective_scan: everything the op runs forward (kernel and glue)
+    "ssm_scan_bwd",  # ops/selective_scan: everything its backward runs
 )
 
 

@@ -1,10 +1,19 @@
-"""Decoder-only transformer (TinyLlama-style) with LoRA adapters.
+"""Decoder-only language models with LoRA adapters.
 
 BASELINE config 5: federated LoRA fine-tuning — nodes train and exchange
 ONLY the low-rank adapters, so a round's gossip payload drops from the full
-model to a few MB. Architecture follows the Llama recipe (RMSNorm → GQA
-attention with RoPE → SwiGLU), all matmuls in bfloat16 on the MXU, norms and
-softmax statistics in float32.
+model to a few MB. A block is pre-RMSNorm → sequence mixer → residual, then
+pre-RMSNorm → SwiGLU (or MoE) → residual; all matmuls in bfloat16 on the MXU,
+norms, softmax statistics and the state-space recurrence in float32.
+
+Sequence mixers — ``TransformerConfig.layer_pattern`` names one per layer of a
+period, and the stack repeats the period:
+
+- ``"attention"`` (the default, alone: the Llama recipe): grouped-query causal
+  attention, with RoPE unless ``rope_theta`` is ``None``;
+- ``"mamba"``: the Mamba-1 mixer (:class:`MambaMixer`) — causal depthwise
+  convolution, input-dependent step sizes, and the selective scan of
+  ``ops/selective_scan.py`` (the Jamba hybrids interleave it with attention).
 
 Attention backends — pick with ``tiny_transformer(attn=...)``:
 
@@ -19,6 +28,7 @@ Power users can instead pass any ``attn_fn(q, k, v) -> out`` directly.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable, Optional
@@ -34,10 +44,15 @@ from p2pfl_tpu.ops.attention import causal_attention
 from p2pfl_tpu.ops.flash_attention import FlashConfig
 
 
+_SSM_OUT = ("ssm_y", "ssm_state")  # the scan's output and its boundary states
 _REMAT_SAVE_NAMES = {
     "mlp": ("ffn_gate", "ffn_up"),
     "mlp_qkv": ("ffn_gate", "ffn_up", "attn_q", "attn_k", "attn_v"),
+    "ssm": ("attn_q", "attn_k", "attn_v", *_SSM_OUT),
+    "mlp_ssm": ("ffn_gate", "ffn_up", "attn_q", "attn_k", "attn_v", *_SSM_OUT),
+    "mlp_ssm_in": ("ffn_gate", "ffn_up", "attn_q", "attn_k", "attn_v", *_SSM_OUT, "ssm_in", "ssm_dt"),
 }
+LAYER_KINDS = ("attention", "mamba")
 
 
 def _remat_policy(name: Optional[str]):
@@ -61,7 +76,20 @@ class TransformerConfig:
     n_heads: int = 8
     n_kv_heads: int = 4
     ffn_hidden: int = 688  # ~8/3 * dim rounded
-    rope_theta: float = 10000.0
+    # None = no positional rotation at all (Jamba's attention layers: the
+    # state-space layers around them carry position)
+    rope_theta: Optional[float] = 10000.0
+    # One period of the layer stack, a sequence mixer per layer
+    # (``"attention"`` | ``"mamba"``); ``n_layers`` must be a multiple of its
+    # length. Jamba: 14 long, attention at index 7.
+    layer_pattern: tuple = ("attention",)
+    # Mamba-1 widths (used by ``"mamba"`` layers only): inner width
+    # ``ssm_expand * dim``, state per channel, depthwise-conv kernel, and the
+    # rank of the step-size projection (None = ceil(dim / 16), Mamba's rule)
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: Optional[int] = None
     lora_rank: int = 8
     lora_alpha: float = 16.0
     lora_mlp: bool = False
@@ -92,6 +120,13 @@ class TransformerConfig:
     #                so 2·kv_heads·head_dim + dim per token): the backward
     #                recomputes only the flash kernel forward (for its lse
     #                residual) and elementwise glue.
+    #   "ssm"      — q/k/v as above, and of a Mamba layer the selective
+    #                scan's output and chunk-boundary states: the re-forward
+    #                runs the projections and the convolution again (the
+    #                scan's backward needs them) but NO scan;
+    #   "mlp_ssm"  — "mlp_qkv" + "ssm";
+    #   "mlp_ssm_in" — additionally the Mamba in-projection's output and the
+    #                step sizes (the re-forward skips its two largest matmuls).
     # Memory cost per token-layer (bf16): mlp = 2·ffn_hidden, mlp_qkv adds
     # dim + 2·(kv/heads)·dim. Pick the richest policy that fits HBM —
     # bench config5_nameplate_1b measures the ladder at 0.98B.
@@ -117,6 +152,14 @@ class TransformerConfig:
     flash_config: Optional[FlashConfig] = None
 
     def __post_init__(self) -> None:
+        pattern = tuple(self.layer_pattern)
+        object.__setattr__(self, "layer_pattern", pattern)  # a list would not hash
+        if not pattern or any(kind not in LAYER_KINDS for kind in pattern):
+            raise ValueError(f"layer_pattern {pattern!r}: one or more of {LAYER_KINDS}")
+        if self.n_layers % len(pattern):
+            raise ValueError(
+                f"n_layers {self.n_layers} is not a whole number of periods of {len(pattern)} layers"
+            )
         if self.remat_policy is not None:
             _remat_policy(self.remat_policy)  # raises on an unknown name
             if not self.remat:
@@ -196,9 +239,11 @@ class Attention(nn.Module):
         k = dense(cfg.n_kv_heads * head_dim, name="wk")(x)
         v = dense(cfg.n_kv_heads * head_dim, name="wv")(x)
         b, t = x.shape[:2]
-        q = rope(q.reshape(b, t, cfg.n_heads, head_dim), cfg.rope_theta)
-        k = rope(k.reshape(b, t, cfg.n_kv_heads, head_dim), cfg.rope_theta)
+        q = q.reshape(b, t, cfg.n_heads, head_dim)
+        k = k.reshape(b, t, cfg.n_kv_heads, head_dim)
         v = v.reshape(b, t, cfg.n_kv_heads, head_dim)
+        if cfg.rope_theta is not None:
+            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         # selective-remat tags: saved pre-GQA-repeat (kv_heads wide, the
         # repeat is a cheap broadcast to recompute)
         q = checkpoint_name(q, "attn_q")
@@ -341,15 +386,90 @@ class MoEMLP(nn.Module):
         return out.reshape(b, t, d)
 
 
-class Block(nn.Module):
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba's own: the bias whose softplus is log-uniform in [1e-3, 1e-1].
+    (A zero-mean bias gives steps near 0.7, every decay collapses within a few
+    tokens and the scan carries nothing.)"""
+    lo, hi = math.log(1e-3), math.log(1e-1)
+    dt = jnp.maximum(jnp.exp(lo + (hi - lo) * jax.random.uniform(key, shape, dtype)), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -(1..N)`` on every channel (S4D-real), kept as its log."""
+    return jnp.log(jnp.broadcast_to(jnp.arange(1, shape[1] + 1, dtype=dtype), shape))
+
+
+def causal_depthwise_conv(u: jax.Array, kernel: jax.Array, bias: jax.Array) -> jax.Array:
+    """``out[t] = bias + Σ_k kernel[k] · u[t − (K−1) + k]`` per channel, zeros
+    before the sequence; ``u`` is ``[B, T, C]``, ``kernel`` ``[K, C]``. Float32."""
+    taps, t = kernel.shape[0], u.shape[1]
+    padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for k in range(taps):
+        out = out + kernel[k].astype(jnp.float32) * padded[:, k:k + t]
+    return out
+
+
+class MambaMixer(nn.Module):
+    """The Mamba-1 sequence mixer as Jamba runs it (inner RMSNorms on the step
+    size, ``B`` and ``C``)::
+
+        [u, z] = x W_in;  u = silu(causal_depthwise_conv(u) + b_conv)
+        [δ, B, C] = u W_x;  δ, B, C = norm(δ), norm(B), norm(C)
+        Δ = softplus(δ W_dt + b_dt);  A = −exp(A_log)
+        y = selective_scan(u, Δ, A, B, C, D, z);  out = y W_out
+
+    ``in_proj``, ``x_proj`` and ``out_proj`` carry adapters; ``dt_proj`` does
+    not. ``A_log``, ``D``, the convolution, ``dt_bias`` and the inner norms are
+    base leaves: frozen under LoRA and outside its FedAvg.
+    """
+
     cfg: TransformerConfig
-    attn_fn: Optional[Callable] = None
 
     @nn.compact
     def __call__(self, x):
-        x = x + Attention(self.cfg, self.attn_fn, name="attn")(
-            RMSNorm(self.cfg.dtype, name="attn_norm")(x)
+        from p2pfl_tpu.ops.selective_scan import selective_scan
+
+        cfg = self.cfg
+        inner, n = cfg.ssm_expand * cfg.dim, cfg.ssm_state
+        dt_rank = cfg.ssm_dt_rank or -(-cfg.dim // 16)
+        dense = partial(LoRADense, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
+        xz = checkpoint_name(dense(2 * inner, name="in_proj")(x), "ssm_in")
+        u, z = xz[..., :inner], xz[..., inner:]
+        kernel = self.param(
+            "conv_kernel", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+            (cfg.ssm_conv, inner),
         )
+        conv_bias = self.param("conv_bias", nn.initializers.zeros, (inner,))
+        with scope("ssm_conv"):
+            u = nn.silu(causal_depthwise_conv(u, kernel, conv_bias)).astype(cfg.dtype)
+        dbc = dense(dt_rank + 2 * n, name="x_proj")(u)
+        dt = RMSNorm(cfg.dtype, name="dt_norm")(dbc[..., :dt_rank])
+        b = RMSNorm(cfg.dtype, name="b_norm")(dbc[..., dt_rank:dt_rank + n])
+        c = RMSNorm(cfg.dtype, name="c_norm")(dbc[..., dt_rank + n:])
+        dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
+        delta = LoRADense(inner, rank=0, dtype=cfg.dtype, name="dt_proj")(dt)
+        delta = checkpoint_name(jax.nn.softplus(delta.astype(jnp.float32) + dt_bias), "ssm_dt")
+        a_log = self.param("A_log", _a_log_init, (inner, n))
+        skip = self.param("D", nn.initializers.ones, (inner,))
+        y = selective_scan(u, delta, -jnp.exp(a_log), b, c, skip, z)
+        return dense(cfg.dim, name="out_proj")(checkpoint_name(y, "ssm_y"))
+
+
+class Block(nn.Module):
+    cfg: TransformerConfig
+    attn_fn: Optional[Callable] = None
+    kind: str = "attention"  # the sequence mixer: one of LAYER_KINDS
+
+    @nn.compact
+    def __call__(self, x):
+        if self.kind == "mamba":
+            x = x + MambaMixer(self.cfg, name="mamba")(RMSNorm(self.cfg.dtype, name="mamba_norm")(x))
+        else:
+            x = x + Attention(self.cfg, self.attn_fn, name="attn")(
+                RMSNorm(self.cfg.dtype, name="attn_norm")(x)
+            )
         ffn = MoEMLP if self.cfg.n_experts > 0 else MLP
         x = x + ffn(self.cfg, name="mlp")(RMSNorm(self.cfg.dtype, name="mlp_norm")(x))
         return x
@@ -362,10 +482,61 @@ class _ScanBlock(nn.Module):
 
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x, _):
-        return Block(self.cfg, self.attn_fn, name="block")(x), None
+        return Block(self.cfg, self.attn_fn, self.kind, name="block")(x), None
+
+
+def layer_runs(pattern: tuple) -> list[tuple[str, int]]:
+    """The maximal runs of same-kind layers of one period, in order:
+    Jamba's period is ``[("mamba", 7), ("attention", 1), ("mamba", 6)]``."""
+    runs: list[tuple[str, int]] = []
+    for kind in pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    return runs
+
+
+def _rematted_in_scan(body, cfg: TransformerConfig):
+    """``body`` rematerialised where the config says so, for use INSIDE a scan.
+    prevent_cse=False: inside lax.scan the remat thunk can't be CSE'd across
+    iterations anyway, and True blocks the scan lowering (flax's documented
+    scan-over-remat recipe)."""
+    if not cfg.remat:
+        return body
+    return nn.remat(body, prevent_cse=False, policy=_remat_policy(cfg.remat_policy))
+
+
+def _scan_over(body, length: int):
+    """``nn.scan`` of ``body`` over ``length`` stacked copies of its params."""
+    return nn.scan(body, variable_axes={"params": 0}, split_rngs={"params": True}, length=length)
+
+
+class _ScanPeriod(nn.Module):
+    """nn.scan body over PERIODS of unlike layers: inside, every maximal run
+    of same-kind layers is a scan of its own (a run of one layer is the block
+    itself), so the compiled program holds one body per run whatever the
+    depth. Params: ``run<i>_<kind>/block/...`` with a leading run-length axis,
+    or ``run<i>_<kind>/...`` for a run of one."""
+
+    cfg: TransformerConfig
+    attn_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, _):
+        cfg = self.cfg
+        for i, (kind, count) in enumerate(layer_runs(cfg.layer_pattern)):
+            name = f"run{i}_{kind}"
+            if count > 1:
+                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg), count)
+                x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, None)
+            else:
+                x = _rematted_in_scan(Block, cfg)(cfg, self.attn_fn, kind, name=name)(x)
+        return x, None
 
 
 class CausalLM(nn.Module):
@@ -379,27 +550,20 @@ class CausalLM(nn.Module):
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim)
         )
         x = emb[tokens].astype(cfg.dtype)
+        pattern = cfg.layer_pattern
         if cfg.scan_layers:
             if cfg.n_experts > 0:
                 raise NotImplementedError(
-                    "scan_layers with MoE: sown aux losses don't thread "
-                    "through this scan — use unrolled layers for MoE"
+                    "scan_layers with MoE: sown aux losses don't thread through "
+                    "the layer scan or the period scan — use unrolled layers for MoE"
                 )
-            body = _ScanBlock
-            if cfg.remat:
-                # prevent_cse=False: inside lax.scan the remat thunk can't
-                # be CSE'd across iterations anyway, and True blocks the
-                # scan lowering (flax's documented scan-over-remat recipe)
-                body = nn.remat(
-                    body, prevent_cse=False, policy=_remat_policy(cfg.remat_policy)
-                )
-            scan = nn.scan(
-                body,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                length=cfg.n_layers,
-            )
-            x, _ = scan(cfg, self.attn_fn, name="layers")(x, None)
+            if len(pattern) == 1:
+                # a period of one layer is the scan body itself
+                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg), cfg.n_layers)
+                x, _ = scan(cfg, self.attn_fn, pattern[0], name="layers")(x, None)
+            else:
+                scan = _scan_over(_ScanPeriod, cfg.n_layers // len(pattern))
+                x, _ = scan(cfg, self.attn_fn, name="layers")(x, None)
         else:
             block_cls = (
                 nn.remat(Block, policy=_remat_policy(cfg.remat_policy))
@@ -407,7 +571,7 @@ class CausalLM(nn.Module):
                 else Block
             )
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, self.attn_fn, name=f"layer_{i}")(x)
+                x = block_cls(cfg, self.attn_fn, pattern[i % len(pattern)], name=f"layer_{i}")(x)
         x = RMSNorm(cfg.dtype, name="final_norm")(x)
         logits = jnp.dot(x, emb.T.astype(cfg.dtype))  # tied embeddings
         return logits.astype(jnp.float32)

@@ -141,6 +141,8 @@ def test_chip_smoke_phases_at_toy_size():
     clock = chip_smoke.CompileClock()
     flash = chip_smoke.check_flash(1, 256, 2, 32, interpret=True)
     assert flash["config_source"] == "defaults" and flash["mosaic_calls"] == 0
+    scan = chip_smoke.check_scan(1, 600, 256, 4, interpret=True)
+    assert scan["mosaic_calls"] == 0 and set(scan["rel_err"]) >= {"y", "dA", "dB", "dz", "xla_vs_loop"}
 
     a = chip_smoke.run_phase(
         "A", clock, chip_smoke.phase_spmd,

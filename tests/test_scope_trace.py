@@ -22,6 +22,7 @@ from p2pfl_tpu.settings import Settings
 
 FIXTURES = Path(scope_reduce.__file__).resolve().parent / "fixtures"
 ROUND_SCOPES = ("grad", "optimizer", "fold")
+SSM_SCOPES = ("ssm_conv", "ssm_scan_fwd", "ssm_scan_bwd")  # a Mamba layer's; read by readers/scope_named.py
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
@@ -42,6 +43,17 @@ def _lora_federation():
     return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
 
 
+def _hybrid_federation():
+    cfg = TransformerConfig(
+        vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=1, ffn_hidden=128, rope_theta=None,
+        layer_pattern=("mamba", "attention"), ssm_dt_rank=8, lora_rank=4, lora_mlp=True, remat=True,
+        scan_layers=True, remat_policy="ssm",
+    )
+    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
+    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
+    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
+
+
 def _lower_spmd_round(fed):
     from p2pfl_tpu.parallel.spmd import spmd_round
 
@@ -57,13 +69,17 @@ def _lower_spmd_round(fed):
 @pytest.fixture(scope="module")
 def compiled_op_names():
     """``{engine: the op_names of its compiled round}``, compiled once."""
-    lowered = {"spmd": _lower_spmd_round(_mlp_federation()), "spmd_lora": _lora_federation().lower_round(epochs=1)}
+    lowered = {
+        "spmd": _lower_spmd_round(_mlp_federation()), "spmd_lora": _lora_federation().lower_round(epochs=1),
+        "spmd_lora_hybrid": _hybrid_federation().lower_round(epochs=1),
+    }
     return {engine: set(OP_NAME.findall(low.compile().as_text())) for engine, low in lowered.items()}
 
 
 @pytest.mark.parametrize(
     "engine,scope",
-    [("spmd", s) for s in ROUND_SCOPES] + [("spmd_lora", s) for s in DEVICE_SCOPES],
+    [("spmd", s) for s in ROUND_SCOPES] + [("spmd_lora", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES]
+    + [("spmd_lora_hybrid", s) for s in DEVICE_SCOPES],
 )
 def test_scope_is_in_the_compiled_round(compiled_op_names, engine, scope):
     assert any(f"p2pfl.{scope}" in name for name in compiled_op_names[engine])
@@ -80,7 +96,7 @@ def test_forward_reforward_backward_fall_out_of_the_grad_scope(compiled_op_names
 def test_scope_names_are_spelled_in_one_place():
     import p2pfl_tpu
 
-    assert set(ROUND_SCOPES) | set(scope_reduce.SUB_SHARES) == set(DEVICE_SCOPES)
+    assert set(ROUND_SCOPES) | set(scope_reduce.SUB_SHARES) | set(SSM_SCOPES) == set(DEVICE_SCOPES)
     sources = Path(p2pfl_tpu.__file__).parent.rglob("*.py")
     assert [p.name for p in sources if '"p2pfl."' in p.read_text()] == ["profiling.py"]
     with pytest.raises(ValueError, match="gard"):
