@@ -37,9 +37,13 @@ On a TPU the recurrence runs in two Pallas kernels instead
 ``p2pfl_ssm_scan_bwd``, time inside the kernel, one channel block's state in
 VMEM across the chunk axis); chosen by backend the way
 ``models/transformer.Attention`` picks flash, not by a setting. Same chunks,
-same boundary states, same residuals. ``impl`` is for tests and for the chip
-comparison of the two paths (4096 × 5120 × 16 on a v5e, PR 27: forward 4.8 ms
-in XLA, 1.7 ms in the kernel; forward + backward 16.4 against 8.4).
+same boundary states, same residuals. The kernels read ``u``, ``Δ`` and the
+cotangent as ``[B, T, Dm]`` in the dtype the mixer holds them and write their
+results the same way, so between the mixer and the two Mosaic calls stands
+only the gate arithmetic of ``_fwd`` / ``_bwd`` below. ``impl`` is for tests
+and for the chip comparison of the two paths (4096 × 5120 × 16 on a v5e:
+forward 4.8 ms and forward + backward 16.4 ms in XLA, PR 27; the two kernel
+calls with all they need around them 1.06 and 2.86 ms, PR 28).
 
 State layout is ``[..., N, Dm]`` throughout (channels on the lanes): with
 ``N`` = 16 minor a TPU tile would be seven eighths padding.

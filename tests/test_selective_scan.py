@@ -70,26 +70,96 @@ def test_state_crosses_chunk_boundaries():
     assert float(jnp.max(jnp.abs(y[:, 40:]))) > 1e-4
 
 
-@pytest.mark.parametrize("dm,chunk", [(128, 16), (256, 16), (1024, 25), (2048, 64)])
-def test_pallas_kernels_equal_the_xla_path(dm, chunk):
+def as_the_mixer_passes_them(args):
+    """``u``, ``B``, ``C``, ``z`` in bfloat16; ``Δ``, ``A``, ``D`` float32."""
+    return tuple(x.astype(jnp.bfloat16) if i in (0, 3, 4, 6) else x for i, x in enumerate(args))
+
+
+@pytest.mark.parametrize(
+    "dm,chunk,mixed",
+    [
+        (128, 16, False), (256, 16, False), (1024, 25, False), (2048, 64, False),
+        (1024, 16, True), (2048, 64, True),
+    ],
+)
+def test_pallas_kernels_equal_the_xla_path(dm, chunk, mixed):
     """Interpret mode: 128 and 256 channels are one narrow block, 1024 one whole
-    [8, 128] tile, 2048 two blocks; chunk 16 and 64 do not divide T."""
+    [8, 128] tile, 2048 two blocks; chunk 16 and 64 do not divide T = 50, which
+    is no multiple of 8 either. ``mixed``: the dtypes a Mamba layer passes."""
     args = inputs(seed=5, dm=dm)
+    if mixed:
+        args = as_the_mixer_passes_them(args)
     w = jax.random.normal(jax.random.PRNGKey(1), args[0].shape)
-    y_x, g_x = weighted(lambda *xs: ss.selective_scan(*xs, chunk=chunk, impl="xla"), args, w)
-    y_p, g_p = weighted(lambda *xs: ss.selective_scan(*xs, chunk=chunk, impl="pallas"), args, w)
-    assert abs(float(y_x - y_p)) <= TOL * abs(float(y_x)) + 1e-3
+    run = lambda impl: weighted(  # noqa: E731
+        lambda *xs: ss.selective_scan(*xs, chunk=chunk, impl=impl).astype(jnp.float32), args, w
+    )
+    (y_x, g_x), (y_p, g_p) = run("xla"), run("pallas")
+    # a bfloat16 result may round the other way on a float32 difference of an ulp
+    assert abs(float(y_x - y_p)) <= (2e-3 if mixed else TOL) * abs(float(y_x)) + 1e-3
     for name, got, want in zip(NAMES, g_p, g_x):
-        assert rel(got, want) < TOL, name
+        assert got.dtype == want.dtype, name
+        tol = 2.0**-7 if got.dtype == jnp.bfloat16 else TOL
+        assert rel(got.astype(jnp.float32), want.astype(jnp.float32)) < tol, name
     y, starts = kernel.scan_fwd(*args[:5], chunk, interpret=True)
     y_ref, starts_ref = ss._scan_xla(*args[:5], chunk)
-    assert rel(y, y_ref) < TOL and rel(starts, starts_ref) < TOL
+    assert y.dtype == jnp.float32 and rel(y, y_ref) < TOL and rel(starts, starts_ref) < TOL
     gy = jax.random.normal(jax.random.PRNGKey(2), y.shape)
     ys, grads = kernel.scan_bwd(*args[:5], starts_ref, gy, chunk, interpret=True)
     ys_ref, grads_ref = ss._scan_bwd_xla(*args[:5], starts_ref, gy, chunk)
     assert rel(ys, ys_ref) < TOL
     for name, got, want in zip(NAMES, grads, grads_ref):
         assert got.shape == want.shape and rel(got, want) < TOL, name
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry, the
+    inside of a ``pallas_call`` left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+@pytest.mark.parametrize("dm", [1024, 2048])
+def test_nothing_stands_between_the_mixer_and_the_kernels(dm):
+    """The kernels' HBM interface: outside the two ``pallas_call``s no array has
+    the tiled shape ``[B, T', Dm / 128, 128]`` — ``u``, ``Δ``, ``gy`` go in and
+    ``y``, ``du``, ``dΔ`` come out as ``[B, T', Dm]`` in the dtype they have —
+    and dB / dC leave the backward kernel as ``[B, T', N, 128]`` at most."""
+    chunk, n = 16, 4
+    args = as_the_mixer_passes_them(inputs(seed=11, dm=dm, n=n))
+    bsz, padded = args[0].shape[0], -(-T // chunk) * chunk
+
+    def fn(*xs):
+        return jnp.sum(ss.selective_scan(*xs, chunk=chunk, impl="pallas").astype(jnp.float32))
+
+    for traced in (fn, jax.grad(fn, argnums=tuple(range(7)))):
+        eqns = list(equations(jax.make_jaxpr(traced)(*args).jaxpr))
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == (
+            ["p2pfl_ssm_scan_fwd"] if traced is fn else ["p2pfl_ssm_scan_fwd", "p2pfl_ssm_scan_bwd"]
+        )
+        for e in eqns:
+            if e.primitive.name != "pallas_call":
+                assert all(v.aval.shape != (bsz, padded, dm // 128, 128) for v in e.outvars), e
+        for call in calls:
+            wide = [v.aval for v in call.invars if v.aval.shape == (bsz, padded, dm)]
+            assert jnp.bfloat16 in [a.dtype for a in wide], "u enters in its own dtype"
+            for out in call.outvars:
+                shape = out.aval.shape
+                if len(shape) > 3 and shape[1:3] == (padded, n):  # dB, dC
+                    assert out.aval.size <= bsz * padded * n * 128, shape
+
+
+def test_a_compiled_kernel_wants_whole_sublane_tiles_of_time():
+    args = inputs(dm=128)
+    with pytest.raises(ValueError, match="multiple of 16"):
+        kernel.scan_fwd(*args[:5], 25, interpret=False)
 
 
 def test_pallas_kernel_under_vmap_as_the_federation_calls_it():
