@@ -62,9 +62,8 @@ Usage: ``JAX_PLATFORMS=cpu python bench_async.py [--smoke]
 [--sections a,b,...] [--out BENCH_ASYNC.json]``
 
 ``--sections`` (any of ``threaded,simulated,churn,restart,byzantine,
-megafleet,megafleet_chunks,megafleet_robust,megafleet_sharded``) runs a
-subset and MERGES it into
-the existing ``--out`` document, leaving the other sections' rows
+megafleet,megafleet_chunks,megafleet_robust``) runs a subset and MERGES
+it into the existing ``--out`` document, leaving the other sections' rows
 untouched — so CI can refresh one section without paying for the full
 grid.
 """
@@ -72,9 +71,7 @@ grid.
 from __future__ import annotations
 
 import json
-import os
 import sys
-import tempfile
 import time
 
 import numpy as np
@@ -920,140 +917,9 @@ def run_megafleet_robust(smoke: bool = False) -> dict:
     }
 
 
-def run_megafleet_sharded(smoke: bool = False) -> dict:
-    """ISSUE 17: the device-mesh sharded engine vs single-device chunked.
-
-    Three parts: (a) an inline BIT-IDENTITY check, flat and
-    hierarchical — the sharded engine's only collective is a tiled
-    ``all_gather`` (pure concatenation, no float reassociation), so
-    every counter and every float must equal the single-device chunked
-    engine's; (b) the device-count sweep at the big scale: clients/s
-    for 1 (single-device chunked baseline) / 2 / 4 / 8 host devices;
-    (c) the autotuned-vs-default chunk delta through
-    ``ops/fleet_autotune.py``.
-
-    Honesty note: host devices come from
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and SHARE
-    the machine's cores and memory bandwidth, so the sweep is a LOWER
-    bound for real chips — the replicated admission scan runs once per
-    device, and on a 1-core container the sweep measures pure sharding
-    overhead (speedup < 1). ``cpu_count`` is recorded with the rows so
-    the ratio can be read in context.
-    """
-    import jax
-
-    from p2pfl_tpu.federation.megafleet import FleetSpec, MegaFleet
-    from p2pfl_tpu.ops import fleet_autotune as ft
-    from p2pfl_tpu.settings import Settings
-
-    n_dev = jax.device_count()
-    big_n = 50_000 if smoke else 1_000_000
-    updates = 4
-
-    # -- (a) bit-identity, flat and hierarchical --
-    pn = 2000 if smoke else 20_000
-    pspec = FleetSpec.synth(pn, seed=SEED, slow_frac=0.10)
-
-    def parity_cell(cluster, shards):
-        kw = dict(cluster_size=cluster, k=32, updates_per_node=updates,
-                  local_lr=0.7, chunk=256)
-        ref = MegaFleet(pspec, **kw).run()
-        got = MegaFleet(pspec, shards=shards, **kw).run()
-        cell = {
-            "n_clients": pn, "cluster_size": cluster, "shards": shards,
-            "merges_equal": got.merges == ref.merges,
-            "loss_curve_bit_equal": got.loss_curve == ref.loss_curve,
-            "params_bit_equal": bool(
-                np.array_equal(got.params["w"], ref.params["w"])
-            ),
-        }
-        log(json.dumps({"sharded_bit_identity": cell}))
-        return cell
-
-    parity = [parity_cell(0, min(2, n_dev))]
-    if n_dev >= 8:
-        parity.append(parity_cell(64, 8))
-
-    # -- (b) device-count sweep at scale --
-    spec = FleetSpec.synth(big_n, seed=SEED, slow_frac=0.10)
-
-    def big(shards, chunk=256):
-        return MegaFleet(
-            spec, cluster_size=1024, k=64, updates_per_node=updates,
-            local_lr=0.7, chunk=chunk, shards=shards,
-        )
-
-    rows = []
-    for p in [None, 2, 4, 8]:
-        if p is not None and p > n_dev:
-            continue
-        res = big(p).run()
-        rows.append({
-            "devices": 1 if p is None else p,
-            "engine": "chunked" if p is None else "sharded",
-            "n_clients": big_n,
-            "wall_s": round(res.wall_s, 2),
-            "clients_per_sec": int(res.clients_per_sec),
-            "merges": res.merges,
-        })
-        log(json.dumps(rows[-1]))
-    base = rows[0]["clients_per_sec"]
-    for r in rows:
-        r["speedup_vs_1dev"] = round(r["clients_per_sec"] / max(base, 1), 2)
-
-    # -- (c) autotuned vs default chunk (scratch cache: measured fresh) --
-    old_cache = Settings.FLEET_TUNE_CACHE
-    Settings.FLEET_TUNE_CACHE = os.path.join(
-        tempfile.mkdtemp(prefix="fleet_tune_"), "tune.json"
-    )
-    ft.clear_memory_cache()
-    try:
-        p_auto = min(2, n_dev) if n_dev > 1 else None
-        auto = big(p_auto, chunk=0)
-        res_auto = auto.run()
-        res_def = big(p_auto, chunk=256).run()
-        autotune = {
-            "devices": 1 if p_auto is None else p_auto,
-            "tuned_chunk": auto.chunk,
-            "default_chunk": 256,
-            "tuned_clients_per_sec": int(res_auto.clients_per_sec),
-            "default_clients_per_sec": int(res_def.clients_per_sec),
-            "delta": round(
-                res_auto.clients_per_sec / max(res_def.clients_per_sec, 1e-9),
-                2,
-            ),
-            "note": "tuned_clients_per_sec includes the one-time candidate "
-                    "sweep on a bounded event prefix; a cached key replays "
-                    "with zero measurements",
-        }
-    finally:
-        Settings.FLEET_TUNE_CACHE = old_cache
-        ft.clear_memory_cache()
-    log(json.dumps({"autotune": autotune}))
-
-    return {
-        "engine": "run_fleet_program_sharded (ops/fleet_kernels.py, "
-                  "shard_map over Settings.MESH_CLIENTS_AXIS)",
-        "bit_identity": parity,
-        "sweep": rows,
-        "speedup_8dev_vs_1dev": next(
-            (r["speedup_vs_1dev"] for r in rows if r["devices"] == 8), None
-        ),
-        "autotune": autotune,
-        "cpu_count": os.cpu_count(),
-        "scaling_note": "forced host devices share cores and memory "
-                        "bandwidth — a LOWER bound for real chips; on a "
-                        "1-core container the replicated admission scan "
-                        "runs once PER device and the sweep measures pure "
-                        "sharding overhead (speedup < 1); the bitwise "
-                        "parity rows are the unconditional claim",
-        "smoke": smoke,
-    }
-
-
 ALL_SECTIONS = (
     "threaded", "simulated", "churn", "restart", "byzantine", "megafleet",
-    "megafleet_chunks", "megafleet_robust", "megafleet_sharded",
+    "megafleet_chunks", "megafleet_robust",
 )
 
 
@@ -1139,10 +1005,6 @@ def main() -> int:
         log("=== megafleet robust-agg attacker sweep ===")
         doc["megafleet_robust"] = run_megafleet_robust(smoke=smoke)
 
-    if "megafleet_sharded" in sections:
-        log("=== megafleet sharded device sweep ===")
-        doc["megafleet_sharded"] = run_megafleet_sharded(smoke=smoke)
-
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1)
         f.write("\n")
@@ -1159,10 +1021,6 @@ def main() -> int:
         )
     if "megafleet_robust" in doc:
         summary["robust_cells"] = len(doc["megafleet_robust"]["cells"])
-    if "megafleet_sharded" in doc:
-        summary["sharded_speedup_8dev"] = (
-            doc["megafleet_sharded"]["speedup_8dev_vs_1dev"]
-        )
     print(json.dumps(summary))
     return 0
 

@@ -293,25 +293,25 @@ class MegaFleet:
     - ``rate_limit_regional`` / ``rate_limit_global`` — per-tier rate
       limits: a tier refuses offers arriving within the gap of its last
       accepted one (counted, never raising);
-    - ``chunk`` — events per scan step (1 = the per-event reference
-      engine; >1 = the chunked engine, bit-identical on flat
-      topologies; 0 or ``"auto"`` = measure the
-      :data:`~p2pfl_tpu.ops.fleet_autotune.DEFAULT_CANDIDATES` once on
-      the live device and replay the winner from the fleet-tune cache);
-    - ``shards`` — partition the chunked engine's client state over a
-      1-D device mesh (:func:`~p2pfl_tpu.parallel.fleet_mesh.
-      fleet_clients_mesh`); admission stays replicated, so results are
-      bit-identical to the single-device chunked engine at any shard
-      count (0/1 = single device);
+    - ``chunk`` — events per scan step, an integer ≥ 1 (1 = the
+      per-event reference engine, unless a feature below needs the
+      chunked one; >1 = the chunked engine, bit-identical on flat
+      topologies);
     - ``task`` — a :class:`GradTask` swaps the consensus step for real
       vmapped-gradient local rounds;
     - ``fold`` / ``trim`` — the window fold family (``fedavg`` /
       ``trimmed-mean`` / ``median``), the robust-aggregation sweep knob.
 
-    Defaults for the knobs come from ``Settings.MEGAFLEET_*`` (and
-    ``Settings.ASYNC_ROBUST_AGG`` / ``ASYNC_TRIM`` for the fold) at
-    construction time (never read inside the program — the
-    jit-staleness contract).
+    Defaults for the knobs come from ``Settings.MEGAFLEET_PACE_WINDOW``
+    / ``_SELECT_FRAC`` / ``_REGIONAL_RATE_S`` / ``_GLOBAL_RATE_S`` /
+    ``_SCAN_UNROLL`` / ``_CHUNK`` (and ``Settings.ASYNC_ROBUST_AGG`` /
+    ``ASYNC_TRIM`` for the fold) at construction time (never read inside
+    the program — the jit-staleness contract).
+
+    :meth:`run` picks the engine from these arguments alone: the
+    per-event scan when ``chunk == 1`` and nothing asks for chunking,
+    the chunked scan otherwise (``chunk > 1``, a ``task``, a robust
+    ``fold``, a Byzantine or churn plan, duplicates on a hierarchy).
     """
 
     def __init__(
@@ -334,7 +334,6 @@ class MegaFleet:
         rate_limit_global: Optional[float] = None,
         unroll: Optional[int] = None,
         chunk: Optional[int] = None,
-        shards: Optional[int] = None,
         task: Optional[GradTask] = None,
         fold: Optional[str] = None,
         trim: Optional[int] = None,
@@ -378,16 +377,13 @@ class MegaFleet:
             else rate_limit_global
         )
         self.unroll = max(1, int(Settings.MEGAFLEET_SCAN_UNROLL if unroll is None else unroll))
-        chunk_val = Settings.MEGAFLEET_CHUNK if chunk is None else chunk
-        # chunk="auto"/0: resolve through the fleet-tune cache at run()
-        # (measured once per device kind × shard count × workload key);
-        # until then self.chunk holds the un-tuned fallback
-        self._chunk_auto = chunk_val == "auto" or (
-            not isinstance(chunk_val, str) and int(chunk_val) == 0
-        )
-        self.chunk = 256 if self._chunk_auto else max(1, int(chunk_val))
-        self.shards = max(0, int(Settings.MEGAFLEET_SHARDS if shards is None else shards))
-        self.shard_slack = max(1.0, float(Settings.MEGAFLEET_SHARD_SLACK))
+        chunk = Settings.MEGAFLEET_CHUNK if chunk is None else chunk
+        if not isinstance(chunk, (int, np.integer)) or chunk < 1:
+            raise ValueError(
+                "chunk is the number of events per scan step, an integer "
+                f">= 1 (1 = the per-event engine); got {chunk!r}"
+            )
+        self.chunk = int(chunk)
         self.task = task
         self.fold = str(Settings.ASYNC_ROBUST_AGG if fold is None else fold)
         self.trim = int(Settings.ASYNC_TRIM if trim is None else trim)
@@ -907,92 +903,10 @@ class MegaFleet:
 
         return np.asarray(jax.vmap(ce)(jnp.asarray(G)), np.float64)
 
-    def _shard_layout(
-        self, client: np.ndarray, C: int, P: int, cp: int, ncap: int
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """The sharded engine's chunk layout: like :meth:`_chunk_layout`
-        but each chunk is ALSO partitioned into per-shard segments of
-        ``cp`` lanes (shard = owner ``client // ncap``, lanes in
-        chronological order). Returns ``(rows [S, C], seg_ev [S, P·cp],
-        invperm [S, C])`` — ``rows`` is the chronological grid the
-        replicated passes consume (−1 = pad), ``seg_ev`` maps each
-        shard-segment lane to its event (−1 = dead lane), and
-        ``invperm`` maps a chunk's chronological position to its
-        segment slot, which is how the device program unpermutes the
-        per-chunk ``all_gather``. Fast path: the aligned-group reshape
-        whenever no client repeats in a group AND every (group, shard)
-        count fits the ``cp`` quota (slack sizes ``cp`` so this is the
-        fleet-scale regime); fallback: greedy chunking that closes on a
-        repeat OR a full segment."""
-        E = int(client.shape[0])
-        S = -(-E // C) if E else 0
-        sh = client.astype(np.int64) // ncap
-        rows = np.full(S * C, -1, np.int64)
-        rows[:E] = np.arange(E)
-        gid_full = np.arange(S * C) // C
-        cl = np.where(rows >= 0, client[np.clip(rows, 0, None)], -1)
-        o = np.lexsort((cl, gid_full))
-        gs, cs = gid_full[o], cl[o]
-        collide = (gs[1:] == gs[:-1]) & (cs[1:] == cs[:-1]) & (cs[1:] >= 0)
-        gid = np.arange(E) // C
-        key = gid * P + sh
-        counts = np.bincount(key, minlength=S * P)
-        if not collide.any() and (counts <= cp).all():
-            order = np.lexsort((np.arange(E), sh, gid))
-            sk = key[order]
-            starts = np.r_[0, 1 + np.flatnonzero(np.diff(sk))]
-            lens = np.diff(np.r_[starts, E])
-            within = np.arange(E) - np.repeat(starts, lens)
-            slot = sh[order] * cp + within
-            seg_slot = np.empty(E, np.int64)
-            seg_slot[order] = slot
-            seg_ev = np.full((S, P * cp), -1, np.int64)
-            seg_ev[gid, seg_slot] = np.arange(E)
-            invperm = np.zeros((S, C), np.int32)
-            invperm[gid, np.arange(E) - gid * C] = seg_slot
-            return rows.reshape(S, C), seg_ev, invperm
-        row_chunks: List[List[int]] = []
-        seg_chunks: List[np.ndarray] = []
-        inv_chunks: List[np.ndarray] = []
-        cur: List[int] = []
-        slots: List[int] = []
-        seen: set = set()
-        cnt = np.zeros(P, np.int64)
-
-        def close() -> None:
-            row_chunks.append(cur + [-1] * (C - len(cur)))
-            seg_row = np.full(P * cp, -1, np.int64)
-            seg_row[np.asarray(slots, np.int64)] = np.asarray(cur, np.int64)
-            inv_row = np.zeros(C, np.int32)
-            inv_row[: len(slots)] = np.asarray(slots, np.int32)
-            seg_chunks.append(seg_row)
-            inv_chunks.append(inv_row)
-
-        for j in range(E):
-            cj = int(client[j])
-            sj = int(sh[j])
-            if cj in seen or len(cur) == C or cnt[sj] == cp:
-                close()
-                cur, slots, seen = [], [], set()
-                cnt[:] = 0
-            cur.append(j)
-            slots.append(sj * cp + int(cnt[sj]))
-            seen.add(cj)
-            cnt[sj] += 1
-        if cur:
-            close()
-        return (
-            np.asarray(row_chunks, np.int64).reshape(-1, C),
-            np.stack(seg_chunks),
-            np.stack(inv_chunks),
-        )
-
     def _chunk_grids(self, fk, jnp, cfg, tiers, ev, clients, agg, rows):
         """Build the ``[S, C]`` chronological event grids + per-regional
         grids from a chunk layout (pads carry trash values that every
-        in-kernel gate masks: client=N, PAD keys, live=False). Shared by
-        the chunked and sharded drivers — the layouts differ, the grid
-        semantics do not."""
+        in-kernel gate masks: client=N, PAD keys, live=False)."""
         C = cfg.chunk
         PAD = int(fk.PAD_KEY)
         live = rows >= 0
@@ -1064,81 +978,6 @@ class MegaFleet:
                 reg["agg_noise"] = jnp.asarray(agg["agg_noise"])
         return events, reg
 
-    def _run_chunked(self, fk, jnp, cfg, tiers, ev, clients, agg, init):
-        """Chunked single-device drive: chronological layout → grids →
-        :func:`run_fleet_program_chunked`."""
-        rows = self._chunk_layout(ev["client"], cfg.chunk)
-        events, reg = self._chunk_grids(fk, jnp, cfg, tiers, ev, clients, agg, rows)
-        return fk.run_fleet_program_chunked(cfg, events, clients, reg, init)
-
-    def _run_sharded(self, fk, jnp, cfg, tiers, ev, clients, agg, init):
-        """Sharded drive: segment layout → chronological grids + shard
-        grids → :func:`run_fleet_program_sharded` on a ``(clients,)``
-        mesh of ``self.shards`` devices."""
-        from p2pfl_tpu.parallel.fleet_mesh import fleet_clients_mesh, shard_capacity
-
-        P = self.shards
-        mesh = fleet_clients_mesh(P)
-        ncap = shard_capacity(self.n, P)
-        cp = max(1, int(np.ceil(self.shard_slack * cfg.chunk / P)))
-        rows, seg_ev, invperm = self._shard_layout(
-            ev["client"], cfg.chunk, P, cp, ncap
-        )
-        events, reg = self._chunk_grids(fk, jnp, cfg, tiers, ev, clients, agg, rows)
-        # chronological position of each event inside its chunk — segment
-        # lanes forward-gather the replicated chronological grids with it
-        E = int(ev["client"].shape[0])
-        pos = np.zeros(E, np.int64)
-        sidx, cidx = np.nonzero(rows >= 0)
-        pos[rows[sidx, cidx]] = cidx
-        seg_live = seg_ev >= 0
-        safe = np.clip(seg_ev, 0, None)
-        events["seg_fwd"] = jnp.asarray(
-            np.where(seg_live, pos[safe], 0).astype(np.int32)
-        )
-        events["seg_loc"] = jnp.asarray(
-            np.where(seg_live, ev["client"][safe] % ncap, ncap).astype(np.int32)
-        )
-        events["seg_live"] = jnp.asarray(seg_live)
-        events["invperm"] = jnp.asarray(invperm)
-        return fk.run_fleet_program_sharded(cfg, events, clients, reg, init, mesh)
-
-    def _autotune_chunk(self, fk, jnp, make_cfg, tiers, ev, clients, agg, init):
-        """Resolve ``chunk="auto"``: measure the engine over a bounded
-        event prefix for each candidate, once per (device kind, shard
-        count, workload) key — cached on disk so replays are free."""
-        import jax
-
-        from p2pfl_tpu.ops import fleet_autotune as ft
-
-        n_sh = self.shards if self.shards > 1 else 1
-        extra = (
-            f"task={self.task.kind if self.task else 'consensus'}"
-            f"|dim={self.dim}|hier={int(self.hier)}|k={self.k}"
-            f"|n~1e{len(str(max(1, self.n))) - 1}"
-        )
-        got = ft.get_fleet_chunk(n_shards=n_sh, extra=extra)
-        if got is not None:
-            return got
-        cands = ft.DEFAULT_CANDIDATES
-        E = int(ev["client"].shape[0])
-        budget = max(min(E, 8 * max(cands)), 1)
-        ev_cut = {
-            k: (v[:budget] if isinstance(v, np.ndarray) else v)
-            for k, v in ev.items()
-        }
-        runner = self._run_sharded if n_sh > 1 else self._run_chunked
-
-        def measure(c: int) -> float:
-            cfg_c = make_cfg(c)
-            runner(fk, jnp, cfg_c, tiers, ev_cut, dict(clients), agg, init)
-            t0 = time.monotonic()
-            out = runner(fk, jnp, cfg_c, tiers, ev_cut, dict(clients), agg, init)
-            jax.block_until_ready(out["G"])
-            return time.monotonic() - t0
-
-        return ft.autotune_fleet_chunk(measure, cands, n_shards=n_sh, extra=extra)
-
     # ---- the drive ----
 
     def run(self) -> MegaFleetResult:
@@ -1176,8 +1015,6 @@ class MegaFleet:
             stride = 2
         use_chunked = (
             self.chunk > 1
-            or self._chunk_auto
-            or self.shards > 1
             or self.task is not None
             or self.fold != "fedavg"
             or self._byz is not None
@@ -1186,43 +1023,43 @@ class MegaFleet:
         )
         task = self.task
 
-        def make_cfg(C):
-            return fk.FleetConfig(
-                hier=self.hier,
-                n_clients=self.n,
-                dim=self.dim,
-                n_regionals=R,
-                k_global=k_glob,
-                k_reg_max=int(tiers["k_reg"].max(initial=1)) if self.hier else 1,
-                v_cap=max(v_cap, 2),
-                alpha=self.alpha,
-                server_lr=self.server_lr,
-                local_lr=self.local_lr,
-                max_staleness=self.max_staleness,
-                rate_gap_reg=self.rate_limit_regional,
-                rate_gap_glob=self.rate_limit_global,
-                hist_bins=self.max_staleness + 2,
-                agg_key_stride=stride,
-                unroll=self.unroll,
-                chunk=C,
-                gf_cap=(C // k_glob + 2) if use_chunked else 0,
-                fold_kind=self.fold,
-                trim=self.trim,
-                task=(task.kind if task is not None else "consensus"),
-                t_din=(task.d_in if task is not None else 0),
-                t_nout=(task.n_out if task is not None else 0),
-                t_hidden=(task.hidden if task is not None else 0),
-                t_bs=(task.batch if task is not None else 0),
-                t_steps=(task.steps if task is not None else 0),
-                data_seed=(task.data_seed if task is not None else 0),
-                byz=bool("bkind" in ev and use_chunked),
-                dup=bool(
-                    self.hier
-                    and plan is not None
-                    and plan.default.duplicate > 0.0
-                    and use_chunked
-                ),
-            )
+        C = self.chunk if use_chunked else 1
+        cfg = fk.FleetConfig(
+            hier=self.hier,
+            n_clients=self.n,
+            dim=self.dim,
+            n_regionals=R,
+            k_global=k_glob,
+            k_reg_max=int(tiers["k_reg"].max(initial=1)) if self.hier else 1,
+            v_cap=max(v_cap, 2),
+            alpha=self.alpha,
+            server_lr=self.server_lr,
+            local_lr=self.local_lr,
+            max_staleness=self.max_staleness,
+            rate_gap_reg=self.rate_limit_regional,
+            rate_gap_glob=self.rate_limit_global,
+            hist_bins=self.max_staleness + 2,
+            agg_key_stride=stride,
+            unroll=self.unroll,
+            chunk=C,
+            gf_cap=(C // k_glob + 2) if use_chunked else 0,
+            fold_kind=self.fold,
+            trim=self.trim,
+            task=(task.kind if task is not None else "consensus"),
+            t_din=(task.d_in if task is not None else 0),
+            t_nout=(task.n_out if task is not None else 0),
+            t_hidden=(task.hidden if task is not None else 0),
+            t_bs=(task.batch if task is not None else 0),
+            t_steps=(task.steps if task is not None else 0),
+            data_seed=(task.data_seed if task is not None else 0),
+            byz=bool("bkind" in ev and use_chunked),
+            dup=bool(
+                self.hier
+                and plan is not None
+                and plan.default.duplicate > 0.0
+                and use_chunked
+            ),
+        )
         clients = {
             "targets": jnp.asarray(self.spec.targets, jnp.float32),
             "samples": jnp.asarray(self.spec.num_samples, jnp.float32),
@@ -1234,15 +1071,10 @@ class MegaFleet:
             clients["tb"] = jnp.asarray(tb)
         agg = self._agg_grids(tiers, stride)
         init = jnp.asarray(self.spec.init, jnp.float32)
-        if self._chunk_auto and use_chunked:
-            self.chunk = self._autotune_chunk(
-                fk, jnp, make_cfg, tiers, ev, clients, agg, init
-            )
-        cfg = make_cfg(self.chunk if use_chunked else 1)
-        if use_chunked and self.shards > 1:
-            out = self._run_sharded(fk, jnp, cfg, tiers, ev, clients, agg, init)
-        elif use_chunked:
-            out = self._run_chunked(fk, jnp, cfg, tiers, ev, clients, agg, init)
+        if use_chunked:
+            rows = self._chunk_layout(ev["client"], cfg.chunk)
+            events, reg = self._chunk_grids(fk, jnp, cfg, tiers, ev, clients, agg, rows)
+            out = fk.run_fleet_program_chunked(cfg, events, clients, reg, init)
         else:
             events = {
                 "client": jnp.asarray(ev["client"]),

@@ -616,24 +616,17 @@ def run_fleet_program(
 # ---------------------------------------------------------------------------
 
 
-def _init_carry_chunked(
-    cfg: FleetConfig, init_params, n_w_rows: int | None = None
-) -> Dict[str, jax.Array]:
+def _init_carry_chunked(cfg: FleetConfig, init_params) -> Dict[str, jax.Array]:
     """The per-event carry plus one TRASH row per scatter target (client
     ``N``, regional ``R``, version ``v_cap+1``, mint ``v_cap``): masked
     scatters route their dead lanes there instead of predicating every
-    write, which keeps the chunk body one straight-line program.
-
-    ``n_w_rows`` overrides the client-row count of ``w`` for the sharded
-    engine, whose layout is ``shards × (ncap + local trash)`` — every
-    row is the same init row, so the shape is the only difference."""
+    write, which keeps the chunk body one straight-line program."""
     n, dim, r = cfg.n_clients, cfg.dim, cfg.n_regionals
-    rows = (n + 1) if n_w_rows is None else n_w_rows
     row0 = jnp.concatenate(
         [jnp.asarray(init_params, jnp.float32), jnp.zeros((1,), jnp.float32)]
     )
     carry = {
-        "w": jnp.broadcast_to(row0, (rows, dim + 1)).astype(jnp.float32),
+        "w": jnp.broadcast_to(row0, (n + 1, dim + 1)).astype(jnp.float32),
         "G": jnp.zeros((cfg.v_cap + 2, dim), jnp.float32).at[0].set(init_params),
         "mint": jnp.full((cfg.v_cap + 1,), jnp.inf, jnp.float32),
         "last_mint": jnp.float32(-jnp.inf),
@@ -677,7 +670,7 @@ def _init_carry_chunked(
 
 
 def _make_train_vec(cfg: FleetConfig, clients: Dict[str, jax.Array]):
-    """The chunk engines' batched local round: ``train_vec(starts, idx,
+    """The chunked engine's batched local round: ``train_vec(starts, idx,
     e)`` trains every lane of a ``[C, dim]`` start matrix as client
     ``idx``'s next local round (consensus pull toward the private
     target, or the :func:`make_grad_fns` SGD round keyed by the lane's
@@ -732,32 +725,13 @@ def _make_chunk_body(
     cfg: FleetConfig,
     clients: Dict[str, jax.Array],
     reg: Dict[str, jax.Array],
-    train_vec,
-    apply_byz,
-    adopt_train,
-    writeback_w,
 ):
-    """The shared chunk step of the chunked AND sharded engines — the
-    admission scan (pass B), the flush loop (pass C) and the replicated
-    writebacks (pass D) are one implementation; only the two touches of
-    the fleet-scale ``w`` buffer differ by layout and arrive as hooks:
-
-    - ``adopt_train(c, e) -> (c, wcur, prev0i, base0)`` — pass A: gather
-      the chunk's client rows, adopt against the PRE-chunk mint history,
-      run one vmapped local round, scatter the rows back, and return the
-      CHRONOLOGICAL ``[C, dim]`` trained payloads plus each lane's
-      pre-chunk adopted version. The sharded hook trains only the lanes
-      its shard owns and reassembles the chronological view with one
-      ``all_gather`` (pure concatenation — no cross-shard arithmetic, so
-      nothing reassociates).
-    - ``writeback_w(c, e, ys, fresh_g, v0) -> c`` — the corrected-adopter
-      re-scatter at the end of pass D (lanes whose adoption base moved
-      by an in-chunk mint retrain from the fresh global).
-
-    Everything the hooks feed back is ``[C]``-chronological, so the
-    verdict math in between is layout-blind — the sharded engine's
-    bit-parity with the chunked engine is this function being shared.
-    """
+    """One scan step of :func:`run_fleet_program_chunked`: the four
+    passes its docstring describes (A: adopt + train, B: admission scan,
+    C: flush loop, D: writebacks) over the ``[C]``-chronological event
+    grids of one chunk."""
+    train_vec = _make_train_vec(cfg, clients)
+    apply_byz = _make_apply_byz(cfg, clients)
     C = cfg.chunk
     GF = cfg.gf_cap
     dim = cfg.dim
@@ -765,6 +739,7 @@ def _make_chunk_body(
     k_max = cfg.k_reg_max
     k_glob = cfg.k_global
     stride = cfg.agg_key_stride
+    n_trash = cfg.n_clients
     r_trash = cfg.n_regionals
     v_trash = cfg.v_cap + 1
     m_trash = cfg.v_cap
@@ -773,10 +748,24 @@ def _make_chunk_body(
         idx = e["client"]
         live = e["live"]
 
-        # ---- pass A (layout hook): adopt + train against the PRE-chunk
-        # mint history, returning the chronological trained payloads
-        c, wcur, prev0i, base0 = adopt_train(c, e)
+        # ---- pass A: adopt + train against the PRE-chunk mint history
         mint_hist = c["mint"][:v_cap]
+        base0 = jnp.searchsorted(mint_hist, e["t_adopt"]).astype(jnp.int32)
+        rows0 = c["w"][idx]
+        wvec0 = rows0[:, :dim]
+        prev0 = rows0[:, dim]
+        base0_f = base0.astype(jnp.float32)
+        adopt0 = base0_f > prev0
+        g0 = c["G"][base0]
+        starts0 = jnp.where(adopt0[:, None], g0, wvec0)
+        outs0 = train_vec(starts0, idx, e)
+        newver0 = jnp.maximum(base0_f, prev0)
+        c["w"] = c["w"].at[idx].set(jnp.concatenate([outs0, newver0[:, None]], axis=1))
+        # re-gather from the UPDATED carry (copy law, fix 1): the staged
+        # payloads must not be the same temporary that fed the w scatter
+        rows_cur = c["w"][idx]
+        wcur = rows_cur[:, :dim]
+        prev0i = prev0.astype(jnp.int32)
 
         payload0 = apply_byz(wcur, e)
         samples = clients["samples"][idx]
@@ -1208,18 +1197,24 @@ def _make_chunk_body(
             c["rkey_hi"] = c["rkey_hi"].at[rr_f, sl_f].set(e["key_hi"])
             c["rkey_lo"] = c["rkey_lo"].at[rr_f, sl_f].set(e["key_lo"])
 
-        # corrected adopters (layout hook): retrain from the fresh global
-        # they actually saw (honest weights — corruption only touches the
-        # SENT copy)
-        c = writeback_w(c, e, ys, fresh_g, v0)
+        # corrected adopters: retrain from the fresh global they actually
+        # saw (honest weights — corruption only touches the SENT copy)
+        cmask = (ys["adj"] > 0) & live
+        starts2 = fresh_g[jnp.clip(ys["adj"] - 1, 0, GF - 1)]
+        couts2 = train_vec(starts2, idx, e)
+        newver2 = (v0 + ys["adj"]).astype(jnp.float32)
+        wt2 = jnp.where(cmask, idx, n_trash)
+        c["w"] = c["w"].at[wt2].set(
+            jnp.concatenate([couts2, newver2[:, None]], axis=1)
+        )
         return c, None
 
     return chunk_body
 
 
 def _strip_chunk_out(cfg: FleetConfig, out: Dict[str, Any]) -> Dict[str, Any]:
-    """Strip the trash rows so consumers see the per-event carry shapes
-    (``w`` is layout-specific and already stripped by the caller)."""
+    """Strip the trash rows so consumers see the per-event carry shapes."""
+    out["w"] = out["w"][: cfg.n_clients]
     out["G"] = out["G"][: cfg.v_cap + 1]
     out["mint"] = out["mint"][: cfg.v_cap]
     for k in ("gbuf", "gwt", "gkey_hi", "gkey_lo"):
@@ -1282,53 +1277,9 @@ def run_fleet_program_chunked(
        temporaries), exactly the per-event engine's two fixes at chunk
        granularity.
 
-    Passes 2–4 live in :func:`_make_chunk_body`, shared verbatim with
-    :func:`run_fleet_program_sharded`; this function supplies the
-    single-device pass-A / corrected-adopter hooks.
+    The step itself is :func:`_make_chunk_body`.
     """
-    dim = cfg.dim
-    v_cap = cfg.v_cap
-    GF = cfg.gf_cap
-    n_trash = cfg.n_clients
-    train_vec = _make_train_vec(cfg, clients)
-    apply_byz = _make_apply_byz(cfg, clients)
-
-    def adopt_train(c, e):
-        idx = e["client"]
-        # ---- pass A: adopt + train against the PRE-chunk mint history
-        mint_hist = c["mint"][:v_cap]
-        base0 = jnp.searchsorted(mint_hist, e["t_adopt"]).astype(jnp.int32)
-        rows0 = c["w"][idx]
-        wvec0 = rows0[:, :dim]
-        prev0 = rows0[:, dim]
-        base0_f = base0.astype(jnp.float32)
-        adopt0 = base0_f > prev0
-        g0 = c["G"][base0]
-        starts0 = jnp.where(adopt0[:, None], g0, wvec0)
-        outs0 = train_vec(starts0, idx, e)
-        newver0 = jnp.maximum(base0_f, prev0)
-        c["w"] = c["w"].at[idx].set(jnp.concatenate([outs0, newver0[:, None]], axis=1))
-        # re-gather from the UPDATED carry (copy law, fix 1): the staged
-        # payloads must not be the same temporary that fed the w scatter
-        rows_cur = c["w"][idx]
-        wcur = rows_cur[:, :dim]
-        return c, wcur, prev0.astype(jnp.int32), base0
-
-    def writeback_w(c, e, ys, fresh_g, v0):
-        idx = e["client"]
-        cmask = (ys["adj"] > 0) & e["live"]
-        starts2 = fresh_g[jnp.clip(ys["adj"] - 1, 0, GF - 1)]
-        couts2 = train_vec(starts2, idx, e)
-        newver2 = (v0 + ys["adj"]).astype(jnp.float32)
-        wt2 = jnp.where(cmask, idx, n_trash)
-        c["w"] = c["w"].at[wt2].set(
-            jnp.concatenate([couts2, newver2[:, None]], axis=1)
-        )
-        return c
-
-    chunk_body = _make_chunk_body(
-        cfg, clients, reg, train_vec, apply_byz, adopt_train, writeback_w
-    )
+    chunk_body = _make_chunk_body(cfg, clients, reg)
 
     @jax.jit
     def program(events, carry):
@@ -1336,155 +1287,4 @@ def run_fleet_program_chunked(
         return carry
 
     carry = _init_carry_chunked(cfg, init_params)
-    out = dict(program(events, carry))
-    out["w"] = out["w"][: cfg.n_clients]
-    return _strip_chunk_out(cfg, out)
-
-
-def run_fleet_program_sharded(
-    cfg: FleetConfig,
-    events: Dict[str, jax.Array],
-    clients: Dict[str, jax.Array],
-    reg: Dict[str, jax.Array],
-    init_params: jax.Array,
-    mesh,
-) -> Dict[str, Any]:
-    """The chunked fleet scan partitioned over a 1-D ``(clients,)`` device
-    mesh (:func:`p2pfl_tpu.parallel.fleet_mesh.fleet_clients_mesh`) via
-    ``shard_map`` — bit-identical to :func:`run_fleet_program_chunked`
-    by construction (see docs/design.md "sharded scan semantics"):
-
-    - **Sharded:** only the fleet-scale state — the ``w [N, dim+1]``
-      client rows, laid out ``[P, ncap+1, dim+1]`` with client ``i`` on
-      shard ``i // ncap`` and one LOCAL trash row per shard — plus the
-      per-chunk segment grids (``seg_fwd``/``seg_loc``/``seg_live``,
-      each shard's ≤ ``Cp`` lanes of the chunk in chronological order).
-      Pass A runs on the owner shard only: local gather, the vmapped
-      local round over ``Cp`` instead of ``C`` lanes (the FLOPs win),
-      local scatter.
-    - **Replicated:** everything version-count-sized — global history,
-      windows, counters, the admission scan, the flush loop. Admission
-      is a scalar recurrence over the chunk in arrival order; running
-      it per-shard over only local events would need the OTHER shards'
-      accept/flush verdicts mid-recurrence, so replicating it is what
-      keeps verdicts (and therefore every fold) bit-identical.
-    - **One collective per chunk:** after the local train, each shard
-      contributes its ``[Cp, dim+2]`` packed rows (trained row +
-      pre-chunk adopted version) to a tiled ``all_gather``, and the
-      replicated ``invperm`` grid unpermutes the ``[P·Cp]`` segment
-      layout back to chronological ``[C]``. The gather is pure
-      concatenation — fold keys, weights and sums are computed AFTER it
-      on the replicated side, so no floating-point sum ever
-      reassociates across shards (the cross-shard fold-key rule).
-
-    ``events`` must carry the segment grids + ``invperm`` built by
-    :meth:`MegaFleet._shard_layout` alongside the chronological grids.
-    """
-    from jax.sharding import PartitionSpec
-
-    from p2pfl_tpu.parallel.fleet_mesh import shard_capacity
-
-    axis = mesh.axis_names[0]
-    n_dev = mesh.size
-    ncap = shard_capacity(cfg.n_clients, n_dev)
-    nloc = ncap + 1  # owned rows + the shard-local trash row
-    dim = cfg.dim
-    v_cap = cfg.v_cap
-    GF = cfg.gf_cap
-    train_vec = _make_train_vec(cfg, clients)
-    apply_byz = _make_apply_byz(cfg, clients)
-
-    def adopt_train(c, e):
-        # pass A on the shard's own lanes: e["seg_fwd"] maps each local
-        # segment lane to its chronological chunk position (dead lanes
-        # → lane 0, trained then discarded via the local trash row)
-        fwd = e["seg_fwd"]
-        loc = e["seg_loc"]
-        idx_l = e["client"][fwd]
-        mint_hist = c["mint"][:v_cap]
-        base0 = jnp.searchsorted(mint_hist, e["t_adopt"]).astype(jnp.int32)
-        base0_l = base0[fwd]
-        rows0 = c["w"][loc]
-        wvec0 = rows0[:, :dim]
-        prev0 = rows0[:, dim]
-        base0_f = base0_l.astype(jnp.float32)
-        adopt0 = base0_f > prev0
-        g0 = c["G"][base0_l]
-        starts0 = jnp.where(adopt0[:, None], g0, wvec0)
-        e_l = {"key_hi": e["key_hi"][fwd], "key_lo": e["key_lo"][fwd]}
-        outs0 = train_vec(starts0, idx_l, e_l)
-        newver0 = jnp.maximum(base0_f, prev0)
-        c["w"] = c["w"].at[loc].set(jnp.concatenate([outs0, newver0[:, None]], axis=1))
-        # re-gather (copy law, fix 1), then ONE tiled all_gather: packed
-        # [Cp, dim+2] = trained row ⊕ pre-chunk adopted version, and the
-        # replicated invperm undoes the segment permutation so every
-        # shard sees the same chronological [C] view the chunked engine
-        # computes — concatenation only, nothing reassociates
-        rows_cur = c["w"][loc]
-        packed = jnp.concatenate([rows_cur, prev0[:, None]], axis=1)
-        full = jax.lax.all_gather(packed, axis, tiled=True)
-        chron = full[e["invperm"]]
-        wcur = chron[:, :dim]
-        prev0i = chron[:, dim + 1].astype(jnp.int32)
-        return c, wcur, prev0i, base0
-
-    def writeback_w(c, e, ys, fresh_g, v0):
-        # corrected adopters, owner-shard only: gather the replicated
-        # [C] verdicts at the shard's lanes — no collective needed
-        fwd = e["seg_fwd"]
-        loc = e["seg_loc"]
-        loc_trash = nloc - 1
-        cmask = (ys["adj"] > 0) & e["live"]
-        adj_l = ys["adj"][fwd]
-        starts2 = fresh_g[jnp.clip(adj_l - 1, 0, GF - 1)]
-        e_l = {"key_hi": e["key_hi"][fwd], "key_lo": e["key_lo"][fwd]}
-        couts2 = train_vec(starts2, e["client"][fwd], e_l)
-        newver2 = (v0 + adj_l).astype(jnp.float32)
-        cm_l = cmask[fwd] & e["seg_live"]
-        wt2 = jnp.where(cm_l, loc, loc_trash)
-        c["w"] = c["w"].at[wt2].set(
-            jnp.concatenate([couts2, newver2[:, None]], axis=1)
-        )
-        return c
-
-    chunk_body = _make_chunk_body(
-        cfg, clients, reg, train_vec, apply_byz, adopt_train, writeback_w
-    )
-
-    seg_keys = ("seg_fwd", "seg_loc", "seg_live")
-    ev_seg = {k: events[k] for k in seg_keys}
-    ev_repl = {k: v for k, v in events.items() if k not in seg_keys}
-
-    def body_fn(w, rest, er, es):
-        carry = dict(rest)
-        carry["w"] = w
-
-        def step(c, xs):
-            e = dict(xs[0])
-            e.update(xs[1])
-            return chunk_body(c, e)
-
-        carry, _ = jax.lax.scan(step, carry, (er, es), unroll=cfg.unroll)
-        w_out = carry.pop("w")
-        return w_out, carry
-
-    shard = PartitionSpec(axis)
-    seg = PartitionSpec(None, axis)
-    repl = PartitionSpec()
-    program = jax.jit(
-        jax.shard_map(
-            body_fn,
-            mesh=mesh,
-            in_specs=(shard, repl, repl, seg),
-            out_specs=(shard, repl),
-        )
-    )
-    carry = _init_carry_chunked(cfg, init_params, n_w_rows=n_dev * nloc)
-    w0 = carry.pop("w")
-    w_out, out = program(w0, carry, ev_repl, ev_seg)
-    out = dict(out)
-    # un-map the block-sharded rows (drop each shard's trash row and the
-    # last shard's padding) back to the chunked engine's [N, dim+1]
-    w_full = jnp.reshape(w_out, (n_dev, nloc, dim + 1))[:, :ncap]
-    out["w"] = jnp.reshape(w_full, (n_dev * ncap, dim + 1))[: cfg.n_clients]
-    return _strip_chunk_out(cfg, out)
+    return _strip_chunk_out(cfg, dict(program(events, carry)))

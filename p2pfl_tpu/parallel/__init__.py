@@ -10,7 +10,6 @@ reduction that XLA lowers to an all-reduce over ICI. Control decisions
 boundary inside a round.
 """
 
-from p2pfl_tpu.parallel.fleet_mesh import fleet_clients_mesh, shard_capacity
 from p2pfl_tpu.parallel.mesh import (
     federation_mesh,
     node_slices,
@@ -32,9 +31,7 @@ __all__ = [
     "SpmdLmFederation",
     "SpmdLoraFederation",
     "federation_mesh",
-    "fleet_clients_mesh",
     "node_slices",
-    "shard_capacity",
     "pipeline_apply",
     "pipeline_mesh",
     "pipelined_lm_apply",

@@ -153,12 +153,6 @@ class Settings:
     MESH_NODES_AXIS: str = "nodes"
     MESH_MODEL_AXIS: str = "model"
     MESH_DATA_AXIS: str = "data"
-    # ``clients`` is the megafleet engine's 1-D mesh axis: the simulated
-    # edge population's parameter rows are sharded over it while the
-    # small admission/window state stays replicated
-    # (parallel/fleet_mesh.py, ops/fleet_kernels.py
-    # run_fleet_program_sharded).
-    MESH_CLIENTS_AXIS: str = "clients"
     # Outgoing gRPC frame format: "envelope" (compact JSON-header frames,
     # the default) | "protobuf" (the reference's node.proto schema —
     # communication/proto_wire.py; control plane fully interoperable with
@@ -390,30 +384,9 @@ class Settings:
     # arrivals, runs the sequential admission logic as cheap scalar ops,
     # and scatters every dense-carry write back in one predicated pass —
     # amortizing XLA:CPU's per-op dispatch over the chunk. 1 selects the
-    # per-event reference engine (the bit-parity baseline).
-    # 0 = autotune: measure a handful of candidate chunk sizes on the
-    # live device once and pin the winner in the fleet-tune disk cache
-    # (ops/fleet_autotune.py — the ops/autotune.py device-kind-keyed
-    # pattern), so later runs replay the choice without re-measuring.
+    # per-event reference engine (the bit-parity baseline); anything
+    # below 1 is refused at construction.
     MEGAFLEET_CHUNK: int = 256
-    # Device shards of the sharded megafleet engine
-    # (run_fleet_program_sharded): the per-client parameter rows are
-    # partitioned over MESH_CLIENTS_AXIS while admission stays
-    # replicated, so verdicts are bit-identical to the single-device
-    # chunked engine. 0/1 = single-device chunked engine.
-    MEGAFLEET_SHARDS: int = 0
-    # Per-shard segment head-room of the sharded chunk layout: each
-    # shard owns ceil(SLACK * chunk / shards) lanes of a chunk, so a
-    # mildly imbalanced chunk (one shard's clients over-represented)
-    # still packs without closing the chunk early. Raising it trades
-    # per-shard FLOPs (chunk/shards * SLACK trained lanes per shard)
-    # for fewer short chunks; 2.0 keeps the vectorized layout path on
-    # every schedule the simulator generates.
-    MEGAFLEET_SHARD_SLACK: float = 2.0
-    # Override path of the fleet-tune cache file (chunk-size winners per
-    # device kind / shard count). Empty = $P2PFL_FLEET_TUNE_CACHE or
-    # ~/.cache/p2pfl_tpu/fleet_tune.json.
-    FLEET_TUNE_CACHE: str = ""
     # --- Byzantine robustness (federation/defense.py, ops/aggregation.py) ---
     # Which merge kernel the async plane's BufferedAggregator folds a
     # flushed buffer with: "fedavg" is the FedBuff staleness-weighted mean
@@ -712,9 +685,6 @@ def set_test_settings() -> None:
     # small odd chunk in tests: every parity suite then crosses chunk
     # boundaries (masked tails, mid-chunk flushes, fresh-mint adoption)
     Settings.MEGAFLEET_CHUNK = 48
-    Settings.MEGAFLEET_SHARDS = 0
-    Settings.MEGAFLEET_SHARD_SLACK = 2.0
-    Settings.FLEET_TUNE_CACHE = ""
     Settings.TRAIN_SET_SIZE = 4
     Settings.VOTE_TIMEOUT = 10.0
     Settings.AGGREGATION_TIMEOUT = 10.0
