@@ -83,8 +83,13 @@ def restore_learner(directory: str, learner, step: Optional[int] = None) -> None
 def _federation_state(fed) -> dict:
     """Everything a resumed federation needs: params + opt state + any
     algorithm state (SCAFFOLD control variates, FedOpt server moments) —
-    dropping those on resume would silently degrade the algorithm."""
-    state = {"params": fed.params, "opt_state": fed.opt_state}
+    dropping those on resume would silently degrade the algorithm. A
+    federation that keeps no optimizer state between rounds
+    (``SpmdLoraFederation(keep_opt_state=False)``: ``opt_state`` is ``None``)
+    has none to save."""
+    state = {"params": fed.params}
+    if fed.opt_state is not None:
+        state["opt_state"] = fed.opt_state
     if getattr(fed, "scaffold", False):
         state["c_global"] = fed.c_global
         state["c_local"] = fed.c_local
@@ -106,7 +111,7 @@ def restore_federation(directory: str, fed, step: Optional[int] = None) -> None:
             raise FileNotFoundError(f"no checkpoint under {directory}")
         state = mgr.restore(use, args=ocp.args.StandardRestore(_federation_state(fed)))
     fed.params = state["params"]
-    fed.opt_state = state["opt_state"]
+    fed.opt_state = state.get("opt_state")
     if getattr(fed, "scaffold", False):
         fed.c_global = state["c_global"]
         fed.c_local = state["c_local"]

@@ -1244,7 +1244,7 @@ class SpmdFederation:
         """
         from p2pfl_tpu.management.logger import logger
 
-        donated = [self.params, self.opt_state]
+        donated = [self.params, self.opt_state]  # a None opt_state has no leaves
         if self.scaffold:
             donated += [self.c_global, self.c_local]
         if self.server_opt:
@@ -1384,9 +1384,7 @@ class SpmdFederation:
         p1 = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), self.params
         )
-        o1 = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), self.opt_state
-        )
+        o1 = jax.eval_shape(self.tx.init, p1)  # ``self.opt_state`` may be None (LoRA, not kept)
         bx = jax.ShapeDtypeStruct(
             (self.batch_size,) + tuple(self.x_all.shape[2:]), self.x_all.dtype
         )

@@ -61,7 +61,7 @@ def _on_own_nodes(fn, sharding: Optional[NamedSharding], n_stacked: int):
 
 def _lora_round_core(
     stacked_lora,  # [N, ...] adapters
-    opt_states,  # [N, ...]
+    opt_states,  # [N, ...] when keep_opt_state, else not read
     base,  # shared frozen params (no node axis)
     x_all,  # [N, S, T] int tokens
     y_all,  # [N, S, T]
@@ -88,12 +88,27 @@ def _lora_round_core(
     FFN recompute entirely, a net model-MFU win. 0 = single vmap. On a
     mesh of k devices each device scans its own N/k nodes, ``node_chunk //
     k`` at a time (at least one), so the count in flight is the same.
+
+    ``keep_opt_state``: whether the optimizer state outlives the round. True:
+    ``opt_states`` ``[N, ...]`` is scanned over with the adapters and the
+    trained state comes back in second place. False: every node starts from
+    ``tx.init`` of its own adapters inside the vmapped chunk (the state of
+    the nodes in flight is all that exists), ``opt_states`` is not read
+    (whatever is passed there is an unused operand) and ``None`` comes back
+    in its place. N nodes' fresh moments as an argument and a result are
+    zeros that cost memory: at 32 nodes of a 7B block's adapters, 2 x 1.34 GB
+    that the TPU compiler fitted by running a backward matmul twice.
     """
     n = mask.shape[0]
     if node_chunk and node_chunk < n and n % node_chunk:
         raise ValueError(f"node_chunk {node_chunk} must divide n_nodes {n}")
+    if not keep_opt_state:
+        opt_states = None
 
     def node_fn(lora, opt_state, x, y, idx, base):
+        if not keep_opt_state:
+            opt_state = tx.init(lora)
+
         def epoch_body(carry, ep_idx):
             lo, o = carry
             xs = jnp.take(x, ep_idx, axis=0)
@@ -119,7 +134,7 @@ def _lora_round_core(
             return (lo, o), jnp.mean(losses)
 
         (lora, opt_state), losses = jax.lax.scan(epoch_body, (lora, opt_state), idx)
-        return lora, opt_state, jnp.mean(losses)
+        return lora, opt_state if keep_opt_state else None, jnp.mean(losses)
 
     def train(lora, opt, x, y, idx, base):
         """Every node on the leading axis — all N, or one device's share of
@@ -156,12 +171,11 @@ def _lora_round_core(
         out = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (n, *a.shape)), agg_lora)
         if out_sharding is not None:
             out = jax.tree.map(lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out)
-        out_opt = trained_opt if keep_opt_state else jax.vmap(tx.init)(out)
-        if out_sharding is not None:
-            out_opt = jax.tree.map(
-                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), out_opt
+        if out_sharding is not None:  # trained_opt is None unless it is kept
+            trained_opt = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, out_sharding), trained_opt
             )
-        return out, out_opt, jnp.mean(losses, where=mask.astype(bool))
+        return out, trained_opt, jnp.mean(losses, where=mask.astype(bool))
 
 
 _LORA_STATICS = (
@@ -175,7 +189,8 @@ def spmd_lora_round(
     *, remat=None, **kw,
 ):
     # ``remat`` is ignored (an unused operand, pruned from the program):
-    # ``benchmark/compile_check.py`` still passes it
+    # ``benchmark/compile_check.py`` still passes it — and a full optimizer
+    # tree in second place, which is pruned likewise unless it is kept
     return _lora_round_core(
         stacked_lora, opt_states, base, x_all, y_all, perm, mask, weights, sel_idx, **kw
     )
@@ -190,8 +205,11 @@ def spmd_lora_rounds_fused(
     ``perms``: [R, N, epochs, nb, bs]. Adapters are tiny (config 5:
     57 k params/node), so a round is dispatch-dominated — fusing amortizes
     the host↔device round-trip R×, same as :func:`spmd_rounds_fused`.
-    Returns (adapters', opt', losses [R]).
+    Returns (adapters', opt', losses [R]); ``opt'`` is ``None`` and the scan
+    carries the adapters alone unless ``keep_opt_state``.
     """
+    if not kw.get("keep_opt_state"):
+        opt_states = None
 
     def body(carry, perm):
         p, o = carry
@@ -217,7 +235,14 @@ def spmd_lora_eval(stacked_lora, base, x_test, y_test, *, module, sharding=None)
 
 
 class SpmdLoraFederation(SpmdFederation):
-    """SPMD federation over adapter subtrees; frozen base stored once."""
+    """SPMD federation over adapter subtrees; frozen base stored once.
+
+    Round-carried state is the adapters ``[N, ...]`` and, with
+    ``keep_opt_state=True``, the optimizer state ``[N, ...]``. Without it
+    ``opt_state`` is ``None``: each node makes its fresh state inside the
+    round's program (:func:`_lora_round_core`), so no N-wide tree of zeros
+    is staged, passed in, or written back.
+    """
 
     def __init__(
         self,
@@ -239,12 +264,12 @@ class SpmdLoraFederation(SpmdFederation):
 
     # node-stacked state = adapters only; base placed separately
     def _stage_state(self) -> None:
-        n = self.n
+        n, keep = self.n, self.keep_opt_state
 
         @partial(jax.jit, out_shardings=(self._shard, self._shard))
         def stage(tree):
             stacked = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n, *x.shape)), tree)
-            return stacked, jax.vmap(self.tx.init)(stacked)
+            return stacked, jax.vmap(self.tx.init)(stacked) if keep else None
 
         self.params, self.opt_state = stage(self._lora_template)
         if self._mp_base:

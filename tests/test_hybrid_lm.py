@@ -209,7 +209,8 @@ def test_default_pattern_keeps_the_parents_round():
     """The guard for the dense LoRA cells: parameter tree paths and the lowered
     round's operation counts of a default-pattern ``scan_layers`` LoRA model
     equal what the parent commit gave (recorded before ``transformer.py`` was
-    edited: ``tests/fixtures/lora_round_parent.json``)."""
+    edited: ``tests/fixtures/lora_round_parent.json``; its ``note`` names the
+    three counts that moved when the not-kept optimizer state left the round)."""
     want = json.loads((ROOT / "tests" / "fixtures" / "lora_round_parent.json").read_text())
     cfg = TransformerConfig(
         vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, ffn_hidden=128,

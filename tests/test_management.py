@@ -124,20 +124,42 @@ def test_learner_checkpoint_roundtrip(tmp_path):
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
 
 
-def test_federation_checkpoint_roundtrip(tmp_path):
-    import jax
-
+def _mlp_federation(seed):
     from p2pfl_tpu.learning.dataset import FederatedDataset
     from p2pfl_tpu.models import mlp
     from p2pfl_tpu.parallel import SpmdFederation
 
     data = FederatedDataset.synthetic_mnist(n_train=1024, n_test=128)
-    fed = SpmdFederation.from_dataset(mlp(), data, n_nodes=4, batch_size=64, vote=False)
+    return SpmdFederation.from_dataset(mlp(seed=seed), data, n_nodes=4, batch_size=64, vote=False)
+
+
+def _lora_federation_without_kept_state(seed):
+    from p2pfl_tpu.learning.dataset import FederatedDataset
+    from p2pfl_tpu.models.transformer import TransformerConfig, tiny_transformer
+    from p2pfl_tpu.parallel import SpmdLoraFederation
+
+    cfg = TransformerConfig(vocab_size=64, dim=32, n_layers=1, n_heads=2, n_kv_heads=2, ffn_hidden=64, lora_rank=2)
+    data = FederatedDataset.synthetic_lm(vocab_size=64, seq_len=16, n_train=32, n_test=16)
+    return SpmdLoraFederation.from_dataset(
+        tiny_transformer(seq_len=16, seed=seed, cfg=cfg), data, n_nodes=4, batch_size=4, vote=False,
+        keep_opt_state=False,
+    )
+
+
+@pytest.mark.parametrize("build", [_mlp_federation, _lora_federation_without_kept_state])
+def test_federation_checkpoint_roundtrip(tmp_path, build):
+    """Params, round and optimizer state round-trip; a federation that keeps
+    no optimizer state (``opt_state is None``) saves none and restores None."""
+    import jax
+
+    fed = build(0)
     fed.run_round()
     fed.save(str(tmp_path / "fed"))
 
-    fed2 = SpmdFederation.from_dataset(mlp(seed=5), data, n_nodes=4, batch_size=64, vote=False)
+    fed2 = build(5)
     fed2.restore(str(tmp_path / "fed"))
     assert fed2.round == 1
-    for a, b in zip(jax.tree.leaves(fed.params), jax.tree.leaves(fed2.params)):
+    assert (fed2.opt_state is None) == (fed.opt_state is None) == (build is _lora_federation_without_kept_state)
+    for a, b in zip(jax.tree.leaves((fed.params, fed.opt_state)), jax.tree.leaves((fed2.params, fed2.opt_state))):
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    fed2.run_round()  # and the restored federation runs on

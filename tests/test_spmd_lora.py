@@ -117,32 +117,126 @@ def test_node_chunk_matches_unchunked():
     """``node_chunk`` reorders the node axis from one vmap into a scan of
     vmapped chunks — identical round results, and a non-dividing chunk
     size is rejected."""
-    import numpy as np
-
-    from p2pfl_tpu.learning.dataset import FederatedDataset
-    from p2pfl_tpu.models.transformer import TransformerConfig, tiny_transformer
-    from p2pfl_tpu.parallel import SpmdLoraFederation
-
-    data = FederatedDataset.synthetic_lm(
-        vocab_size=64, seq_len=16, n_train=32, n_test=16
-    )
-
-    def make(nc):
-        cfg = TransformerConfig(
-            vocab_size=64, dim=32, n_layers=2, n_heads=2, n_kv_heads=2,
-            ffn_hidden=64, lora_rank=2, remat=True, scan_layers=True,
-        )
-        m = tiny_transformer(seq_len=16, seed=0, cfg=cfg)
-        return SpmdLoraFederation.from_dataset(
-            m, data, n_nodes=4, batch_size=4, vote=False, seed=3, node_chunk=nc
-        )
-
-    a, b = make(0), make(2)
+    a, b = _small(False, node_chunk=0), _small(False, node_chunk=2)
     ea, eb = a.run_round(epochs=1), b.run_round(epochs=1)
     assert float(ea["train_loss"]) == pytest.approx(float(eb["train_loss"]), abs=1e-6)
     for x, y in zip(jax.tree.leaves(a.params), jax.tree.leaves(b.params)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
 
-    bad = make(3)
+    bad = _small(False, node_chunk=3)
     with pytest.raises(ValueError, match="node_chunk"):
         bad.run_round(epochs=1)
+
+
+# --- keep_opt_state: what a round carries --------------------------------
+
+
+def _small(keep, n_nodes=4, node_chunk=2):
+    cfg = TransformerConfig(
+        vocab_size=64, dim=32, n_layers=2, n_heads=2, n_kv_heads=2, ffn_hidden=64, lora_rank=2, remat=True,
+        scan_layers=True,
+    )
+    data = FederatedDataset.synthetic_lm(vocab_size=64, seq_len=16, n_train=8 * n_nodes, n_test=16)
+    return SpmdLoraFederation.from_dataset(
+        tiny_transformer(seq_len=16, seed=0, cfg=cfg), data, n_nodes=n_nodes, batch_size=4, vote=False, seed=3,
+        node_chunk=node_chunk, keep_opt_state=keep,
+    )
+
+
+def _bit_equal(a, b) -> bool:
+    return all(np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def test_fresh_adam_in_the_node_is_fresh_adam_from_outside():
+    """Round 1 starts from zero moments either way, so kept and not kept
+    agree to the bit; from round 2 on the kept moments differ from zeros."""
+    kept, fresh = _small(True), _small(False)
+    assert fresh.opt_state is None and kept.opt_state is not None
+    ek, ef = kept.run_round(epochs=1), fresh.run_round(epochs=1)
+    assert fresh.opt_state is None
+    assert float(ek["train_loss"]) == float(ef["train_loss"])
+    assert _bit_equal(kept.params, fresh.params)
+    kept.run_round(epochs=1), fresh.run_round(epochs=1)
+    assert not _bit_equal(kept.params, fresh.params)
+
+
+def test_not_kept_fused_rounds_match_sequential_rounds():
+    seq, fused = _small(False), _small(False)
+    losses = [float(seq.run_round(epochs=1)["train_loss"]) for _ in range(2)]
+    entries = fused.run_fused(2, epochs=1)
+    assert fused.opt_state is None and fused.round == 2
+    np.testing.assert_allclose([float(e["train_loss"]) for e in entries], losses, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(seq.params), jax.tree.leaves(fused.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def _main_signature(lowered):
+    """(operand types, result types) of the lowered module's entry function."""
+    import re
+
+    head = re.search(r"func\.func public @main\((.*?)\) -> \((.*?)\) \{", lowered.as_text(), re.S)
+    tensor = re.compile(r"tensor<([0-9x]*)x?([a-z]+[0-9]+)>")
+    return tensor.findall(head.group(1)), tensor.findall(head.group(2))
+
+
+def test_not_kept_round_has_no_node_wide_optimizer_operand_or_result():
+    """The only ``[N, ...]`` float arrays that enter or leave the round's
+    program are the adapters — and an optimizer tree in second place (how
+    ``benchmark/compile_check.py`` calls it) is pruned: the same program."""
+    from p2pfl_tpu.parallel.spmd_lora import spmd_lora_round
+
+    n = 6  # no other dimension of the model, the data or the optimizer is 6
+    fed = _small(False, n_nodes=n)
+    args, statics = fed._round_call(1)
+    lowered = spmd_lora_round.lower(*args, **statics)
+
+    def node_wide(types):  # float, rank two or more (the mask and the weights are [N] floats)
+        return [t for t in types if t[0].startswith(f"{n}x") and t[0].count("x") > 1 and t[1].startswith(("f", "bf"))]
+
+    operands, results = _main_signature(lowered)
+    leaves = len(jax.tree.leaves(fed.params))
+    assert len(node_wide(operands)) == leaves and len(node_wide(results)) == leaves
+    assert len(results) == leaves + 1  # and the loss
+
+    opt = jax.vmap(fed.tx.init)(fed.params)
+    with_tree = spmd_lora_round.lower(args[0], opt, *args[2:], **statics)
+    assert with_tree.as_text() == lowered.as_text()
+
+    kept = _small(True, n_nodes=n)
+    k_args, k_statics = kept._round_call(1)
+    k_operands, k_results = _main_signature(spmd_lora_round.lower(*k_args, **k_statics))
+    assert len(node_wide(k_operands)) == len(node_wide(k_results)) == 3 * leaves  # adapters + two moments
+
+
+def test_kept_round_is_the_program_it_was():
+    """With ``keep_opt_state=True`` the round traces to the program of the
+    commit before the optimizer state left the not-kept round (ba2aefb):
+    939 equations (nested ones counted), and the sequence of primitives with
+    their result types hashes to 12552ce8600d1968 — both recorded there."""
+    import hashlib
+
+    from p2pfl_tpu.parallel.spmd_lora import spmd_lora_round
+
+    def walk(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out.append(eqn.primitive.name + ":" + ",".join(str(v.aval) for v in eqn.outvars))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, out)
+        return out
+
+    args, statics = _small(True)._round_call(1)
+    closed = jax.make_jaxpr(lambda *a: spmd_lora_round(*a, **statics))(*args)
+    eqns = walk(closed.jaxpr, [])
+    assert len(eqns) == 939
+    assert hashlib.sha256("\n".join(eqns).encode()).hexdigest()[:16] == "12552ce8600d1968"
+    assert (len(closed.jaxpr.invars), len(closed.jaxpr.outvars)) == (42, 26)
+
+
+def test_recovery_after_a_consumed_donation_restages_without_optimizer_state():
+    fed = _small(False)
+    for leaf in jax.tree.leaves(fed.params):
+        leaf.delete()
+    fed._recover_donated_state()
+    assert fed.opt_state is None
+    fed.run_round(epochs=1)
+    assert np.isfinite(float(fed.history[-1]["train_loss"]))
