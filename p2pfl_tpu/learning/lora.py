@@ -23,7 +23,8 @@ import optax
 from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import NodeLearner, adam, ce_eval
 from p2pfl_tpu.management.logger import logger
-from p2pfl_tpu.models.base import FlaxModel, apply_with_aux
+from p2pfl_tpu.management.profiling import scope
+from p2pfl_tpu.models.base import FlaxModel
 
 Pytree = Any
 
@@ -60,12 +61,32 @@ def merge_params(base: dict, overlay: dict) -> dict:
     return out
 
 
+def _lm_forward(lora, base, module, x, y):
+    """(training loss, logits, statistics, routing): CE + any sown auxiliary
+    losses (MoE router balance); what the model sowed into ``"moe_stats"`` —
+    each name's mean over the layers that sowed it, ``{}`` for a model that
+    sows none (an expert layer's ``load_max_over_mean``); and the
+    ``"moe_routing"`` collection as sown (the experts each row chose, from THIS
+    forward — a comparison must not take them from another program: a TPU
+    rounds a near-tie differently from one compiled program to the next)."""
+    params = merge_params(base, lora)
+    logits, mut = module.apply({"params": params}, x, mutable=["moe_losses", "moe_stats", "moe_routing"])
+    leaves = jax.tree.leaves(mut.get("moe_losses", {}))
+    aux = sum(leaves) if leaves else jnp.zeros((), jnp.float32)
+    with scope("head"):
+        ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+    found: dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(mut.get("moe_stats", {})):
+        name = next(k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey))
+        found.setdefault("moe_" + name, []).append(jnp.mean(leaf))
+    stats = {name: jnp.mean(jnp.stack(vals)) for name, vals in found.items()}
+    return ce + aux, logits, stats, mut.get("moe_routing", {})
+
+
 def _lm_loss(lora, base, module, x, y):
     """Training loss: CE + any sown auxiliary losses (MoE router balance)."""
-    params = merge_params(base, lora)
-    logits, aux = apply_with_aux(module, params, x)
-    ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
-    return ce + aux, logits
+    loss, logits, _, _ = _lm_forward(lora, base, module, x, y)
+    return loss, logits
 
 
 @partial(jax.jit, static_argnames=("module", "tx"), donate_argnums=(1,))

@@ -50,6 +50,12 @@ DEVICE_SCOPES = (
     "ssm_conv",  # MambaMixer: the causal depthwise convolution and its silu
     "ssm_scan_fwd",  # ops/selective_scan: everything the op runs forward (kernel and glue)
     "ssm_scan_bwd",  # ops/selective_scan: everything its backward runs
+    "mla",  # MLAttention: the glue between its five projections (split, RoPE, concatenate, broadcast)
+    "moe_route",  # ExpertFFN: router, top-k, sort indices, group sizes
+    "moe_experts",  # ExpertFFN: gather into sorted order, both grouped matmuls, the activation
+    "moe_gmm",  # ops/grouped_matmul: the product alone (the Mosaic call p2pfl_gmm on a TPU), inside moe_experts
+    "moe_combine",  # ExpertFFN: unsort, weigh, sum over the k, add the shared expert's output
+    "head",  # CausalLM / _lm_loss: final norm's output x embedding^T, and the loss
 )
 
 

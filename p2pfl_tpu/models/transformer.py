@@ -13,7 +13,12 @@ period, and the stack repeats the period:
   attention, with RoPE unless ``rope_theta`` is ``None``;
 - ``"mamba"``: the Mamba-1 mixer (:class:`MambaMixer`) — causal depthwise
   convolution, input-dependent step sizes, and the selective scan of
-  ``ops/selective_scan.py`` (the Jamba hybrids interleave it with attention).
+  ``ops/selective_scan.py`` (the Jamba hybrids interleave it with attention);
+- ``"mla_dense"`` / ``"mla_experts"``: latent attention (:class:`MLAttention`,
+  the DeepSeek-V3 block) followed by the dense SwiGLU or by the dropless
+  sigmoid-routed expert layer (:class:`ExpertFFN`) — these two kinds name the
+  feed-forward as well as the mixer (:data:`LAYER_KINDS`), so "one dense layer,
+  then N expert layers" is one period of two runs.
 
 Attention backends — pick with ``tiny_transformer(attn=...)``:
 
@@ -52,20 +57,35 @@ _REMAT_SAVE_NAMES = {
     "mlp_ssm": ("ffn_gate", "ffn_up", "attn_q", "attn_k", "attn_v", *_SSM_OUT),
     "mlp_ssm_in": ("ffn_gate", "ffn_up", "attn_q", "attn_k", "attn_v", *_SSM_OUT, "ssm_in", "ssm_dt"),
 }
-LAYER_KINDS = ("attention", "mamba")
+# layer kind -> (sequence mixer, feed-forward). ``None`` is the config-wide
+# rule the first two kinds have always had (``MoEMLP`` if ``n_experts`` else
+# ``MLP``); the latent-attention kinds name theirs.
+LAYER_KINDS = {
+    "attention": ("attention", None),
+    "mamba": ("mamba", None),
+    "mla_dense": ("mla", "mlp"),
+    "mla_experts": ("mla", "experts"),
+}
 
 
-def _remat_policy(name: Optional[str]):
-    """Map ``TransformerConfig.remat_policy`` to a jax.checkpoint policy."""
-    if name is None:
+def _remat_policy(name: Optional[str], kind: str = "attention"):
+    """Map ``TransformerConfig.remat_policy`` to a jax.checkpoint policy for a
+    block of ``kind``. An expert layer ALWAYS keeps its routing choice
+    (``moe_chosen``, ``[S, k]`` int32): the re-forward must use the forward's
+    experts, and recomputing the choice does not guarantee that — a TPU fuses
+    the re-forward's norm differently, a bfloat16 input rounds the other way,
+    and a near-tie flips (the backward would then differentiate another
+    function than the forward ran)."""
+    keep = ("moe_chosen",) if LAYER_KINDS[kind][1] == "experts" else ()
+    if name is None and not keep:
         return None  # full per-block remat: save nothing inside the block
     try:
-        names = _REMAT_SAVE_NAMES[name]
+        names = _REMAT_SAVE_NAMES[name] if name is not None else ()
     except KeyError:
         raise ValueError(
             f"unknown remat_policy {name!r} (None|{'|'.join(_REMAT_SAVE_NAMES)})"
         ) from None
-    return jax.checkpoint_policies.save_only_these_names(*names)
+    return jax.checkpoint_policies.save_only_these_names(*names, *keep)
 
 
 @dataclass(frozen=True)
@@ -150,12 +170,35 @@ class TransformerConfig:
     # provably re-traces (the guarantee the old BWD_MODE global broke).
     # None = dense XLA attention unless the caller overrides attn/attn_fn.
     flash_config: Optional[FlashConfig] = None
+    # epsilon of every RMSNorm (the published value of the model that is run)
+    norm_eps: float = 1e-6
+    # Latent attention (``"mla_*"`` layers only; DeepSeek-V3 / glm4_moe_lite
+    # names): ranks of the two low-rank paths, the un-rotated and rotated parts
+    # of a query/key head, and the value head. ``n_heads`` heads; the rotated
+    # key part is ONE head shared by all of them.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # The dropless expert layer (``"mla_experts"`` layers only): routed experts
+    # and how many a token takes, one expert's width, shared experts (run on
+    # every token, one MLP of ``shared_experts * expert_hidden``), the factor on
+    # the normalised routing weights, the row tile of the grouped matmul and
+    # its path (None = the Mosaic kernel on a TPU, XLA elsewhere).
+    routed_experts: int = 0
+    experts_per_token: int = 0
+    expert_hidden: int = 0
+    shared_experts: int = 0
+    routed_scale: float = 1.0
+    expert_tile_m: int = 128
+    expert_impl: Optional[str] = None
 
     def __post_init__(self) -> None:
         pattern = tuple(self.layer_pattern)
         object.__setattr__(self, "layer_pattern", pattern)  # a list would not hash
         if not pattern or any(kind not in LAYER_KINDS for kind in pattern):
-            raise ValueError(f"layer_pattern {pattern!r}: one or more of {LAYER_KINDS}")
+            raise ValueError(f"layer_pattern {pattern!r}: one or more of {tuple(LAYER_KINDS)}")
         if self.n_layers % len(pattern):
             raise ValueError(
                 f"n_layers {self.n_layers} is not a whole number of periods of {len(pattern)} layers"
@@ -172,12 +215,13 @@ class TransformerConfig:
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         xf = x.astype(jnp.float32)
-        norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+        norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
         return (norm * scale).astype(self.dtype)
 
 
@@ -226,6 +270,26 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _attend_fn(cfg: TransformerConfig, attn_fn: Optional[Callable]) -> Callable:
+    """The ``(q, k, v) -> out`` of an attention module: the explicit callable,
+    else the config's pinned flash schedule, else fused dense causal attention."""
+    if attn_fn is not None:
+        return attn_fn
+    if cfg.flash_config is not None:
+        # cfg-pinned flash schedule: every path that builds Blocks from
+        # the config alone (pipeline stages, spmd train steps) picks up
+        # the SAME statically-keyed kernel without threading a callable
+        from p2pfl_tpu.ops.flash_attention import flash_attention
+
+        return partial(
+            flash_attention,
+            causal=True,
+            config=cfg.flash_config,
+            interpret=jax.default_backend() != "tpu",
+        )
+    return causal_attention
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None  # (q, k, v) -> out; default fused causal
@@ -253,36 +317,22 @@ class Attention(nn.Module):
         rep = cfg.n_heads // cfg.n_kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-        if self.attn_fn is not None:
-            attend = self.attn_fn
-        elif cfg.flash_config is not None:
-            # cfg-pinned flash schedule: every path that builds Blocks from
-            # the config alone (pipeline stages, spmd train steps) picks up
-            # the SAME statically-keyed kernel without threading a callable
-            from p2pfl_tpu.ops.flash_attention import flash_attention
-
-            attend = partial(
-                flash_attention,
-                causal=True,
-                config=cfg.flash_config,
-                interpret=jax.default_backend() != "tpu",
-            )
-        else:
-            attend = causal_attention
-        out = attend(q, k, v).reshape(b, t, cfg.dim)
+        out = _attend_fn(cfg, self.attn_fn)(q, k, v).reshape(b, t, cfg.dim)
         return dense(cfg.dim, name="wo")(out)
 
 
 class MLP(nn.Module):
     cfg: TransformerConfig
+    hidden: Optional[int] = None  # None = cfg.ffn_hidden (a shared expert gives its own)
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
         rank = cfg.lora_rank if cfg.lora_mlp else 0
         dense = partial(LoRADense, rank=rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
-        gate = checkpoint_name(dense(cfg.ffn_hidden, name="w1")(x), "ffn_gate")
-        up = checkpoint_name(dense(cfg.ffn_hidden, name="w3")(x), "ffn_up")
+        hidden = self.hidden or cfg.ffn_hidden
+        gate = checkpoint_name(dense(hidden, name="w1")(x), "ffn_gate")
+        up = checkpoint_name(dense(hidden, name="w3")(x), "ffn_up")
         return dense(cfg.dim, name="w2")(nn.silu(gate) * up)
 
 
@@ -386,6 +436,213 @@ class MoEMLP(nn.Module):
         return out.reshape(b, t, d)
 
 
+class MLAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V3 / ``glm4_moe_lite``)::
+
+        cq = norm(x W_qa);             q = cq W_qb          -> [T, H, nope + rope]
+        (ckv, kr) = split(x W_kva);    (k_nope, v) = split(norm(ckv) W_kvb -> [T, H, nope + v])
+        q = [q_nope | rope(q_rope)];   k = [k_nope | rope(kr) for every head]
+        out = causal softmax(q k^T / sqrt(nope + rope)) v;   y = out W_o
+
+    Two low-rank paths with an inner RMSNorm each; the rotated key part is ONE
+    head shared by all ``n_heads``. All five projections carry adapters. The
+    attention itself is whatever ``attn_fn`` / ``cfg.flash_config`` says, as in
+    :class:`Attention` — the flash kernels see ordinary q, k, v at the full
+    head width, so q·k and v must be equally wide there (GLM-4.7-Flash: 256)."""
+
+    cfg: TransformerConfig
+    attn_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        heads, nope, rot, vd = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        dense = partial(LoRADense, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
+        norm = partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+        b, t = x.shape[:2]
+        cq = dense(cfg.q_lora_rank, name="q_a")(x)
+        with scope("mla"):
+            cq = norm(name="q_norm")(cq)
+        q = dense(heads * (nope + rot), name="q_b")(cq)
+        ckv_kr = dense(cfg.kv_lora_rank + rot, name="kv_a")(x)
+        with scope("mla"):
+            ckv, kr = ckv_kr[..., :cfg.kv_lora_rank], ckv_kr[..., cfg.kv_lora_rank:]
+            ckv = norm(name="kv_norm")(ckv)
+        kv = dense(heads * (nope + vd), name="kv_b")(ckv)
+        with scope("mla"):
+            q = q.reshape(b, t, heads, nope + rot)
+            kv = kv.reshape(b, t, heads, nope + vd)
+            kr = kr.reshape(b, t, 1, rot)
+            q_rot = q[..., nope:]
+            if cfg.rope_theta is not None:
+                q_rot, kr = rope(q_rot, cfg.rope_theta), rope(kr, cfg.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(kr, (b, t, heads, rot))], axis=-1)
+            v = kv[..., nope:]
+            q = checkpoint_name(q, "attn_q")
+            k = checkpoint_name(k, "attn_k")
+            v = checkpoint_name(v, "attn_v")
+        out = _attend_fn(cfg, self.attn_fn)(q, k, v).reshape(b, t, heads * vd)
+        return dense(cfg.dim, name="o")(out)
+
+
+def _bank_init(key, shape, dtype=jnp.bfloat16):
+    """An expert bank as a checkpoint stores it: drawn in float32 (lecun-normal
+    over each expert's own fan-in), rounded ONCE to ``dtype``."""
+    std = 1.0 / math.sqrt(shape[-2])
+    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def router_scores(x, router):
+    """``s = sigmoid(x W_g)`` in float32 on the float32 cast of the input, as the
+    published modelling code computes it. ``x``: ``[S, D]``."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )  # HIGHEST: a TPU's default float32 matmul is one bfloat16 pass
+    return jax.nn.sigmoid(logits)
+
+
+def choose_experts(s, bias, top_k: int):
+    """``[S, k]`` int32: the ``top_k`` of ``s + bias`` — the bias CHOOSES only.
+    No group limit (``n_group`` = ``topk_group`` = 1); no gradient (a choice)."""
+    _, chosen = jax.lax.top_k(jax.lax.stop_gradient(s) + bias.astype(jnp.float32), top_k)
+    return chosen.astype(jnp.int32)
+
+
+def routing_weights(s, chosen, scale: float):
+    """WEIGH by ``s`` without the bias: the chosen experts' scores, normalised
+    over the chosen (``norm_topk_prob``), times ``scale``."""
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
+
+
+@jax.custom_vjp
+def _to_expert_rows(x, token_of_row, row_of_assignment):
+    """``[S, D]`` tokens -> ``[rows, D]`` in the grouped layout (padding rows
+    zero). A gather forward AND backward: the cotangent of a token is the sum
+    of its ``k`` rows', read back through ``row_of_assignment``."""
+    del row_of_assignment
+    return jnp.take(x, token_of_row, axis=0, mode="fill", fill_value=0)
+
+
+def _to_expert_rows_fwd(x, token_of_row, row_of_assignment):
+    return _to_expert_rows(x, token_of_row, row_of_assignment), (row_of_assignment, x.shape[0])
+
+
+def _to_expert_rows_bwd(res, g):
+    row_of_assignment, s = res
+    with scope("moe_experts"):
+        mine = jnp.take(g, row_of_assignment, axis=0).reshape(s, -1, g.shape[-1])
+        return jnp.sum(mine.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+
+
+_to_expert_rows.defvjp(_to_expert_rows_fwd, _to_expert_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
+    """``y[s] = Σ_j weights[s, j] · rows[row of (s, j)]`` — float32 sum, result
+    in ``rows``' dtype. Gathers both ways: a row's cotangent is its token's,
+    times its weight (zero for padding rows)."""
+    del token_of_row, assignment_of_row
+    s, k = weights.shape
+    mine = jnp.take(rows, row_of_assignment, axis=0).reshape(s, k, rows.shape[-1])
+    return jnp.sum(weights[..., None] * mine.astype(jnp.float32), axis=1).astype(rows.dtype)
+
+
+def _from_expert_rows_fwd(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
+    out = _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row)
+    return out, (rows, weights, row_of_assignment, token_of_row, assignment_of_row)
+
+
+def _from_expert_rows_bwd(res, g):
+    rows, weights, row_of_assignment, token_of_row, assignment_of_row = res
+    s, k = weights.shape
+    with scope("moe_combine"):
+        mine = jnp.take(rows, row_of_assignment, axis=0).reshape(s, k, rows.shape[-1])
+        d_weights = jnp.sum(mine.astype(jnp.float32) * g.astype(jnp.float32)[:, None, :], axis=-1)
+        weight_of_row = jnp.take(weights.reshape(-1), assignment_of_row, mode="fill", fill_value=0)
+        g_rows = jnp.take(g, token_of_row, axis=0, mode="fill", fill_value=0)
+        d_rows = (weight_of_row[:, None] * g_rows.astype(jnp.float32)).astype(rows.dtype)
+    return d_rows, d_weights.astype(weights.dtype), None, None, None
+
+
+_from_expert_rows.defvjp(_from_expert_rows_fwd, _from_expert_rows_bwd)
+
+
+class ExpertFFN(nn.Module):
+    """Dropless sigmoid-routed expert feed-forward (DeepSeek-V3's, as
+    ``glm4_moe_lite`` runs it): every one of the ``S x k`` assignments is
+    computed, at any imbalance — no capacity, no ``[S, E, C]`` tensor::
+
+        s = router_scores(x);  experts = choose_experts(s, bias);  w = routing_weights(s, experts)
+        rows = sort x's k copies by expert               # tile-aligned grouped layout
+        h = gmm(rows, W13);  h = silu(h[:, :F]) * h[:, F:];  out = gmm(h, W2)
+        y = Σ_j w_j · out[row of (token, j)]  +  shared(x)
+
+    The bank is two stacked parameters in the dtype a checkpoint stores
+    (bfloat16), ``experts_w13`` ``[E, D, 2F]`` (gate | up) and ``experts_w2``
+    ``[E, F, D]``, read as they lie by ``ops/grouped_matmul.py``. Inside a layer
+    scan the layer does not own them: ``bank`` = ``(layer, w13, w2)`` hands it
+    the whole run's stacks ``[L, E, ...]`` (loop constants, declared by
+    :class:`CausalLM` outside every scan) and its index, and the kernel reads
+    ``w13[layer]`` in place — sliced by the scan, each layer's 1.2 GB would be
+    copied before every call. Bank, router and its bias carry no adapter: frozen
+    under LoRA and outside its FedAvg. The shared expert is :class:`MLP`
+    (adapters where ``lora_mlp``).
+
+    Sows ``moe_stats/load_max_over_mean`` — rows on the fullest expert over the
+    even share ``S k / E`` — from the group sizes the matmul takes anyway, and
+    ``moe_routing/chosen``, the ``[S, k]`` experts themselves.
+    The routing choice is kept across remat (``moe_chosen``): the re-forward
+    lays out and weighs the forward's own assignments."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, bank=None):
+        from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul
+
+        cfg = self.cfg
+        e, k, f = cfg.routed_experts, cfg.experts_per_token, cfg.expert_hidden
+        b, t, d = x.shape
+        s = b * t
+        xs = x.reshape(s, d)
+        router = self.param("router", nn.initializers.normal(0.02), (d, e))
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        if bank is None:
+            layer = None
+            w13 = self.param("experts_w13", _bank_init, (e, d, 2 * f))
+            w2 = self.param("experts_w2", _bank_init, (e, f, d))
+        else:
+            layer, w13, w2 = bank
+        gmm = partial(grouped_matmul, layer=layer, tile_m=cfg.expert_tile_m, impl=cfg.expert_impl)
+        with scope("moe_route"):
+            s_ = router_scores(xs, router)
+            # kept across remat (see _remat_policy): weights, rows and combine of the
+            # re-forward all follow the forward's choice
+            chosen = checkpoint_name(choose_experts(s_, bias, k), "moe_chosen")
+            weights = routing_weights(s_, chosen, cfg.routed_scale)
+            layout = group_layout(chosen.reshape(-1), e, cfg.expert_tile_m)
+            token_of_row = jnp.where(
+                layout.assignment_of_slot < s * k, layout.assignment_of_slot // k, s
+            )  # s: out of range, filled with zeros
+            load = jnp.max(layout.group_sizes).astype(jnp.float32) / (s * k / e)
+        self.sow("moe_stats", "load_max_over_mean", load)
+        self.sow("moe_routing", "chosen", chosen)  # for whoever compares assignments (tests, the benchmark's check)
+        with scope("moe_experts"):
+            rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, layout.slot_of_assignment)
+            h = gmm(rows, w13, layout.group_sizes)
+            h = nn.silu(h[:, :f]) * h[:, f:]
+            out = gmm(h, w2, layout.group_sizes)
+        shared = MLP(cfg, cfg.shared_experts * f, name="shared")(x) if cfg.shared_experts else None
+        with scope("moe_combine"):
+            y = _from_expert_rows(
+                out, weights, layout.slot_of_assignment, token_of_row, layout.assignment_of_slot
+            ).reshape(b, t, d)
+            return y if shared is None else y + shared
+
+
 def _dt_bias_init(key, shape, dtype=jnp.float32):
     """Mamba's own: the bias whose softplus is log-uniform in [1e-3, 1e-1].
     (A zero-mean bias gives steps near 0.7, every decay collapses within a few
@@ -445,9 +702,9 @@ class MambaMixer(nn.Module):
         with scope("ssm_conv"):
             u = nn.silu(causal_depthwise_conv(u, kernel, conv_bias)).astype(cfg.dtype)
         dbc = dense(dt_rank + 2 * n, name="x_proj")(u)
-        dt = RMSNorm(cfg.dtype, name="dt_norm")(dbc[..., :dt_rank])
-        b = RMSNorm(cfg.dtype, name="b_norm")(dbc[..., dt_rank:dt_rank + n])
-        c = RMSNorm(cfg.dtype, name="c_norm")(dbc[..., dt_rank + n:])
+        dt = RMSNorm(cfg.dtype, cfg.norm_eps, name="dt_norm")(dbc[..., :dt_rank])
+        b = RMSNorm(cfg.dtype, cfg.norm_eps, name="b_norm")(dbc[..., dt_rank:dt_rank + n])
+        c = RMSNorm(cfg.dtype, cfg.norm_eps, name="c_norm")(dbc[..., dt_rank + n:])
         dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
         delta = LoRADense(inner, rank=0, dtype=cfg.dtype, name="dt_proj")(dt)
         delta = checkpoint_name(jax.nn.softplus(delta.astype(jnp.float32) + dt_bias), "ssm_dt")
@@ -463,16 +720,20 @@ class Block(nn.Module):
     kind: str = "attention"  # the sequence mixer: one of LAYER_KINDS
 
     @nn.compact
-    def __call__(self, x):
-        if self.kind == "mamba":
-            x = x + MambaMixer(self.cfg, name="mamba")(RMSNorm(self.cfg.dtype, name="mamba_norm")(x))
+    def __call__(self, x, bank=None):
+        cfg = self.cfg
+        mixer, ffn_kind = LAYER_KINDS[self.kind]
+        norm = partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+        if mixer == "mamba":
+            x = x + MambaMixer(cfg, name="mamba")(norm(name="mamba_norm")(x))
         else:
-            x = x + Attention(self.cfg, self.attn_fn, name="attn")(
-                RMSNorm(self.cfg.dtype, name="attn_norm")(x)
-            )
-        ffn = MoEMLP if self.cfg.n_experts > 0 else MLP
-        x = x + ffn(self.cfg, name="mlp")(RMSNorm(self.cfg.dtype, name="mlp_norm")(x))
-        return x
+            attention = MLAttention if mixer == "mla" else Attention
+            x = x + attention(cfg, self.attn_fn, name="attn")(norm(name="attn_norm")(x))
+        h = norm(name="mlp_norm")(x)
+        if ffn_kind == "experts":
+            return x + ExpertFFN(cfg, name="mlp")(h, bank)
+        ffn = MLP if ffn_kind == "mlp" or cfg.n_experts == 0 else MoEMLP
+        return x + ffn(cfg, name="mlp")(h)
 
 
 class _ScanBlock(nn.Module):
@@ -485,8 +746,9 @@ class _ScanBlock(nn.Module):
     kind: str = "attention"
 
     @nn.compact
-    def __call__(self, x, _):
-        return Block(self.cfg, self.attn_fn, self.kind, name="block")(x), None
+    def __call__(self, x, layer, bank=None):
+        block = Block(self.cfg, self.attn_fn, self.kind, name="block")
+        return (block(x) if bank is None else block(x, (layer, *bank))), None
 
 
 def layer_runs(pattern: tuple) -> list[tuple[str, int]]:
@@ -501,41 +763,63 @@ def layer_runs(pattern: tuple) -> list[tuple[str, int]]:
     return runs
 
 
-def _rematted_in_scan(body, cfg: TransformerConfig):
-    """``body`` rematerialised where the config says so, for use INSIDE a scan.
-    prevent_cse=False: inside lax.scan the remat thunk can't be CSE'd across
-    iterations anyway, and True blocks the scan lowering (flax's documented
-    scan-over-remat recipe)."""
+def _rematted_in_scan(body, cfg: TransformerConfig, kind: str = "attention"):
+    """``body`` (a block of ``kind``) rematerialised where the config says so,
+    for use INSIDE a scan. prevent_cse=False: inside lax.scan the remat thunk
+    can't be CSE'd across iterations anyway, and True blocks the scan lowering
+    (flax's documented scan-over-remat recipe)."""
     if not cfg.remat:
         return body
-    return nn.remat(body, prevent_cse=False, policy=_remat_policy(cfg.remat_policy))
+    return nn.remat(body, prevent_cse=False, policy=_remat_policy(cfg.remat_policy, kind))
 
 
-def _scan_over(body, length: int):
-    """``nn.scan`` of ``body`` over ``length`` stacked copies of its params."""
-    return nn.scan(body, variable_axes={"params": 0}, split_rngs={"params": True}, length=length)
+def _scan_over(body, length: int, with_bank: bool = False):
+    """``nn.scan`` of ``body`` over ``length`` stacked copies of its params.
+    ``with_bank``: the body takes a third argument that is NOT scanned — the
+    expert banks, whole, a constant of the loop."""
+    # what ExpertFFN sows comes out stacked along the scan (only where a caller
+    # makes the collection mutable; otherwise nothing is sown)
+    return nn.scan(
+        body, variable_axes={"params": 0, "moe_stats": 0, "moe_routing": 0},
+        split_rngs={"params": True}, length=length, **({"in_axes": (0, nn.broadcast)} if with_bank else {}),
+    )
+
+
+def _is_expert_run(kind: str) -> bool:
+    return LAYER_KINDS[kind][1] == "experts"
 
 
 class _ScanPeriod(nn.Module):
-    """nn.scan body over PERIODS of unlike layers: inside, every maximal run
+    """One period of ``cfg.layer_pattern`` as the body of the period scan. A run
     of same-kind layers is a scan of its own (a run of one layer is the block
     itself), so the compiled program holds one body per run whatever the
     depth. Params: ``run<i>_<kind>/block/...`` with a leading run-length axis,
-    or ``run<i>_<kind>/...`` for a run of one."""
+    or ``run<i>_<kind>/...`` for a run of one.
+
+    ``period`` / ``banks`` are for runs of EXPERT layers (``None`` without one):
+    this period's index and ``{run name: (w13, w2)}``, every such run's banks
+    stacked over ALL its layers ``[periods * count, E, ...]`` — see
+    :class:`ExpertFFN`. Layer ``j`` of the run reads bank ``period * count + j``."""
 
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
 
     @nn.compact
-    def __call__(self, x, _):
+    def __call__(self, x, period, banks=None):
         cfg = self.cfg
         for i, (kind, count) in enumerate(layer_runs(cfg.layer_pattern)):
             name = f"run{i}_{kind}"
+            experts = _is_expert_run(kind)
             if count > 1:
-                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg), count)
-                x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, None)
+                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg, kind), count, with_bank=experts)
+                if experts:
+                    layers = period * count + jnp.arange(count, dtype=jnp.int32)
+                    x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, layers, banks[name])
+                else:
+                    x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, None)
             else:
-                x = _rematted_in_scan(Block, cfg)(cfg, self.attn_fn, kind, name=name)(x)
+                block = _rematted_in_scan(Block, cfg, kind)(cfg, self.attn_fn, kind, name=name)
+                x = block(x, (period, *banks[name])) if experts else block(x)
         return x, None
 
 
@@ -552,29 +836,41 @@ class CausalLM(nn.Module):
         x = emb[tokens].astype(cfg.dtype)
         pattern = cfg.layer_pattern
         if cfg.scan_layers:
-            if cfg.n_experts > 0:
+            if cfg.n_experts > 0 and any(LAYER_KINDS[kind][1] is None for kind in pattern):
+                # MoEMLP's auxiliary LOSSES; ExpertFFN (noaux_tc routing) sows none
                 raise NotImplementedError(
                     "scan_layers with MoE: sown aux losses don't thread through "
                     "the layer scan or the period scan — use unrolled layers for MoE"
                 )
+            periods = cfg.n_layers // len(pattern)
+            # the expert banks of every layer, declared OUTSIDE the scans: a scan
+            # would slice its stacked params, and a Mosaic call reads no slice in place
+            bank_shapes = {"w13": (cfg.dim, 2 * cfg.expert_hidden), "w2": (cfg.expert_hidden, cfg.dim)}
+            banks = {
+                f"run{i}_{kind}": tuple(
+                    self.param(f"experts_{w}_run{i}", _bank_init, (periods * count, cfg.routed_experts, *shape))
+                    for w, shape in bank_shapes.items()
+                )
+                for i, (kind, count) in enumerate(layer_runs(pattern)) if _is_expert_run(kind)
+            }
             if len(pattern) == 1:
                 # a period of one layer is the scan body itself
-                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg), cfg.n_layers)
-                x, _ = scan(cfg, self.attn_fn, pattern[0], name="layers")(x, None)
+                body, args = _rematted_in_scan(_ScanBlock, cfg, pattern[0]), (cfg, self.attn_fn, pattern[0])
+                bank = banks.get(f"run0_{pattern[0]}")
             else:
-                scan = _scan_over(_ScanPeriod, cfg.n_layers // len(pattern))
-                x, _ = scan(cfg, self.attn_fn, name="layers")(x, None)
+                body, args, bank = _ScanPeriod, (cfg, self.attn_fn), banks or None
+            scan = _scan_over(body, periods, with_bank=bank is not None)
+            xs = (None,) if bank is None else (jnp.arange(periods, dtype=jnp.int32), bank)
+            x, _ = scan(*args, name="layers")(x, *xs)
         else:
-            block_cls = (
-                nn.remat(Block, policy=_remat_policy(cfg.remat_policy))
-                if cfg.remat
-                else Block
-            )
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, self.attn_fn, pattern[i % len(pattern)], name=f"layer_{i}")(x)
-        x = RMSNorm(cfg.dtype, name="final_norm")(x)
-        logits = jnp.dot(x, emb.T.astype(cfg.dtype))  # tied embeddings
-        return logits.astype(jnp.float32)
+                kind = pattern[i % len(pattern)]
+                block_cls = nn.remat(Block, policy=_remat_policy(cfg.remat_policy, kind)) if cfg.remat else Block
+                x = block_cls(cfg, self.attn_fn, kind, name=f"layer_{i}")(x)
+        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
+        with scope("head"):
+            logits = jnp.dot(x, emb.T.astype(cfg.dtype))  # tied embeddings
+            return logits.astype(jnp.float32)
 
 
 def pick_attention(seq_len: int, backend: Optional[str] = None) -> str:

@@ -140,11 +140,13 @@ def _pallas_call(kernel, *, scope_name: str, name: str, **kw):
     return scoped
 
 
-def _compiler_params(*dims: str) -> pltpu.CompilerParams:
+def _compiler_params(*dims: str, vmem_limit_bytes: Optional[int] = None) -> pltpu.CompilerParams:
     """Pin grid ``dimension_semantics`` ('parallel' dims may be split across
     megacore; 'arbitrary' dims MUST run sequentially on one core). Ignored
     in interpret mode."""
-    return pltpu.CompilerParams(dimension_semantics=dims)
+    if vmem_limit_bytes is None:
+        return pltpu.CompilerParams(dimension_semantics=dims)
+    return pltpu.CompilerParams(dimension_semantics=dims, vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
@@ -442,6 +444,17 @@ def _bwd_use_fused(t: int, d: int, mode: str) -> bool:
     return t * d * 4 <= _FUSED_SCRATCH_LIMIT
 
 
+def _fused_vmem_limit(d: int) -> Optional[int]:
+    """Scoped-VMEM limit of the fused backward. Heads up to 128 wide fit the
+    compiler's 16 MiB default at every shipped shape and keep it (their
+    programs do not change). At D = 256 / T = 4096 the resident q, dO and dQ
+    blocks (2 MiB each, double-buffered) and the 4 MiB dQ scratch pass it by
+    0.8 MiB (host-only compile for a v5e), so wider heads ask for 32 MiB of
+    the chip's 128 — which keeps the 5-matmul kernel instead of the 7-matmul
+    split pair."""
+    return 32 * 1024 * 1024 if d > 128 else None
+
+
 def _dq_scratch(t: int, d: int):
     """The fused backward's persistent fp32 [T, D] dQ accumulator."""
     return [pltpu.VMEM((t, d), jnp.float32)]
@@ -472,7 +485,9 @@ def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interp
             # across its grid steps (and dq_ref flushes at the last) — this
             # encodes the requirement instead of relying on the default
             # semantics happening to serialize (advisor round-5)
-            compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+            compiler_params=_compiler_params(
+                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(d)
+            ),
             interpret=interpret,
             scope_name="flash_bwd",
             name="p2pfl_flash_bwd_fused",
@@ -949,7 +964,9 @@ def _fab_bwd(config, interpret, res, cts):
             ],
             scratch_shapes=_dq_scratch(t, d),
             # sequential k-block accumulation into dq_acc — see _dkvq_kernel
-            compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+            compiler_params=_compiler_params(
+                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(d)
+            ),
             interpret=interpret,
             scope_name="flash_bwd",
             name="p2pfl_flash_bwd_fused",

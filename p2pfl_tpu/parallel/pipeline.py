@@ -194,6 +194,6 @@ def pipelined_lm_apply(
 
     y, aux = pipeline_apply(stacked, xm, apply_layer, mesh, axis, with_aux=True)
     y = y.reshape(b, *x.shape[1:])
-    y = RMSNorm(cfg.dtype).apply({"params": params["final_norm"]}, y)
+    y = RMSNorm(cfg.dtype, cfg.norm_eps).apply({"params": params["final_norm"]}, y)
     logits = jnp.dot(y, emb.T.astype(cfg.dtype)).astype(jnp.float32)
     return (logits, aux) if return_aux else logits

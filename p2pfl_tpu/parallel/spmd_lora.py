@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import adam, ce_eval
 from p2pfl_tpu.learning.lora import lora_train_epoch as _node_lora_epoch  # noqa: F401 (shared math)
-from p2pfl_tpu.learning.lora import _lm_loss, merge_params, split_lora
+from p2pfl_tpu.learning.lora import _lm_forward, _lm_loss, merge_params, split_lora
 from p2pfl_tpu.management.profiling import dispatch_span, scope
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.parallel.spmd import SpmdFederation, _aggregate
@@ -119,22 +119,24 @@ def _lora_round_core(
                 bx, by = batch
 
                 def loss_of(lo__, bx_, by_):
-                    return _lm_loss(lo__, base, module, bx_, by_)
+                    loss, _, stats, _ = _lm_forward(lo__, base, module, bx_, by_)
+                    return loss, stats
 
                 with scope("grad"):
-                    (loss, _), grads = jax.value_and_grad(loss_of, has_aux=True)(
+                    (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(
                         lo_, bx, by
                     )
                 with scope("optimizer"):
                     updates, o_ = tx.update(grads, o_, lo_)
                     lo_ = optax.apply_updates(lo_, updates)
-                return (lo_, o_), loss
+                return (lo_, o_), (loss, stats)
 
-            (lo, o), losses = jax.lax.scan(step, (lo, o), (xs, ys))
-            return (lo, o), jnp.mean(losses)
+            (lo, o), (losses, stats) = jax.lax.scan(step, (lo, o), (xs, ys))
+            return (lo, o), (jnp.mean(losses), jax.tree.map(jnp.mean, stats))
 
-        (lora, opt_state), losses = jax.lax.scan(epoch_body, (lora, opt_state), idx)
-        return lora, opt_state if keep_opt_state else None, jnp.mean(losses)
+        (lora, opt_state), (losses, stats) = jax.lax.scan(epoch_body, (lora, opt_state), idx)
+        stats = jax.tree.map(jnp.mean, stats)  # {} unless the model sows statistics
+        return lora, opt_state if keep_opt_state else None, jnp.mean(losses), stats
 
     def train(lora, opt, x, y, idx, base):
         """Every node on the leading axis — all N, or one device's share of
@@ -157,7 +159,7 @@ def _lora_round_core(
         _, out = jax.lax.scan(chunk_body, None, chunked((lora, opt, x, y, idx)))
         return jax.tree.map(lambda a: a.reshape(here, *a.shape[2:]), out)
 
-    trained, trained_opt, losses = _on_own_nodes(train, out_sharding, 5)(
+    trained, trained_opt, losses, stats = _on_own_nodes(train, out_sharding, 5)(
         stacked_lora, opt_states, x_all, y_all, perm, base
     )
 
@@ -175,7 +177,9 @@ def _lora_round_core(
             trained_opt = jax.tree.map(
                 lambda a: jax.lax.with_sharding_constraint(a, out_sharding), trained_opt
             )
-        return out, trained_opt, jnp.mean(losses, where=mask.astype(bool))
+        trained_nodes = mask.astype(bool)
+        stats = {name: jnp.mean(per_node, where=trained_nodes) for name, per_node in stats.items()}
+        return out, trained_opt, jnp.mean(losses, where=trained_nodes), stats
 
 
 _LORA_STATICS = (
@@ -213,7 +217,7 @@ def spmd_lora_rounds_fused(
 
     def body(carry, perm):
         p, o = carry
-        out_p, out_o, loss = _lora_round_core(
+        out_p, out_o, loss, _ = _lora_round_core(
             p, o, base, x_all, y_all, perm, mask, weights, sel_idx, **kw
         )
         return (out_p, out_o), loss
@@ -301,9 +305,12 @@ class SpmdLoraFederation(SpmdFederation):
             self.train_mask = self.elect_train_set()
         args, statics = self._round_call(epochs)
         with dispatch_span("spmd_lora_round", "spmd", nodes=self.n, epochs=epochs):
-            self.params, self.opt_state, loss = spmd_lora_round(*args, **statics)
+            self.params, self.opt_state, loss, stats = spmd_lora_round(*args, **statics)
         self.round += 1
-        entry = {"round": self.round, "train_loss": loss}
+        # ``stats``: device scalars the model sowed, averaged over the round's
+        # steps and trained nodes (an expert model's ``moe_load_max_over_mean``);
+        # ``{}`` otherwise. Nothing here fetches them.
+        entry = {"round": self.round, "train_loss": loss, **stats}
         self.history.append(entry)
         return entry
 

@@ -34,7 +34,7 @@ def test_compiler_params_carry_dimension_semantics():
     assert tuple(params.dimension_semantics) == ("parallel", "parallel", "arbitrary")
 
 
-@pytest.mark.parametrize("t,d", [(1024, 64), (4096, 128), (8192, 128)])
+@pytest.mark.parametrize("t,d", [(1024, 64), (4096, 128), (8192, 128), (4096, 256)])
 def test_flash_fwd_bwd_lowers_for_mosaic(t, d):
     """Host-only Pallas→Mosaic lowering of forward + backward under the v5e
     defaults: catches Python-side lowering breaks before chip time is spent.
@@ -54,6 +54,35 @@ def test_flash_fwd_bwd_lowers_for_mosaic(t, d):
     for body in bodies:
         raw = base64.b64decode(body)
         assert b"dimension_semantics" in raw and b"arbitrary" in raw
+
+
+def test_wide_heads_ask_for_more_scoped_vmem_in_the_fused_backward_only():
+    """D = 256 / T = 4096: the fused backward's resident blocks pass the 16 MiB
+    default by 0.8 MiB (host-only compile, PR 31); heads up to 128 keep the
+    default, so their kernels are the ones they were."""
+    from p2pfl_tpu.ops.flash_attention import _fused_vmem_limit
+
+    assert _fused_vmem_limit(64) is None and _fused_vmem_limit(128) is None
+    assert _fused_vmem_limit(256) == 32 * 1024 * 1024
+    assert autotune.flash_config_source(4096, 256, kind="TPU v5 lite")[1] == "defaults"
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True])
+def test_grouped_matmul_lowers_for_mosaic(transpose_rhs):
+    """Host-only Pallas→Mosaic lowering of ``p2pfl_gmm`` at the GLM expert
+    widths over a stack of four layers' banks, both faces (the product and the
+    input cotangent's); the layer is a traced scalar."""
+    from p2pfl_tpu.ops import grouped_matmul as gm
+
+    e, d, f, tile = 64, 2048, 1536, 128
+    rows = tile * gm.n_row_tiles(4096 * 4, e, tile)
+    lhs = jax.ShapeDtypeStruct((rows, 2 * f if transpose_rhs else d), jnp.bfloat16)
+    rhs = jax.ShapeDtypeStruct((4, e, d, 2 * f), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((e,), jnp.int32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    fn = jax.jit(lambda a, b, l, c: gm._gmm_pallas(a, b, l, c, tile, transpose_rhs, interpret=False))
+    text = fn.trace(lhs, rhs, layer, sizes).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1 and 'kernel_name = "p2pfl_gmm"' in text
 
 
 def test_lora_round_with_compiled_flash_lowers_on_a_four_device_mesh():

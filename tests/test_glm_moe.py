@@ -1,0 +1,553 @@
+"""Latent attention, the dropless sigmoid-routed expert layer and its grouped
+matmul, and "one dense layer, then a scanned run of expert layers" through
+``CausalLM`` and ``SpmdLoraFederation`` — against the plain reference
+``benchmark/reference/glm_moe_lm.py`` on seeded weights."""
+
+import copy
+import dataclasses
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import checks as ck
+from benchmark.reference import fedavg, glm_moe_lm
+from p2pfl_tpu.learning.dataset import FederatedDataset
+from p2pfl_tpu.learning.lora import _lm_forward, _lm_loss, merge_params, split_lora
+from p2pfl_tpu.models.transformer import (
+    CausalLM, ExpertFFN, MLAttention, TransformerConfig, choose_experts, layer_runs, router_scores, routing_weights,
+    tiny_transformer,
+)
+from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles
+from p2pfl_tpu.parallel import SpmdLoraFederation
+from p2pfl_tpu.parallel.spmd import draw_node_perms
+
+ROOT = Path(__file__).resolve().parent.parent
+SEQ = 32
+PATTERN = ("mla_dense", "mla_experts", "mla_experts", "mla_experts")
+# the reference reads Hugging Face's keys; unequal low-rank widths, a shared rotary head
+REF = {
+    "hidden_size": 64, "num_hidden_layers": 4, "num_attention_heads": 4, "intermediate_size": 160,
+    "rope_theta": 1e6, "rms_norm_eps": 1e-5, "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 12,
+    "qk_rope_head_dim": 4, "v_head_dim": 16, "n_routed_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "n_shared_experts": 1, "routed_scaling_factor": 1.8, "first_k_dense_replace": 1,
+    "vocab_size": 256,
+}
+
+
+def config(**kw):
+    base = dict(
+        vocab_size=256, dim=64, n_layers=4, n_heads=4, n_kv_heads=4, ffn_hidden=160, rope_theta=1e6,
+        layer_pattern=PATTERN, lora_rank=4, lora_alpha=8.0, lora_mlp=True, dtype=jnp.float32, remat=True,
+        scan_layers=True, remat_policy=None, norm_eps=1e-5, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_dim=12, qk_rope_dim=4, v_head_dim=16, routed_experts=8, experts_per_token=2, expert_hidden=32,
+        shared_experts=1, routed_scale=1.8, expert_tile_m=8,
+    )
+    base.update(kw)
+    return TransformerConfig(**base)
+
+
+def seeded(cfg, seed=0):
+    """Seeded weights with ``lora_b`` perturbed (at its zero start every
+    ``lora_a`` gradient is exactly zero) and a router bias that changes choices."""
+    model = tiny_transformer(seq_len=SEQ, seed=seed, cfg=cfg)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 128))
+
+    def draw(path, a):
+        name = jax.tree_util.keystr(path)
+        if "lora_b" in name:
+            return 0.05 * jax.random.normal(next(keys), a.shape, a.dtype)
+        if "router_bias" in name:
+            return 0.02 * jax.random.normal(next(keys), a.shape, a.dtype)
+        return a
+
+    model.params = jax.tree_util.tree_map_with_path(draw, model.params)
+    lora, base = split_lora(model.params)
+    return model, lora, base
+
+
+def batch(seed=0, n=2):
+    x = jax.random.randint(jax.random.PRNGKey(seed), (n, SEQ + 1), 0, 256)
+    return x[:, :-1], x[:, 1:]
+
+
+@pytest.fixture(scope="module")
+def glm():
+    return seeded(config())
+
+
+# ---- the grouped matmul -------------------------------------------------------
+
+
+def _groups(case: str, m: int, g: int):
+    if case == "even":
+        return jnp.arange(m, dtype=jnp.int32) % g
+    if case == "one_group":
+        return jnp.full((m,), 2, jnp.int32)
+    if case == "empty_groups":
+        return 2 * (jnp.arange(m, dtype=jnp.int32) % (g // 2))  # odd groups get no row
+    return jax.random.randint(jax.random.PRNGKey(3), (m,), 0, g)  # "ragged"
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("case", ["even", "one_group", "empty_groups", "ragged"])
+def test_grouped_matmul_matches_einsum_forward_and_input_cotangent(case, impl):
+    """37 rows in tiles of 8 (no multiple), every routing shape: the product and
+    its input cotangent against a per-row einsum; the bank gets no cotangent."""
+    g, k, n, m, tile = 6, 32, 48, 37, 8
+    rhs = jax.random.normal(jax.random.PRNGKey(0), (g, k, n), jnp.float32).astype(jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (m, k), jnp.float32)
+    group_of = _groups(case, m, g)
+    layout = group_layout(group_of, g, tile)
+    assert layout.rows == tile * n_row_tiles(m, g, tile) and int(layout.group_sizes.sum()) == m
+    rows = jnp.take(x, layout.assignment_of_slot, axis=0, mode="fill", fill_value=0)
+    dense = rhs.astype(jnp.float32)[group_of]
+
+    def ours(rows_, rhs_):
+        return grouped_matmul(rows_, rhs_, layout.group_sizes, tile_m=tile, impl=impl)
+
+    out = ours(rows, rhs)
+    np.testing.assert_allclose(out[layout.slot_of_assignment], jnp.einsum("mk,mkn->mn", x, dense), rtol=1e-5, atol=1e-5)
+    padding = np.setdiff1d(np.arange(layout.rows), np.asarray(layout.slot_of_assignment))
+    assert not np.asarray(out)[padding].any()  # padding rows and unused tiles are zeros, not garbage
+    probe = jax.random.normal(jax.random.PRNGKey(2), out.shape, jnp.float32)
+    d_rows, d_rhs = jax.grad(lambda r, w: jnp.sum(ours(r, w) * probe), argnums=(0, 1))(rows, rhs)
+    want = jnp.einsum("mn,mkn->mk", probe[layout.slot_of_assignment], dense)
+    np.testing.assert_allclose(d_rows[layout.slot_of_assignment], want, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(d_rhs.astype(jnp.float32)).any()  # frozen: no weight gradient exists
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_grouped_matmul_reads_the_named_layer_of_a_stack_of_banks(impl):
+    """``[L, G, K, N]`` with a traced ``layer``: the product and the input
+    cotangent are that layer's; one bank without ``layer`` is a stack of one."""
+    g, k, n, m, tile = 4, 16, 24, 20, 8
+    stack = jax.random.normal(jax.random.PRNGKey(0), (3, g, k, n), jnp.float32).astype(jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (m, k), jnp.float32)
+    layout = group_layout(jax.random.randint(jax.random.PRNGKey(2), (m,), 0, g), g, tile)
+    rows = jnp.take(x, layout.assignment_of_slot, axis=0, mode="fill", fill_value=0)
+
+    @jax.jit
+    def stacked(rows_, layer):
+        fn = lambda r: grouped_matmul(r, stack, layout.group_sizes, layer=layer, tile_m=tile, impl=impl)  # noqa: E731
+        out, pull = jax.vjp(fn, rows_)
+        return out, pull(jnp.ones_like(out))[0]
+
+    for layer in range(3):
+        fn = lambda r: grouped_matmul(r, stack[layer], layout.group_sizes, tile_m=tile, impl=impl)  # noqa: E731
+        want, pull = jax.vjp(fn, rows)
+        got, d_rows = stacked(rows, jnp.int32(layer))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(d_rows, pull(jnp.ones_like(want))[0], rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="stack of banks"):
+        grouped_matmul(rows, stack, layout.group_sizes, tile_m=tile, impl=impl)
+
+
+def test_grouped_matmul_under_vmap_keeps_each_elements_groups():
+    g, k, n, m, tile = 4, 16, 24, 20, 8
+    rhs = jax.random.normal(jax.random.PRNGKey(0), (g, k, n), jnp.float32).astype(jnp.bfloat16)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (3, m, k), jnp.float32)
+    groups = jax.random.randint(jax.random.PRNGKey(2), (3, m), 0, g)
+
+    def one(x, group_of, impl):
+        layout = group_layout(group_of, g, tile)
+        rows = jnp.take(x, layout.assignment_of_slot, axis=0, mode="fill", fill_value=0)
+        return grouped_matmul(rows, rhs, layout.group_sizes, tile_m=tile, impl=impl)[layout.slot_of_assignment]
+
+    want = jnp.stack([one(x, gr, "xla") for x, gr in zip(xs, groups)])
+    for impl in ("xla", "pallas"):
+        np.testing.assert_allclose(jax.vmap(lambda x, gr: one(x, gr, impl))(xs, groups), want, rtol=1e-5, atol=1e-5)
+
+
+# ---- the router ----------------------------------------------------------------
+
+
+def test_router_chooses_with_the_bias_and_weighs_without_it():
+    """Hand-written numpy: sigmoid scores, top-k of score + bias, weights from
+    the scores alone, normalised over the chosen, times the scale — on a case
+    where the bias changes the choice."""
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(16, 12)).astype(np.float32)
+    router = rng.normal(scale=0.3, size=(12, 6)).astype(np.float32)
+    bias = np.array([0.0, 0.4, 0.0, -0.4, 0.0, 0.0], np.float32)
+    scores = router_scores(jnp.asarray(x), jnp.asarray(router))
+    chosen = choose_experts(scores, jnp.asarray(bias), 2)
+    weights = routing_weights(scores, chosen, 1.8)
+    s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ router.astype(np.float64))))
+    want_chosen = np.argsort(-(s + bias), axis=-1)[:, :2]
+    unbiased = np.argsort(-s, axis=-1)[:, :2]
+    assert (np.sort(want_chosen, -1) != np.sort(unbiased, -1)).any()  # the bias matters here
+    np.testing.assert_array_equal(np.sort(np.asarray(chosen), -1), np.sort(want_chosen, -1))
+    picked = np.take_along_axis(s, np.asarray(chosen), axis=-1)
+    np.testing.assert_allclose(weights, 1.8 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.8, rtol=1e-5)
+
+
+# ---- the expert layer ----------------------------------------------------------
+
+
+def _expert_layer(cfg, seed=0):
+    layer = ExpertFFN(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(seed), (1, 37, cfg.dim), jnp.float32)  # 37 x 2 rows: no multiple of the tile
+    params = layer.init(jax.random.PRNGKey(seed + 1), h)["params"]
+    return layer, params, h
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("routing", ["seeded", "all_on_one_expert", "experts_without_rows"])
+def test_expert_layer_drops_no_assignment(routing, impl):
+    """Against "every expert on every row, masked" at any imbalance: the routed
+    output, the sown load, and that the S x k assignments are all in the groups."""
+    cfg = config(expert_impl=impl)
+    layer, params, h = _expert_layer(cfg)
+    bias = {
+        "seeded": 0.05 * jax.random.normal(jax.random.PRNGKey(9), (8,)),
+        "all_on_one_expert": jnp.array([9.0, 5.0, 0, 0, 0, 0, 0, 0]),  # everyone chooses experts 0 and 1
+        "experts_without_rows": jnp.array([0, -9.0, 0, -9.0, 0, -9.0, 0, -9.0]),
+    }[routing]
+    params = dict(params, router_bias=bias)
+    got, mut = layer.apply({"params": params}, h, mutable=["moe_stats", "moe_routing"])
+    ref = dict(REF)
+    with jax.default_matmul_precision("highest"):
+        want, want_chosen = glm_moe_lm.experts(h[0], params, ref, 2.0)
+    np.testing.assert_allclose(got[0], want, rtol=2e-4, atol=2e-5)
+    chosen = np.asarray(mut["moe_routing"]["chosen"][0])
+    np.testing.assert_array_equal(np.sort(chosen, -1), np.sort(np.asarray(want_chosen), -1))
+    sizes = np.bincount(chosen.ravel(), minlength=8)
+    assert sizes.sum() == 37 * 2  # dropless: every assignment has a row
+    assert float(mut["moe_stats"]["load_max_over_mean"][0]) == pytest.approx(sizes.max() / (37 * 2 / 8))
+    if routing == "all_on_one_expert":
+        assert sizes[0] == sizes[1] == 37 and not sizes[2:].any()
+    if routing == "experts_without_rows":
+        assert not sizes[1::2].any()
+
+
+def test_expert_layer_gradients_reach_the_input_through_experts_and_router():
+    cfg = config()
+    layer, params, h = _expert_layer(cfg)
+    params = dict(params, router_bias=0.05 * jax.random.normal(jax.random.PRNGKey(9), (8,)))
+    probe = jax.random.normal(jax.random.PRNGKey(5), h.shape)
+    got = jax.grad(lambda h_: jnp.sum(layer.apply({"params": params}, h_) * probe))(h)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda h_: jnp.sum(glm_moe_lm.experts(h_, params, REF, 2.0)[0] * probe[0]))(h[0])
+    assert ck.rel_l2(got[0], want) < 1e-4
+
+
+def test_the_re_forward_uses_the_forwards_assignments(glm):
+    """The choice is kept across remat (``moe_chosen``), so the backward's
+    re-forward CANNOT choose anew: no second ``top_k`` in the differentiated
+    program — one in the scanned run's body, the forward's. The sort is
+    deterministic: the same choice lays out the same rows. And the gradient
+    equals the un-rematted one."""
+    model, lora, base = glm
+    x, y = batch()
+
+    def top_ks(module):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda lo: _lm_loss(lo, base, module, x, y)[0]))(lora)
+        return str(jaxpr).count("top_k[")
+
+    cfg = model.module.cfg
+    plain = CausalLM(dataclasses.replace(cfg, remat=False))
+    assert top_ks(plain) == top_ks(model.module) == 1  # ONE body for the run's three layers, and in it the forward's only
+    routing = jax.tree.leaves(_lm_forward(lora, base, model.module, x, y)[3])
+    chosen = routing[0].reshape(-1)
+    one, two = group_layout(chosen, 8, 8), group_layout(chosen, 8, 8)
+    np.testing.assert_array_equal(one.slot_of_assignment, two.slot_of_assignment)
+    g_plain = jax.grad(lambda lo: _lm_loss(lo, base, plain, x, y)[0])(lora)
+    g_remat = jax.grad(lambda lo: _lm_loss(lo, base, model.module, x, y)[0])(lora)
+    assert ck.rel_l2(g_remat, g_plain) < 1e-6
+
+
+@pytest.mark.parametrize("fault", ["none", "bf16_router", "dropped_assignment", "weigh_with_bias", "choose_without_bias"])
+def test_the_benchmarks_layer_check_sees_each_planted_fault(fault):
+    """``engines/spmd_lora_moe.check_expert_layer`` under its own limits: the
+    sound layer passes every comparison, and a bfloat16 router, ONE dropped
+    assignment of 8,192, weights taken with the bias, and a choice made without
+    it each fail at least one. The input has a component every token shares, as
+    a residual stream has, and the bias cancels each expert's mean score."""
+    from types import SimpleNamespace
+
+    from benchmark.engines import spmd_lora_moe as engine
+    from benchmark.planted_faults import planted
+
+    cfg = config(expert_impl="xla")
+    tokens = 4096
+    g = jax.random.normal(jax.random.PRNGKey(0), (1, tokens, cfg.dim)) + 3.0 * jax.random.normal(jax.random.PRNGKey(1), (cfg.dim,))
+    h = g / jnp.sqrt(jnp.mean(g * g, axis=-1, keepdims=True))
+    params = ExpertFFN(cfg).init(jax.random.PRNGKey(2), h[:, :8])["params"]
+    mean = jnp.mean(router_scores(h[0], params["router"]), axis=0)
+    assert float(jnp.std(mean)) > 0.02  # the shared component skews the seeded router, as in the cell
+    mlp = {
+        "router": params["router"], "router_bias": jnp.mean(mean) - mean, "bank_layer": 0,
+        "experts_w13": params["experts_w13"][None], "experts_w2": params["experts_w2"][None],
+    }
+    job = SimpleNamespace(cfg=REF, checks=ck.Checks())
+    with planted(fault):
+        engine.check_expert_layer(job, cfg, mlp, h)
+    failed = [row["check"] for row in job.checks.rows if not row["ok"]]
+    assert len(job.checks.rows) == 3
+    if fault == "none":
+        assert not failed, job.checks.rows
+    else:
+        assert failed, job.checks.rows
+    if fault in ("bf16_router", "choose_without_bias"):
+        assert "layer.routing_agreement" in failed
+    if fault in ("dropped_assignment", "weigh_with_bias"):
+        assert "layer.worst_agreeing_token_rel" in failed
+
+
+# ---- latent attention ----------------------------------------------------------
+
+
+def test_latent_attention_forward_and_adapter_gradients_match_the_reference():
+    cfg = config()
+    layer = MLAttention(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(1), h)["params"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(2), 16))
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, a: 0.05 * jax.random.normal(next(keys), a.shape) if "lora_b" in jax.tree_util.keystr(p) else a, params
+    )
+    assert params["q_a"]["kernel"].shape == (64, 24) and params["kv_a"]["kernel"].shape == (64, 16 + 4)  # unequal ranks, one rotary head
+    lora, base = split_lora(params)
+    probe = jax.random.normal(jax.random.PRNGKey(3), h.shape)
+    ours = lambda lo: jnp.sum(layer.apply({"params": merge_params(base, lo)}, h) * probe)  # noqa: E731
+    theirs = lambda lo: jnp.sum(glm_moe_lm.mla(h[0], merge_params(base, lo), REF, 2.0) * probe[0])  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = jax.value_and_grad(theirs)(lora)
+    got, grads = jax.value_and_grad(ours)(lora)
+    assert float(got) == pytest.approx(float(want), rel=1e-4)
+    assert sorted(lora) == ["kv_a", "kv_b", "o", "q_a", "q_b"]  # LoRA on all five projections
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(w))) > 0 and ck.rel_l2(g, w) < 1e-4, jax.tree_util.keystr(path)
+
+
+# ---- the whole model -----------------------------------------------------------
+
+
+def test_layer_runs_of_one_dense_then_expert_layers():
+    assert layer_runs(PATTERN) == [("mla_dense", 1), ("mla_experts", 3)]
+    assert glm_moe_lm.layer_kinds(REF) == list(PATTERN) and glm_moe_lm.runs(REF) == layer_runs(PATTERN)
+
+
+def test_loss_and_every_adapter_gradient_match_the_reference(glm):
+    model, lora, base = glm
+    x, y = batch()
+    (loss, _), grads = jax.value_and_grad(_lm_loss, has_aux=True)(lora, base, model.module, x, y)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want = jax.value_and_grad(glm_moe_lm.loss)(lora, base, x, y, REF, lora_scale=2.0)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    assert jax.tree.structure(grads) == jax.tree.structure(want)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(ref))) > 0, jax.tree_util.keystr(path)  # every adapter is reached
+        assert ck.rel_l2(got, ref) < 2e-4, jax.tree_util.keystr(path)
+
+
+def test_reference_held_to_given_assignments_reports_its_own_and_uses_the_given(glm):
+    """The comparison holds the reference to the program's assignments: given
+    its OWN choice nothing changes; given another, the loss does, and what it
+    reports stays its own choice."""
+    _, lora, base = glm
+    x, y = batch()
+    free, own = glm_moe_lm.loss_and_routing(lora, base, x, y, REF, lora_scale=2.0)
+    assert own.shape == (2, 3, SEQ, 2)
+    same, _ = glm_moe_lm.loss_and_routing(lora, base, x, y, REF, lora_scale=2.0, forced=own)
+    assert float(same) == pytest.approx(float(free), rel=1e-6)
+    other, reported = glm_moe_lm.loss_and_routing(lora, base, x, y, REF, lora_scale=2.0, forced=(own + 1) % 8)
+    assert abs(float(other) - float(free)) > 1e-6
+    np.testing.assert_array_equal(reported[:, 0], own[:, 0])  # the first expert layer sees the same input either way
+
+
+def test_scanned_layers_equal_the_unrolled_layers_on_restacked_parameters(glm):
+    """Under ``scan_layers`` the dense layer and the run of expert layers are two
+    runs of one period, the expert run ONE scan body over stacked parameters —
+    its banks stacked too, beside ``layers``, outside every scan (see ExpertFFN)."""
+    model, lora, base = glm
+    params = merge_params(base, lora)
+    x, _ = batch()
+    jaxpr = str(jax.make_jaxpr(lambda p: model.module.apply({"params": p}, x))(params))
+    assert jaxpr.count("top_k[") == 1  # one expert body, whatever the depth
+    assert params["experts_w13_run1"].shape == (3, 8, 64, 64) and params["experts_w2_run1"].shape == (3, 8, 32, 64)
+    unrolled = CausalLM(dataclasses.replace(model.module.cfg, scan_layers=False))
+    layers = params["layers"]
+    restacked = {"embed": params["embed"], "final_norm": params["final_norm"]}
+    restacked["layer_0"] = jax.tree.map(lambda a: a[0], layers["run0_mla_dense"])
+    for i in range(3):
+        layer = jax.tree.map(lambda a: a[0, i], layers["run1_mla_experts"]["block"])
+        bank = {w: params[f"{w}_run1"][i] for w in ("experts_w13", "experts_w2")}
+        restacked[f"layer_{i + 1}"] = dict(layer, mlp=dict(layer["mlp"], **bank))
+    np.testing.assert_allclose(
+        model.module.apply({"params": params}, x), unrolled.apply({"params": restacked}, x), rtol=1e-5, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "pattern,n_layers",
+    [(("mla_experts",), 2), (("mla_dense", "mla_experts"), 4), (("mla_dense", "mla_experts", "mla_experts"), 6)],
+    ids=["experts_alone", "two_periods_runs_of_one", "two_periods_runs_of_two"],
+)
+def test_every_scanned_expert_layer_reads_its_own_bank(pattern, n_layers):
+    """The banks lie outside the scans, stacked over ALL of a run's layers
+    (``periods x count``); layer ``j`` of period ``p`` must read bank
+    ``p * count + j`` — through the period scan, the run scan, a run of one, and
+    a stack of expert layers alone. Against the unrolled layers."""
+    cfg = config(layer_pattern=pattern, n_layers=n_layers)
+    model, lora, base = seeded(cfg)
+    params = merge_params(base, lora)
+    x, _ = batch()
+    periods = n_layers // len(pattern)
+    restacked = {"embed": params["embed"], "final_norm": params["final_norm"]}
+    at = 0
+    for period in range(periods):
+        for i, (kind, count) in enumerate(layer_runs(pattern)):
+            for j in range(count):
+                if len(pattern) == 1:
+                    layer = jax.tree.map(lambda a: a[period], params["layers"]["block"])
+                else:
+                    run = jax.tree.map(lambda a: a[period], params["layers"][f"run{i}_{kind}"])
+                    layer = jax.tree.map(lambda a: a[j], run["block"]) if count > 1 else run
+                if kind == "mla_experts":
+                    assert params[f"experts_w13_run{i}"].shape[0] == periods * count
+                    bank = {w: params[f"{w}_run{i}"][period * count + j] for w in ("experts_w13", "experts_w2")}
+                    layer = dict(layer, mlp=dict(layer["mlp"], **bank))
+                restacked[f"layer_{at}"] = layer
+                at += 1
+    unrolled = CausalLM(dataclasses.replace(cfg, scan_layers=False))
+    np.testing.assert_allclose(
+        model.module.apply({"params": params}, x), unrolled.apply({"params": restacked}, x), rtol=1e-5, atol=1e-5
+    )
+
+
+def test_the_statistic_leaves_the_layer_scan_and_the_loss(glm):
+    model, lora, base = glm
+    x, y = batch()
+    loss, _, stats, routing = _lm_forward(lora, base, model.module, x, y)
+    (chosen,) = jax.tree.leaves(routing)
+    assert chosen.shape == (1, 3, 2 * SEQ, 2)  # the experts each row chose, stacked along the period and the run
+    _, mut = model.module.apply({"params": merge_params(base, lora)}, x, mutable=["moe_stats"])
+    (per_layer,) = jax.tree.leaves(mut)
+    assert per_layer.shape == (1, 3)  # one period, three expert layers
+    assert set(stats) == {"moe_load_max_over_mean"}
+    assert float(stats["moe_load_max_over_mean"]) == pytest.approx(float(per_layer.mean()))
+    assert float(loss) == pytest.approx(float(_lm_loss(lora, base, model.module, x, y)[0]))
+    dense = tiny_transformer(seq_len=SEQ, cfg=TransformerConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, ffn_hidden=128))
+    d_lora, d_base = split_lora(dense.params)
+    assert _lm_forward(d_lora, d_base, dense.module, x, y)[2:] == ({}, {})  # a model that sows none
+
+
+def test_bfloat16_compute_meets_the_benchmarks_tolerances():
+    """The cell's comparison at toy size: bfloat16 matmuls and bank, float32
+    router, against the float32 reference, under ``checks.py``'s constants."""
+    model, lora, base = seeded(config(dtype=jnp.bfloat16))
+    x, y = batch()
+    (loss, _), grads = jax.value_and_grad(_lm_loss, has_aux=True)(lora, base, model.module, x, y)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want = jax.value_and_grad(glm_moe_lm.loss)(lora, base, x, y, REF, lora_scale=2.0)
+    assert abs(float(loss) - float(want_loss)) <= ck.LOSS_REL * float(want_loss)
+    # toy widths: 64 rows choose among 8 experts, so ONE near-tie that bfloat16
+    # activations flip moves the gradient by what 250 flips move at the cell's size
+    assert ck.cosine(grads, want) >= 0.95 and ck.rel_l2(grads, want) <= 0.35
+
+
+def test_norm_eps_comes_from_the_configuration():
+    x, _ = batch()
+    small, large = config(norm_eps=1e-5), config(norm_eps=1e-1)
+    model = tiny_transformer(seq_len=SEQ, cfg=small)
+    a = CausalLM(small).apply({"params": model.params}, x)
+    b = CausalLM(large).apply({"params": model.params}, x)
+    assert float(jnp.max(jnp.abs(a - b))) > 1e-3
+    assert TransformerConfig().norm_eps == 1e-6  # every other model's programs keep their constant
+
+
+# ---- LoRA and the federation ---------------------------------------------------
+
+
+def test_split_lora_leaves_the_bank_and_the_router_in_the_base_with_their_dtypes(glm):
+    model, _, _ = glm
+    lora, base = split_lora(model.params)
+    names = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(lora)]
+    assert names and all("lora_" in n for n in names)
+    assert not any(word in n for n in names for word in ("experts_w", "router"))  # the FedAvg payload holds none of them
+    mlp = base["layers"]["run1_mla_experts"]["block"]["mlp"]
+    assert base["experts_w13_run1"].dtype == base["experts_w2_run1"].dtype == jnp.bfloat16
+    assert mlp["router"].dtype == mlp["router_bias"].dtype == jnp.float32
+    merged = merge_params(base, lora)
+    assert jax.tree.structure(merged) == jax.tree.structure(model.params)
+    assert all(a.dtype == b.dtype for a, b in zip(jax.tree.leaves(merged), jax.tree.leaves(model.params)))
+    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=SEQ, n_train=16, n_test=4)
+    fed = SpmdLoraFederation.from_dataset(model, data, n_nodes=2, batch_size=2, vote=False, node_chunk=1)
+    staged = fed.base
+    assert staged["experts_w13_run1"].dtype == staged["experts_w2_run1"].dtype == jnp.bfloat16  # through _stage_state
+    assert staged["experts_w13_run1"].shape == (3, 8, 64, 64)  # stored once, the run's layers stacked: no node axis
+    assert all(leaf.dtype == jnp.float32 and leaf.shape[0] == 2 for leaf in jax.tree.leaves(fed.params))
+
+
+def test_one_federated_round_matches_reference_trained_nodes(glm):
+    model, _, _ = glm
+    lora, base = split_lora(model.params)
+    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=SEQ, n_train=16, n_test=4)
+    fed = SpmdLoraFederation.from_dataset(model, data, n_nodes=2, batch_size=2, vote=False, seed=0, node_chunk=1)
+    x_all, y_all = np.asarray(fed.x_all), np.asarray(fed.y_all)
+    start = jax.tree.map(np.asarray, lora)
+    perm = draw_node_perms(copy.deepcopy(fed._rng), fed._sizes, fed._nb, fed.batch_size, 1)
+    entry = fed.run_round(epochs=1)
+    got = jax.tree.map(lambda a: np.asarray(a[0]), fed.params)
+    assert all(np.array_equal(np.asarray(leaf[0]), np.asarray(leaf[1])) for leaf in jax.tree.leaves(fed.params))
+    assert 1.0 <= float(entry["moe_load_max_over_mean"]) <= 8 / 2  # the round's entry carries the counter
+
+    grad = jax.jit(lambda lo, b, x, y: jax.value_and_grad(glm_moe_lm.loss)(lo, b, x, y, REF, lora_scale=2.0))
+    step = fedavg.adam_step(grad)
+    trained = []
+    with jax.default_matmul_precision("highest"):
+        for node in range(2):
+            batches = [(base, jnp.asarray(x_all[node][i]), jnp.asarray(y_all[node][i])) for i in perm[node, 0]]
+            out, _ = fedavg.adam_train(lora, batches, step, {"name": "adam", "schedule": "constant", "learning_rate": 1e-3})
+            trained.append(jax.tree.map(np.asarray, out))
+    want = fedavg.weighted_mean(trained, [x_all.shape[1]] * 2)
+    assert ck.cosine(ck.tree_sub(got, start), ck.tree_sub(want, start)) > 0.999
+
+
+def test_capacity_based_moe_under_the_layer_scan_is_still_refused_and_experts_are_not():
+    with pytest.raises(NotImplementedError, match="period scan"):
+        CausalLM(TransformerConfig(n_experts=4, scan_layers=True)).init(jax.random.PRNGKey(0), batch()[0])
+    CausalLM(config()).init(jax.random.PRNGKey(0), batch()[0])  # a router that sows no loss scans
+
+
+def test_flops_moe_counts_the_published_model():
+    import json
+
+    from benchmark import flops_moe
+
+    cfg = json.loads((ROOT / "benchmark" / "configs" / "glm47_flash_lora.json").read_text())
+    assert sum(i * o for _, i, o in flops_moe.mla_matrices(cfg)) + 768 + 512 == 21_759_232
+    assert flops_moe.layer_params(cfg, "mla_dense") == 84_677_888
+    assert flops_moe.layer_params(cfg, "mla_experts") == 31_331_648
+    assert flops_moe.bank_params(cfg) == 603_979_776 == 64 * 9_437_184
+    assert cfg["rms_norm_eps"] == 1e-5 and cfg["vocab_size"] == 154_880 and cfg["num_experts_per_tok"] == 4
+    ops, moved = flops_moe.gmm_pass(cfg, 4096)
+    assert ops == 2.0 * 4096 * 4 * 9_437_184  # four experts a token, not executed tiles
+    assert moved > 2 * 603_979_776  # the bfloat16 bank once, and the rows
+
+
+@pytest.mark.parametrize("module", ["benchmark.selfcheck", "benchmark.rehearse", "benchmark.planted_faults"])
+def test_benchmark_files_resolve_and_the_cell_rehearses(module):
+    """The last case runs the cell's WHOLE reference check at the rehearsal's
+    sizes with the expert layer choosing without its bias: exit 0 = it was seen."""
+    args = {
+        "benchmark.selfcheck": [],
+        "benchmark.rehearse": ["--workload", "glm_silo4_seq4096", "--seconds", "1"],
+        "benchmark.planted_faults": ["--workload", "glm_silo4_seq4096", "--seed", "1", "--fault", "choose_without_bias", "--rehearsal"],
+    }[module]
+    done = subprocess.run(
+        [sys.executable, "-m", module, *args], cwd=ROOT, capture_output=True, text=True, timeout=900,
+        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)},
+    )
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
+    if module.endswith("rehearse"):
+        assert '"correct": true' in done.stdout and "rehearsal finished" in done.stdout
+    if module.endswith("planted_faults"):
+        assert "'layer.routing_agreement'" in done.stdout.splitlines()[-1]

@@ -240,3 +240,35 @@ def test_recovery_after_a_consumed_donation_restages_without_optimizer_state():
     assert fed.opt_state is None
     fed.run_round(epochs=1)
     assert np.isfinite(float(fed.history[-1]["train_loss"]))
+
+
+def test_a_model_that_sows_no_statistics_adds_nothing_to_the_round():
+    """The round returns the model's sown statistics in fourth place: an empty
+    tree for a dense model — no result, no history key, the program it was."""
+    from p2pfl_tpu.parallel.spmd_lora import spmd_lora_round
+
+    fed = _small(False)
+    args, statics = fed._round_call(1)
+    out = jax.eval_shape(lambda *a: spmd_lora_round(*a, **statics), *args)
+    assert len(out) == 4 and out[3] == {} and out[1] is None
+    entry = fed.run_round(epochs=1)
+    assert sorted(entry) == ["round", "train_loss"]
+
+
+def test_an_expert_models_round_carries_its_load_statistic_without_a_fetch():
+    cfg = TransformerConfig(
+        vocab_size=256, dim=64, n_layers=3, n_heads=4, n_kv_heads=4, ffn_hidden=160, rope_theta=1e6,
+        layer_pattern=("mla_dense", "mla_experts", "mla_experts"), lora_rank=4, lora_mlp=True, remat=True,
+        scan_layers=True, norm_eps=1e-5, q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=12, qk_rope_dim=4,
+        v_head_dim=16, routed_experts=8, experts_per_token=2, expert_hidden=32, shared_experts=1,
+        routed_scale=1.8, expert_tile_m=8,
+    )
+    model = tiny_transformer(seq_len=32, cfg=cfg)
+    fed = SpmdLoraFederation.from_dataset(model, _data(), n_nodes=4, batch_size=8, vote=False, node_chunk=2)
+    first, second = fed.run_round(epochs=1), fed.run_round(epochs=1)
+    for entry in (first, second):
+        load = entry["moe_load_max_over_mean"]
+        assert isinstance(load, jax.Array) and load.shape == ()  # a device scalar: nobody fetched it
+        assert 1.0 <= float(load) <= 8 / 2
+    fed.reset(0)
+    assert fed.history == []
