@@ -25,6 +25,8 @@ from p2pfl_tpu.learning.learner import NodeLearner, adam, ce_eval
 from p2pfl_tpu.management.logger import logger
 from p2pfl_tpu.management.profiling import scope
 from p2pfl_tpu.models.base import FlaxModel
+from p2pfl_tpu.models.transformer import tied_logits
+from p2pfl_tpu.ops.head_loss import head_loss
 
 Pytree = Any
 
@@ -63,18 +65,24 @@ def merge_params(base: dict, overlay: dict) -> dict:
 
 def _lm_forward(lora, base, module, x, y):
     """(training loss, logits, statistics, routing): CE + any sown auxiliary
-    losses (MoE router balance); what the model sowed into ``"moe_stats"`` —
+    losses (MoE router balance) — the CE from :func:`head_loss`, which holds no
+    ``[T, vocab]`` array whole; the logits the plain way beside it, a dead value
+    that the compiler removes from a program that drops them (every training
+    round does); what the model sowed into ``"moe_stats"`` —
     each name's mean over the layers that sowed it, ``{}`` for a model that
     sows none (an expert layer's ``load_max_over_mean``); and the
     ``"moe_routing"`` collection as sown (the experts each row chose, from THIS
     forward — a comparison must not take them from another program: a TPU
     rounds a near-tie differently from one compiled program to the next)."""
     params = merge_params(base, lora)
-    logits, mut = module.apply({"params": params}, x, mutable=["moe_losses", "moe_stats", "moe_routing"])
+    (hidden, embedding), mut = module.apply(
+        {"params": params}, x, head=False, mutable=["moe_losses", "moe_stats", "moe_routing"]
+    )
     leaves = jax.tree.leaves(mut.get("moe_losses", {}))
     aux = sum(leaves) if leaves else jnp.zeros((), jnp.float32)
+    ce = head_loss(hidden, embedding, y)
     with scope("head"):
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+        logits = tied_logits(hidden, embedding)
     found: dict[str, list] = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(mut.get("moe_stats", {})):
         name = next(k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey))

@@ -55,7 +55,7 @@ DEVICE_SCOPES = (
     "moe_experts",  # ExpertFFN: gather into sorted order, both grouped matmuls, the activation
     "moe_gmm",  # ops/grouped_matmul: the product alone (the Mosaic call p2pfl_gmm on a TPU), inside moe_experts
     "moe_combine",  # ExpertFFN: unsort, weigh, sum over the k, add the shared expert's output
-    "head",  # CausalLM / _lm_loss: final norm's output x embedding^T, and the loss
+    "head",  # CausalLM's logits, and both rules of ops/head_loss (the training loss: logits by block, statistics, dX)
 )
 
 

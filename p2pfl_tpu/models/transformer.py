@@ -823,12 +823,27 @@ class _ScanPeriod(nn.Module):
         return x, None
 
 
+def tied_logits(hidden, embedding):
+    """``[B, T, dim] x [vocab, dim] -> [B, T, vocab]`` float32: the tied head,
+    multiplied in the hidden states' dtype."""
+    return jnp.dot(hidden, embedding.T.astype(hidden.dtype)).astype(jnp.float32)
+
+
 class CausalLM(nn.Module):
+    """Decoder-only LM with the output head tied to the embedding.
+
+    ``__call__(tokens)`` gives float32 logits ``[B, T, vocab]`` — what
+    evaluation, generation and ``SpmdLmFederation`` read. ``head=False`` stops
+    before the head and hands out what it would take, ``(final norm's output
+    [B, T, dim], the head's matrix [vocab, dim])``: a training loss that needs
+    no logits whole takes both to :func:`p2pfl_tpu.ops.head_loss.head_loss`
+    (``learning/lora.py::_lm_forward``)."""
+
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
 
     @nn.compact
-    def __call__(self, tokens):  # [B, T] int32 -> [B, T, vocab] f32 logits
+    def __call__(self, tokens, head: bool = True):  # [B, T] int32 -> [B, T, vocab] f32 logits
         cfg = self.cfg
         emb = self.param(
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim)
@@ -868,9 +883,10 @@ class CausalLM(nn.Module):
                 block_cls = nn.remat(Block, policy=_remat_policy(cfg.remat_policy, kind)) if cfg.remat else Block
                 x = block_cls(cfg, self.attn_fn, kind, name=f"layer_{i}")(x)
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
+        if not head:
+            return x, emb
         with scope("head"):
-            logits = jnp.dot(x, emb.T.astype(cfg.dtype))  # tied embeddings
-            return logits.astype(jnp.float32)
+            return tied_logits(x, emb)
 
 
 def pick_attention(seq_len: int, backend: Optional[str] = None) -> str:

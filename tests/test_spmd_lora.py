@@ -210,9 +210,11 @@ def test_not_kept_round_has_no_node_wide_optimizer_operand_or_result():
 
 def test_kept_round_is_the_program_it_was():
     """With ``keep_opt_state=True`` the round traces to the program of the
-    commit before the optimizer state left the not-kept round (ba2aefb):
-    939 equations (nested ones counted), and the sequence of primitives with
-    their result types hashes to 12552ce8600d1968 — both recorded there."""
+    commit before the optimizer state left the not-kept round (ba2aefb: 939
+    equations, nested ones counted, primitives with their result types hashing
+    to 12552ce8600d1968) but for its loss: recorded again at PR 32, when
+    ``_lm_forward`` took the cross-entropy from ``ops/head_loss.py`` — 901
+    equations, bdd0d24d90a122cb; operands and results as they were."""
     import hashlib
 
     from p2pfl_tpu.parallel.spmd_lora import spmd_lora_round
@@ -227,8 +229,8 @@ def test_kept_round_is_the_program_it_was():
     args, statics = _small(True)._round_call(1)
     closed = jax.make_jaxpr(lambda *a: spmd_lora_round(*a, **statics))(*args)
     eqns = walk(closed.jaxpr, [])
-    assert len(eqns) == 939
-    assert hashlib.sha256("\n".join(eqns).encode()).hexdigest()[:16] == "12552ce8600d1968"
+    assert len(eqns) == 901
+    assert hashlib.sha256("\n".join(eqns).encode()).hexdigest()[:16] == "bdd0d24d90a122cb"
     assert (len(closed.jaxpr.invars), len(closed.jaxpr.outvars)) == (42, 26)
 
 
