@@ -63,6 +63,18 @@ def merge_params(base: dict, overlay: dict) -> dict:
     return out
 
 
+def _mean_over_layers(leaves: list):
+    """Mean over every layer that sowed into ``leaves`` — one leaf a run of
+    expert layers, stacked along its scans; runs of unlike length weigh by
+    their layers (equal runs, one run among them, keep the plain mean of means:
+    the program every single-run model has always lowered to)."""
+    means = jnp.stack([jnp.mean(leaf) for leaf in leaves])
+    sizes = [leaf.size for leaf in leaves]
+    if len(set(sizes)) == 1:
+        return jnp.mean(means)
+    return jnp.sum(means * (jnp.asarray(sizes, means.dtype) / sum(sizes)))
+
+
 def _lm_forward(lora, base, module, x, y):
     """(training loss, logits, statistics, routing): CE + any sown auxiliary
     losses (MoE router balance) — the CE from :func:`head_loss`, which holds no
@@ -86,8 +98,8 @@ def _lm_forward(lora, base, module, x, y):
     found: dict[str, list] = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(mut.get("moe_stats", {})):
         name = next(k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey))
-        found.setdefault("moe_" + name, []).append(jnp.mean(leaf))
-    stats = {name: jnp.mean(jnp.stack(vals)) for name, vals in found.items()}
+        found.setdefault("moe_" + name, []).append(leaf)
+    stats = {name: _mean_over_layers(leaves) for name, leaves in found.items()}
     return ce + aux, logits, stats, mut.get("moe_routing", {})
 
 

@@ -50,6 +50,8 @@ DEVICE_SCOPES = (
     "ssm_conv",  # MambaMixer: the causal depthwise convolution and its silu
     "ssm_scan_fwd",  # ops/selective_scan: everything the op runs forward (kernel and glue)
     "ssm_scan_bwd",  # ops/selective_scan: everything its backward runs
+    "short_conv",  # ShortConvMixer: both gate products and the taps, between its two projections
+    "qk_norm",  # Attention: the per-head norms of q and k before RoPE
     "mla",  # MLAttention: the glue between its five projections (split, RoPE, concatenate, broadcast)
     "moe_route",  # ExpertFFN: router, top-k, sort indices, group sizes
     "moe_experts",  # ExpertFFN: gather into sorted order, both grouped matmuls, the activation

@@ -18,7 +18,13 @@ period, and the stack repeats the period:
   the DeepSeek-V3 block) followed by the dense SwiGLU or by the dropless
   sigmoid-routed expert layer (:class:`ExpertFFN`) — these two kinds name the
   feed-forward as well as the mixer (:data:`LAYER_KINDS`), so "one dense layer,
-  then N expert layers" is one period of two runs.
+  then N expert layers" is one period of two runs;
+- ``"conv_dense"`` / ``"conv_experts"``: the gated short convolution
+  (:class:`ShortConvMixer`, the LFM2 operator) before the dense SwiGLU or the
+  expert layer; ``"attention_experts"``: plain attention before the expert layer.
+
+``TransformerConfig.leading_pattern`` names layers that run ONCE before the
+periodic stack ("two dense layers, then periods of expert layers").
 
 Attention backends — pick with ``tiny_transformer(attn=...)``:
 
@@ -59,13 +65,20 @@ _REMAT_SAVE_NAMES = {
 }
 # layer kind -> (sequence mixer, feed-forward). ``None`` is the config-wide
 # rule the first two kinds have always had (``MoEMLP`` if ``n_experts`` else
-# ``MLP``); the latent-attention kinds name theirs.
+# ``MLP``); every other kind names its own: mixer x feed-forward is a product.
 LAYER_KINDS = {
     "attention": ("attention", None),
     "mamba": ("mamba", None),
     "mla_dense": ("mla", "mlp"),
     "mla_experts": ("mla", "experts"),
+    "attention_experts": ("attention", "experts"),
+    "conv_dense": ("short_conv", "mlp"),
+    "conv_experts": ("short_conv", "experts"),
 }
+
+
+def _is_expert_run(kind: str) -> bool:
+    return LAYER_KINDS[kind][1] == "experts"
 
 
 def _remat_policy(name: Optional[str], kind: str = "attention"):
@@ -76,7 +89,7 @@ def _remat_policy(name: Optional[str], kind: str = "attention"):
     the re-forward's norm differently, a bfloat16 input rounds the other way,
     and a near-tie flips (the backward would then differentiate another
     function than the forward ran)."""
-    keep = ("moe_chosen",) if LAYER_KINDS[kind][1] == "experts" else ()
+    keep = ("moe_chosen",) if _is_expert_run(kind) else ()
     if name is None and not keep:
         return None  # full per-block remat: save nothing inside the block
     try:
@@ -99,10 +112,19 @@ class TransformerConfig:
     # None = no positional rotation at all (Jamba's attention layers: the
     # state-space layers around them carry position)
     rope_theta: Optional[float] = 10000.0
-    # One period of the layer stack, a sequence mixer per layer
-    # (``"attention"`` | ``"mamba"``); ``n_layers`` must be a multiple of its
-    # length. Jamba: 14 long, attention at index 7.
+    # One period of the layer stack, a kind (:data:`LAYER_KINDS`) per layer;
+    # ``n_layers`` less the leading layers must be a multiple of its length.
+    # Jamba: 14 long, attention at index 7.
     layer_pattern: tuple = ("attention",)
+    # Layers that run ONCE, before the first period (LFM2: two dense
+    # short-convolution layers, then periods of expert layers). No expert kind:
+    # an expert layer's stacked bank belongs to a period's run.
+    leading_pattern: tuple = ()
+    # per-head RMSNorm (its own scale, over ``head_dim``) on q and k before
+    # RoPE (``"attention"`` mixers only)
+    qk_norm: bool = False
+    # taps of the gated short convolution (``"conv_*"`` layers only)
+    conv_taps: int = 3
     # Mamba-1 widths (used by ``"mamba"`` layers only): inner width
     # ``ssm_expand * dim``, state per channel, depthwise-conv kernel, and the
     # rank of the step-size projection (None = ceil(dim / 16), Mamba's rule)
@@ -157,8 +179,8 @@ class TransformerConfig:
     # program size stop scaling with n_layers (the unrolled 16L/768d
     # model's MLIR is big enough to overflow intermediaries; the scanned
     # one is ~1 layer's worth). The XLA-idiomatic deep-model form.
-    # Incompatible with n_experts>0 for now (sown MoE aux losses don't
-    # thread through nn.scan broadcasts here).
+    # Incompatible with ``MoEMLP`` (n_experts>0: its sown aux LOSSES don't
+    # thread through nn.scan broadcasts here); ``ExpertFFN`` sows none and scans.
     scan_layers: bool = False
     # Static flash-kernel schedule (ops/flash_attention.FlashConfig): when
     # set, any Block built from this config WITHOUT an explicit attn_fn
@@ -197,11 +219,17 @@ class TransformerConfig:
     def __post_init__(self) -> None:
         pattern = tuple(self.layer_pattern)
         object.__setattr__(self, "layer_pattern", pattern)  # a list would not hash
+        leading = tuple(self.leading_pattern)
+        object.__setattr__(self, "leading_pattern", leading)
         if not pattern or any(kind not in LAYER_KINDS for kind in pattern):
             raise ValueError(f"layer_pattern {pattern!r}: one or more of {tuple(LAYER_KINDS)}")
-        if self.n_layers % len(pattern):
+        if any(kind not in LAYER_KINDS or _is_expert_run(kind) for kind in leading):
+            dense = tuple(kind for kind in LAYER_KINDS if not _is_expert_run(kind))
+            raise ValueError(f"leading_pattern {leading!r}: any of {dense} (an expert layer belongs to the period)")
+        if self.n_layers < len(leading) or (self.n_layers - len(leading)) % len(pattern):
             raise ValueError(
-                f"n_layers {self.n_layers} is not a whole number of periods of {len(pattern)} layers"
+                f"n_layers {self.n_layers} is not {len(leading)} leading layer(s) (leading_pattern) and a whole "
+                f"number of periods of {len(pattern)} layers (layer_pattern)"
             )
         if self.remat_policy is not None:
             _remat_policy(self.remat_policy)  # raises on an unknown name
@@ -306,6 +334,10 @@ class Attention(nn.Module):
         q = q.reshape(b, t, cfg.n_heads, head_dim)
         k = k.reshape(b, t, cfg.n_kv_heads, head_dim)
         v = v.reshape(b, t, cfg.n_kv_heads, head_dim)
+        if cfg.qk_norm:
+            with scope("qk_norm"):
+                q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
         if cfg.rope_theta is not None:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         # selective-remat tags: saved pre-GQA-repeat (kv_heads wide, the
@@ -657,15 +689,50 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jnp.broadcast_to(jnp.arange(1, shape[1] + 1, dtype=dtype), shape))
 
 
-def causal_depthwise_conv(u: jax.Array, kernel: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_depthwise_conv(u: jax.Array, kernel: jax.Array, bias: Optional[jax.Array] = None) -> jax.Array:
     """``out[t] = bias + Σ_k kernel[k] · u[t − (K−1) + k]`` per channel, zeros
-    before the sequence; ``u`` is ``[B, T, C]``, ``kernel`` ``[K, C]``. Float32."""
+    before the sequence; ``u`` is ``[B, T, C]``, ``kernel`` ``[K, C]``, ``bias``
+    ``[C]`` or none. Float32."""
     taps, t = kernel.shape[0], u.shape[1]
     padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = None if bias is None else bias.astype(jnp.float32)
     for k in range(taps):
-        out = out + kernel[k].astype(jnp.float32) * padded[:, k:k + t]
+        term = kernel[k].astype(jnp.float32) * padded[:, k:k + t]
+        out = term if out is None else out + term
     return out
+
+
+def gated_short_conv(bcu: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
+    """``C ⊙ causal_depthwise_conv(B ⊙ u)`` of ``bcu`` = ``[B | C | u]``
+    (``[batch, T, 3 C]``): both gate products and the taps in float32 under one
+    scope, the result rounded once to ``dtype``."""
+    with scope("short_conv"):
+        b, c, u = (part.astype(jnp.float32) for part in jnp.split(bcu, 3, axis=-1))
+        return (c * causal_depthwise_conv(b * u, kernel)).astype(dtype)
+
+
+class ShortConvMixer(nn.Module):
+    """The gated short convolution (the LFM2 operator; ``lfm2_moe``)::
+
+        [B | C | u] = x W_in;    y = (C ⊙ causal_depthwise_conv(B ⊙ u)) W_out
+
+    ``cfg.conv_taps`` taps a channel, no bias, no activation, no state beyond
+    ``conv_taps − 1`` positions. ``in_proj`` and ``out_proj`` carry adapters;
+    the taps are a base leaf: frozen under LoRA and outside its FedAvg. What
+    lies between the projections is :func:`gated_short_conv`."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dense = partial(LoRADense, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
+        bcu = dense(3 * cfg.dim, name="in_proj")(x)
+        kernel = self.param(
+            "conv_kernel", nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+            (cfg.conv_taps, cfg.dim),
+        )
+        return dense(cfg.dim, name="out_proj")(gated_short_conv(bcu, kernel, cfg.dtype))
 
 
 class MambaMixer(nn.Module):
@@ -726,6 +793,8 @@ class Block(nn.Module):
         norm = partial(RMSNorm, cfg.dtype, cfg.norm_eps)
         if mixer == "mamba":
             x = x + MambaMixer(cfg, name="mamba")(norm(name="mamba_norm")(x))
+        elif mixer == "short_conv":
+            x = x + ShortConvMixer(cfg, name="conv")(norm(name="conv_norm")(x))
         else:
             attention = MLAttention if mixer == "mla" else Attention
             x = x + attention(cfg, self.attn_fn, name="attn")(norm(name="attn_norm")(x))
@@ -763,14 +832,14 @@ def layer_runs(pattern: tuple) -> list[tuple[str, int]]:
     return runs
 
 
-def _rematted_in_scan(body, cfg: TransformerConfig, kind: str = "attention"):
-    """``body`` (a block of ``kind``) rematerialised where the config says so,
-    for use INSIDE a scan. prevent_cse=False: inside lax.scan the remat thunk
+def _rematted(body, cfg: TransformerConfig, kind: str = "attention", in_scan: bool = True):
+    """``body`` (a block of ``kind``) rematerialised where the config says so.
+    ``in_scan``: for use INSIDE a scan, prevent_cse=False — the remat thunk
     can't be CSE'd across iterations anyway, and True blocks the scan lowering
     (flax's documented scan-over-remat recipe)."""
     if not cfg.remat:
         return body
-    return nn.remat(body, prevent_cse=False, policy=_remat_policy(cfg.remat_policy, kind))
+    return nn.remat(body, prevent_cse=not in_scan, policy=_remat_policy(cfg.remat_policy, kind))
 
 
 def _scan_over(body, length: int, with_bank: bool = False):
@@ -785,8 +854,22 @@ def _scan_over(body, length: int, with_bank: bool = False):
     )
 
 
-def _is_expert_run(kind: str) -> bool:
-    return LAYER_KINDS[kind][1] == "experts"
+def _apply_run(cfg: TransformerConfig, attn_fn, kind: str, count: int, name: str, x, bank=None, in_scan: bool = True):
+    """One run of ``count`` same-kind layers under ``scan_layers``: a scan of
+    its own over stacked params, or the block itself for a run of one. Params:
+    ``<name>/block/...`` with a leading run-length axis, or ``<name>/...``.
+    ``bank`` (an expert run): ``(index of the run's first layer in the stacks,
+    w13, w2)``; ``in_scan``: whether the run sits inside the period scan."""
+    if count > 1:
+        scan = _scan_over(_rematted(_ScanBlock, cfg, kind), count, with_bank=bank is not None)(
+            cfg, attn_fn, kind, name=name
+        )
+        if bank is None:
+            return scan(x, None)[0]
+        first, *stacks = bank
+        return scan(x, first + jnp.arange(count, dtype=jnp.int32), tuple(stacks))[0]
+    block = _rematted(Block, cfg, kind, in_scan)(cfg, attn_fn, kind, name=name)
+    return block(x) if bank is None else block(x, bank)
 
 
 class _ScanPeriod(nn.Module):
@@ -809,18 +892,32 @@ class _ScanPeriod(nn.Module):
         cfg = self.cfg
         for i, (kind, count) in enumerate(layer_runs(cfg.layer_pattern)):
             name = f"run{i}_{kind}"
-            experts = _is_expert_run(kind)
-            if count > 1:
-                scan = _scan_over(_rematted_in_scan(_ScanBlock, cfg, kind), count, with_bank=experts)
-                if experts:
-                    layers = period * count + jnp.arange(count, dtype=jnp.int32)
-                    x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, layers, banks[name])
-                else:
-                    x, _ = scan(cfg, self.attn_fn, kind, name=name)(x, None)
-            else:
-                block = _rematted_in_scan(Block, cfg, kind)(cfg, self.attn_fn, kind, name=name)
-                x = block(x, (period, *banks[name])) if experts else block(x)
+            # an expert run: this period's first layer in the run's stacks, and the stacks
+            bank = (period * count, *banks[name]) if _is_expert_run(kind) else None
+            x = _apply_run(cfg, self.attn_fn, kind, count, name, x, bank)
         return x, None
+
+
+def sown_by_layer(cfg: TransformerConfig, sown) -> jax.Array:
+    """What the expert layers of a :class:`CausalLM` sowed under one name —
+    ``mut["moe_routing"]`` or ``mut["moe_stats"]`` as ``apply`` hands it out —
+    as ONE array ``[expert layers, ...]`` in the order the layers run. Scanned,
+    each expert run of the period sows its own leaf, stacked along the period
+    scan and, for a run of several layers, its own scan: the runs interleave by
+    period. Unrolled, ``layer_<i>`` sows layer ``i``'s."""
+    if not cfg.scan_layers:
+        at = sorted((int(name.split("_")[1]), name) for name in sown)
+        return jnp.stack([jax.tree.leaves(sown[name])[0] for _, name in at])
+    if len(cfg.layer_pattern) == 1:
+        (leaf,) = jax.tree.leaves(sown)
+        return leaf  # [periods, ...]: a period is a layer
+    per_period = []
+    for i, (kind, count) in enumerate(layer_runs(cfg.layer_pattern)):
+        if _is_expert_run(kind):
+            (leaf,) = jax.tree.leaves(sown["layers"][f"run{i}_{kind}"])
+            per_period.append(leaf if count > 1 else leaf[:, None])  # [periods, count, ...]
+    stacked = jnp.concatenate(per_period, axis=1)
+    return stacked.reshape(-1, *stacked.shape[2:])
 
 
 def tied_logits(hidden, embedding):
@@ -849,15 +946,18 @@ class CausalLM(nn.Module):
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim)
         )
         x = emb[tokens].astype(cfg.dtype)
-        pattern = cfg.layer_pattern
+        pattern, leading = cfg.layer_pattern, cfg.leading_pattern
         if cfg.scan_layers:
-            if cfg.n_experts > 0 and any(LAYER_KINDS[kind][1] is None for kind in pattern):
+            if cfg.n_experts > 0 and any(LAYER_KINDS[kind][1] is None for kind in leading + pattern):
                 # MoEMLP's auxiliary LOSSES; ExpertFFN (noaux_tc routing) sows none
                 raise NotImplementedError(
                     "scan_layers with MoE: sown aux losses don't thread through "
                     "the layer scan or the period scan — use unrolled layers for MoE"
                 )
-            periods = cfg.n_layers // len(pattern)
+            # the layers before the first period: runs of their own, outside the period scan
+            for i, (kind, count) in enumerate(layer_runs(leading)):
+                x = _apply_run(cfg, self.attn_fn, kind, count, f"lead{i}_{kind}", x, in_scan=False)
+            periods = (cfg.n_layers - len(leading)) // len(pattern)
             # the expert banks of every layer, declared OUTSIDE the scans: a scan
             # would slice its stacked params, and a Mosaic call reads no slice in place
             bank_shapes = {"w13": (cfg.dim, 2 * cfg.expert_hidden), "w2": (cfg.expert_hidden, cfg.dim)}
@@ -870,7 +970,7 @@ class CausalLM(nn.Module):
             }
             if len(pattern) == 1:
                 # a period of one layer is the scan body itself
-                body, args = _rematted_in_scan(_ScanBlock, cfg, pattern[0]), (cfg, self.attn_fn, pattern[0])
+                body, args = _rematted(_ScanBlock, cfg, pattern[0]), (cfg, self.attn_fn, pattern[0])
                 bank = banks.get(f"run0_{pattern[0]}")
             else:
                 body, args, bank = _ScanPeriod, (cfg, self.attn_fn), banks or None
@@ -879,9 +979,8 @@ class CausalLM(nn.Module):
             x, _ = scan(*args, name="layers")(x, *xs)
         else:
             for i in range(cfg.n_layers):
-                kind = pattern[i % len(pattern)]
-                block_cls = nn.remat(Block, policy=_remat_policy(cfg.remat_policy, kind)) if cfg.remat else Block
-                x = block_cls(cfg, self.attn_fn, kind, name=f"layer_{i}")(x)
+                kind = leading[i] if i < len(leading) else pattern[(i - len(leading)) % len(pattern)]
+                x = _rematted(Block, cfg, kind, in_scan=False)(cfg, self.attn_fn, kind, name=f"layer_{i}")(x)
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
         if not head:
             return x, emb
