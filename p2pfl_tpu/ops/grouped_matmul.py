@@ -12,18 +12,27 @@ most ``G`` partly-filled tiles (what a kernel that lets tiles straddle pays as
 well: a straddled tile is multiplied once per group) and buys a kernel body
 that is one plain matmul with nothing to mask.
 
-On a TPU the product is the Mosaic kernel ``p2pfl_gmm``: ``tile_group[i]`` (the
-group of row tile ``i``, from ``group_sizes``) and the number of tiles in use
-are prefetched as scalars, the block index map picks ``rhs[tile_group[i]]`` for
-step ``i``, and because the row tiles of one group are consecutive the
-pipeline fetches each group's matrix ONCE a pass — the bank is read once, the
-rows once. Tiles past the used count are not multiplied (they are written as
-zeros) and fetch nothing new. Everywhere else (the CPU tests, ``impl="xla"``)
-the product is ``lax.ragged_dot`` over the same layout.
+On a TPU the product is the Mosaic kernel ``p2pfl_gmm``: the row tiles run in
+order over the grid, the rows and the output through the grid's own pipeline,
+and because the row tiles of one group are consecutive the kernel fetches each
+group's matrix ONCE a pass — the bank is read once, the rows once. The bank
+itself stays in HBM and the kernel copies it, a matrix block a group, into a
+ring of two VMEM slots: when a group's FIRST row tile starts, the copy of the
+next group that has rows is issued into the slot the group before it has just
+left, so a fetch has the whole of a group's tiles to arrive behind. (Until
+PR 34 the matrix was a block of the grid's pipeline, which asks for step
+``i + 1``'s block when step ``i`` starts — at a group's LAST tile: one 128-row
+tile, 6-12 µs, against a 7-15 MB copy of 9-18 µs at 819 GB/s, so every group
+boundary stalled, 10-13 % of a call at both expert cells' shapes.) Which fetch
+each tile multiplies, the group of each fetch and the counts of both
+(:func:`tiles_and_fetches`) are prefetched scalars. Tiles past the used count
+are not multiplied (they are written as zeros) and fetch nothing; a call's
+first block is the one fetch nothing hides. Everywhere else (the CPU tests,
+``impl="xla"``) the product is ``lax.ragged_dot`` over the same layout.
 
 A stack of banks ``[L, G, K, N]`` — the layers of a scanned run — is read in
-place as well: ``layer`` is a third prefetched scalar and the index map picks
-``rhs[layer, tile_group[i]]``. The scan body takes the whole stack as a loop
+place as well: ``layer`` is one more prefetched scalar and the copy's source is
+``rhs[layer, group]``. The scan body takes the whole stack as a loop
 constant; nothing slices it (a Mosaic call cannot read a slice of an operand in
 place: XLA would copy the layer's bank before every call).
 
@@ -51,11 +60,16 @@ from jax.experimental.pallas import tpu as pltpu
 from p2pfl_tpu.management.profiling import scope
 
 DEFAULT_TILE_M = 128
-# one matrix block of the bank may take this much VMEM (it is double-buffered);
-# the GLM expert matrices (2048 x 3072 and 1536 x 2048 bf16: 12.6 and 6.3 MB)
-# go in whole, so the rows are read once a pass
+# one matrix block of the bank may take this much VMEM (the ring holds
+# `_RING_SLOTS` of them); the GLM and LFM2 expert matrices (2048 x 3072 / 1536 x 2048 and
+# 2048 x 3584 / 1792 x 2048 bf16: 12.6 / 6.3 and 14.7 / 7.3 MB) go in whole, so
+# the rows are read once a pass
 _RHS_BLOCK_BYTES = 16 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# matrix blocks in the kernel's ring: the one being multiplied and the next
+# group's. With a third (the block two groups ahead in flight as well) the
+# product read 0.5-1.7 % SLOWER at both expert cells' shapes (PERF.md, PR 34)
+_RING_SLOTS = 2
 
 
 class GroupLayout(NamedTuple):
@@ -97,34 +111,90 @@ def group_layout(group_of: jax.Array, n_groups: int, tile_m: int = DEFAULT_TILE_
     return GroupLayout(sizes, slot_of_assignment, assignment_of_slot, rows)
 
 
-def _tile_groups(group_sizes: jax.Array, n_tiles: int, tile_m: int) -> tuple[jax.Array, jax.Array]:
-    """(``[n_tiles]`` group of each row tile, ``[1]`` tiles in use). A tile past
-    the used count names the last used tile's group: it fetches no matrix."""
-    ends = jnp.cumsum(_tiles_per_group(group_sizes, tile_m))
-    used = ends[-1]
+def tiles_and_fetches(group_sizes: jax.Array, tile_m: int) -> tuple[jax.Array, jax.Array]:
+    """(row tiles in use, matrix blocks fetched) by one call over ``group_sizes``:
+    ``Σ ceil(s_g / tile_m)`` and the number of groups with a row. Their ratio is
+    the arithmetic a fetch has to hide behind (a column-split call fetches that
+    many blocks a column block)."""
+    tiles = _tiles_per_group(group_sizes, tile_m)
+    return tiles.sum().astype(jnp.int32), (tiles > 0).sum().astype(jnp.int32)
+
+
+def _fetch_plan(group_sizes: jax.Array, n_tiles: int, tile_m: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The kernel's prefetched scalars: ``[n_tiles]`` the fetch each row tile
+    multiplies (its group's rank among the groups WITH rows; a tile past the
+    used count names the last used tile's), ``[G]`` the group of each fetch
+    (the groups with rows, in order) and ``[2]`` (tiles in use, fetches).
+    Counted by comparing every tile, and every fetch, with every group: a few
+    small fusions, where running sums, a search and a scatter were a loop and
+    a dozen launches (10-15 µs a call on the chip: PERF.md, PR 34)."""
+    tiles = _tiles_per_group(group_sizes, tile_m)
+    used, fetches = tiles_and_fetches(group_sizes, tile_m)
+    has = tiles > 0
+    group = jnp.arange(group_sizes.shape[0], dtype=jnp.int32)
+    upto = group[:, None] >= group[None, :]  # [g, h]: group h is g or before it
+    ends = jnp.sum(jnp.where(upto, tiles[None, :], 0), axis=1)  # row tiles through group g
+    rank_end = jnp.sum(upto & has[None, :], axis=1)  # groups with rows through group g
     tile = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), jnp.maximum(used - 1, 0))
-    group = jnp.searchsorted(ends, tile, side="right").astype(jnp.int32)
-    return jnp.minimum(group, group_sizes.shape[0] - 1), used.reshape(1).astype(jnp.int32)
+    # the groups with rows that END at or before a tile are those before its own
+    tile_fetch = jnp.sum(has[None, :] & (ends[None, :] <= tile[:, None]), axis=1)
+    # fetch r is the first group through which r + 1 groups have rows
+    fetch_group = jnp.minimum(jnp.sum(rank_end[None, :] <= group[:, None], axis=1), group[-1])
+    return tile_fetch.astype(jnp.int32), fetch_group.astype(jnp.int32), jnp.stack([used, fetches])
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _gmm_kernel(layer_ref, tile_group_ref, used_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs: bool):
-    del layer_ref, tile_group_ref  # read by the index maps
-    i = pl.program_id(1)
+def _gmm_kernel(
+    layer_ref, tile_fetch_ref, fetch_group_ref, counts_ref, lhs_ref, rhs_hbm, out_ref, ring, sems,
+    *, transpose_rhs: bool, n_cols: int,
+):  # fmt: skip
+    j, i = pl.program_id(0), pl.program_id(1)
+    used, fetches = counts_ref[0], counts_ref[1]
+    bn = out_ref.shape[1]
+    blocks = fetches * n_cols  # of the whole call
 
-    @pl.when(i < used_ref[0])
+    def fetch(f):
+        """The copy of the call's ``f``-th matrix block — column block
+        ``f // fetches`` of group ``fetch_group[f % fetches]`` — into its slot."""
+        if n_cols == 1:  # the whole matrix is one block
+            src = rhs_hbm.at[layer_ref[0], fetch_group_ref[f]]
+        else:
+            g, col = fetch_group_ref[lax.rem(f, fetches)], pl.multiple_of(lax.div(f, fetches) * bn, bn)
+            cut = (pl.ds(col, bn), slice(None)) if transpose_rhs else (slice(None), pl.ds(col, bn))
+            src = rhs_hbm.at[(layer_ref[0], g, *cut)]
+        slot = lax.rem(f, _RING_SLOTS)
+        return pltpu.make_async_copy(src, ring.at[slot], sems.at[slot])
+
+    @pl.when(i < used)
     def _():
+        f = j * fetches + tile_fetch_ref[i]
+
+        @pl.when((i == 0) | (tile_fetch_ref[i] != tile_fetch_ref[jnp.maximum(i - 1, 0)]))
+        def _():  # a group's first tile
+            @pl.when(f == 0)
+            def _():  # the call's first block: nothing hides it
+                fetch(0).start()
+
+            fetch(f).wait()
+
+            # every tile of the block before this one is done, so its slot is
+            # free for the next, which has the whole of this group's tiles to
+            # arrive behind
+            @pl.when(f + 1 < blocks)
+            def _():
+                fetch(f + 1).start()
+
         contract = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
         # the block is multiplied in the rows' dtype (a float32 test model over
         # the bf16 bank widens ONE block in VMEM; bf16 rows: no cast at all)
         out_ref[...] = lax.dot_general(
-            lhs_ref[...], rhs_ref[...].astype(lhs_ref.dtype), contract, preferred_element_type=jnp.float32
+            lhs_ref[...], ring[lax.rem(f, _RING_SLOTS)].astype(lhs_ref.dtype), contract, preferred_element_type=jnp.float32
         ).astype(out_ref.dtype)
 
-    @pl.when(i >= used_ref[0])
+    @pl.when(i >= used)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -149,33 +219,34 @@ def _gmm_pallas(lhs, rhs, layer, group_sizes, tile_m: int, transpose_rhs: bool, 
     out = k if transpose_rhs else n
     n_tiles = rows // tile_m
     bn = _block_n(contract, out, rhs.dtype.itemsize)
-    tile_group, used = _tile_groups(group_sizes, n_tiles, tile_m)
 
-    def lhs_map(j, i, layer_ref, tile_group_ref, used_ref):
+    def lhs_map(j, i, layer_ref, tile_fetch_ref, fetch_group_ref, counts_ref):
         # an unused tile re-names the last used one: no new fetch
-        return jnp.minimum(i, jnp.maximum(used_ref[0] - 1, 0)), 0
+        return jnp.minimum(i, jnp.maximum(counts_ref[0] - 1, 0)), 0
 
-    if transpose_rhs:
-        rhs_spec = pl.BlockSpec((None, None, bn, n), lambda j, i, layer_ref, tg, used_ref: (layer_ref[0], tg[i], j, 0))
-    else:
-        rhs_spec = pl.BlockSpec((None, None, k, bn), lambda j, i, layer_ref, tg, used_ref: (layer_ref[0], tg[i], 0, j))
     return pl.pallas_call(
-        partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        partial(_gmm_kernel, transpose_rhs=transpose_rhs, n_cols=out // bn),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            # the row tiles run innermost and in order: consecutive tiles of
-            # one group name the same matrix block, which is then not fetched again
+            num_scalar_prefetch=4,
+            # the row tiles run innermost and in order: the tiles of one group
+            # are consecutive, and the ring's fetches are issued in that order
             grid=(out // bn, n_tiles),
-            in_specs=[pl.BlockSpec((tile_m, contract), lhs_map), rhs_spec],
-            out_specs=pl.BlockSpec((tile_m, bn), lambda j, i, layer_ref, tg, used_ref: (i, j)),
+            # the bank stays in HBM: the kernel copies a matrix block a group into the ring
+            in_specs=[pl.BlockSpec((tile_m, contract), lhs_map), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile_m, bn), lambda j, i, *_: (i, j)),
+            scratch_shapes=[
+                pltpu.VMEM((_RING_SLOTS, bn, n) if transpose_rhs else (_RING_SLOTS, k, bn), rhs.dtype),
+                pltpu.SemaphoreType.DMA((_RING_SLOTS,)),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((rows, out), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+            # the ring carries blocks from one grid step to the next, over both axes
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
         name="p2pfl_gmm",
-    )(layer.reshape(1), tile_group, used, lhs, rhs)
+    )(layer.reshape(1), *_fetch_plan(group_sizes, n_tiles, tile_m), lhs, rhs)
 
 
 @jax.custom_batching.custom_vmap

@@ -5,6 +5,8 @@ matmul, and "one dense layer, then a scanned run of expert layers" through
 
 import copy
 import dataclasses
+import hashlib
+import json
 import subprocess
 import sys
 from pathlib import Path
@@ -22,7 +24,8 @@ from p2pfl_tpu.models.transformer import (
     CausalLM, ExpertFFN, MLAttention, TransformerConfig, choose_experts, layer_runs, router_scores, routing_weights,
     tiny_transformer,
 )
-from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles
+from p2pfl_tpu.ops import grouped_matmul as gmm_ops
+from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles, tiles_and_fetches
 from p2pfl_tpu.parallel import SpmdLoraFederation
 from p2pfl_tpu.parallel.spmd import draw_node_perms
 
@@ -90,11 +93,20 @@ def _groups(case: str, m: int, g: int):
         return jnp.full((m,), 2, jnp.int32)
     if case == "empty_groups":
         return 2 * (jnp.arange(m, dtype=jnp.int32) % (g // 2))  # odd groups get no row
+    if case == "empty_runs":  # groups 0 | 2, 3 | 5 in a row without a row: two fetches, as many as the ring has slots
+        return jnp.where(jnp.arange(m) < 9, 1, 4).astype(jnp.int32)
+    if case == "first_and_last_full":  # several tiles each, a run of four empty groups between them
+        return jnp.where(jnp.arange(m) % 2 == 0, 0, g - 1).astype(jnp.int32)
+    if case == "one_full_among_single_tiles":  # more fetches than slots, one group many tiles
+        return jnp.where(jnp.arange(m) < 5, jnp.arange(m) + 1, 0).astype(jnp.int32)
     return jax.random.randint(jax.random.PRNGKey(3), (m,), 0, g)  # "ragged"
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("case", ["even", "one_group", "empty_groups", "ragged"])
+@pytest.mark.parametrize(
+    "case",
+    ["even", "one_group", "empty_groups", "ragged", "empty_runs", "first_and_last_full", "one_full_among_single_tiles"],
+)
 def test_grouped_matmul_matches_einsum_forward_and_input_cotangent(case, impl):
     """37 rows in tiles of 8 (no multiple), every routing shape: the product and
     its input cotangent against a per-row einsum; the bank gets no cotangent."""
@@ -147,11 +159,15 @@ def test_grouped_matmul_reads_the_named_layer_of_a_stack_of_banks(impl):
         grouped_matmul(rows, stack, layout.group_sizes, tile_m=tile, impl=impl)
 
 
-def test_grouped_matmul_under_vmap_keeps_each_elements_groups():
+@pytest.mark.parametrize("axis_size", [3, 1])
+def test_grouped_matmul_under_vmap_keeps_each_elements_groups(axis_size):
+    """Rows and group sizes mapped, the bank not — how every cell runs the
+    kernel (the node-chunk ``vmap``; axis size 1 takes ``pallas_call``'s own
+    batching rule, a larger one its loop over the elements)."""
     g, k, n, m, tile = 4, 16, 24, 20, 8
     rhs = jax.random.normal(jax.random.PRNGKey(0), (g, k, n), jnp.float32).astype(jnp.bfloat16)
-    xs = jax.random.normal(jax.random.PRNGKey(1), (3, m, k), jnp.float32)
-    groups = jax.random.randint(jax.random.PRNGKey(2), (3, m), 0, g)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (axis_size, m, k), jnp.float32)
+    groups = jax.random.randint(jax.random.PRNGKey(2), (axis_size, m), 0, g)
 
     def one(x, group_of, impl):
         layout = group_layout(group_of, g, tile)
@@ -161,6 +177,111 @@ def test_grouped_matmul_under_vmap_keeps_each_elements_groups():
     want = jnp.stack([one(x, gr, "xla") for x, gr in zip(xs, groups)])
     for impl in ("xla", "pallas"):
         np.testing.assert_allclose(jax.vmap(lambda x, gr: one(x, gr, impl))(xs, groups), want, rtol=1e-5, atol=1e-5)
+
+
+# the kernel's ring of matrix blocks, case by case: group sizes in tiles of 8 over
+# `n_row_tiles` tiles (so some tiles are past the used count)
+RING_TILE = 8
+RING_SIZES = {
+    "empty_runs_first_and_last": [0, 0, 9, 0, 0, 20, 0],  # the ring skips to the next group WITH rows
+    "one_group_every_tile": [0, 0, 0, 37, 0],  # one fetch, nothing to fetch ahead
+    "every_group_one_tile": [8, 1, 5, 8, 3, 7],  # a fetch every grid step
+    "as_many_groups_as_slots": [12, 30],
+    "more_groups_than_slots": [17, 3, 0, 9, 8, 1, 0, 26, 2],
+    "no_assignments": [0, 0, 0, 0],  # used = 0: every tile zeros, nothing fetched
+}
+RING_DIGESTS = json.loads((ROOT / "tests" / "fixtures" / "gmm_parent_outputs.json").read_text())["sha256"]
+
+
+def _ring_operands(sizes, face: bool, layers=None, lead=()):
+    """Small whole numbers in bf16 (every product and partial sum is exact in
+    float32, so an output's bits do not depend on the machine's dot), rows in
+    EVERY tile — the kernel masks nothing, a tile's rows times its group's matrix."""
+    g, k, n = len(sizes), 256, 256
+    rows = RING_TILE * n_row_tiles(sum(sizes), g, RING_TILE)
+    whole = lambda key, shape: jax.random.randint(jax.random.PRNGKey(key), shape, -2, 3).astype(jnp.bfloat16)  # noqa: E731
+    return whole(1, (*lead, rows, n if face else k)), whole(2, (g, k, n) if layers is None else (layers, g, k, n))
+
+
+def _product_by_tile(lhs, rhs, sizes, face: bool):
+    """numpy: tile after tile, each against its group's matrix; zeros past the used count."""
+    lhs, rhs = np.asarray(lhs, np.float32), np.asarray(rhs, np.float32)
+    out = np.zeros((lhs.shape[0], rhs.shape[1] if face else rhs.shape[2]), np.float32)
+    tile_group = [g for g, size in enumerate(sizes) for _ in range(-(-size // RING_TILE))]
+    for t, g in enumerate(tile_group):
+        at = slice(t * RING_TILE, (t + 1) * RING_TILE)
+        out[at] = lhs[at] @ (rhs[g].T if face else rhs[g])
+    return out
+
+
+def _digest(out) -> str:
+    out = np.asarray(out)
+    return hashlib.sha256(str((out.shape, out.dtype.name)).encode() + np.asarray(out, np.float32).tobytes()).hexdigest()
+
+
+@pytest.mark.parametrize("blocks", ["whole_matrix", "column_split"])
+@pytest.mark.parametrize("face", ["forward", "cotangent"])
+@pytest.mark.parametrize("case", list(RING_SIZES))
+def test_grouped_matmul_kernel_fetches_each_groups_matrix_for_its_tiles(case, face, blocks, monkeypatch):
+    """The interpreted kernel against a tile-by-tile numpy product, and its whole
+    output — padding rows and unused tiles too — bit-equal to what the parent's
+    step-ahead kernel gave on these operands (``tests/fixtures/gmm_parent_outputs.json``).
+    ``column_split``: a matrix block limit of 64 KB, so two column blocks a matrix
+    and the ring keyed by (column block, group)."""
+    sizes, transposed = RING_SIZES[case], face == "cotangent"
+    if blocks == "column_split":
+        monkeypatch.setattr(gmm_ops, "_RHS_BLOCK_BYTES", 256 * 128 * 2)
+        assert gmm_ops._block_n(256, 256, 2) == 128
+    lhs, rhs = _ring_operands(sizes, transposed)
+    out = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), tile_m=RING_TILE, transpose_rhs=transposed, impl="pallas")
+    assert out.dtype == lhs.dtype
+    np.testing.assert_array_equal(np.asarray(out, np.float32), _product_by_tile(lhs, rhs, sizes, transposed))
+    assert _digest(out) == RING_DIGESTS[f"{case}.{face}.{blocks}"]
+
+
+@pytest.mark.parametrize("face", ["forward", "cotangent"])
+def test_grouped_matmul_kernel_in_a_scan_reads_the_traced_layers_bank(face):
+    """A stacked bank with a traced ``layer`` inside ``lax.scan``: each step's
+    ring holds that layer's matrices (bit-equal to the parent's kernel)."""
+    sizes, transposed = RING_SIZES["more_groups_than_slots"], face == "cotangent"
+    lhs, stack = _ring_operands(sizes, transposed, layers=3)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    def step(_, layer):
+        out = grouped_matmul(lhs, stack, group_sizes, layer=layer, tile_m=RING_TILE, transpose_rhs=transposed, impl="pallas")
+        return None, out
+
+    _, outs = jax.jit(lambda: jax.lax.scan(step, None, jnp.arange(3, dtype=jnp.int32)))()
+    for layer in range(3):
+        np.testing.assert_array_equal(np.asarray(outs[layer], np.float32), _product_by_tile(lhs, stack[layer], sizes, transposed))
+    assert _digest(outs) == RING_DIGESTS[f"scan.{face}"]
+
+
+@pytest.mark.parametrize("axis_size", [1, 3])
+@pytest.mark.parametrize("face", ["forward", "cotangent"])
+def test_grouped_matmul_kernel_under_vmap_with_an_unmapped_bank(face, axis_size):
+    """Every cell's case: rows and group sizes mapped over the node chunk, the
+    bank shared. Each element has its own routing (bit-equal to the parent's kernel)."""
+    cases, transposed = ["more_groups_than_slots", "every_group_one_tile", "empty_runs_first_and_last"][:axis_size], face == "cotangent"
+    sizes = [(RING_SIZES[c] + [0] * 9)[:9] for c in cases]  # nine groups each; the first element's rows hold the others'
+    lhs, rhs = _ring_operands(sizes[0], transposed, lead=(axis_size,))
+    outs = jax.vmap(
+        lambda rows, group_sizes: grouped_matmul(rows, rhs, group_sizes, tile_m=RING_TILE, transpose_rhs=transposed, impl="pallas")
+    )(lhs, jnp.asarray(sizes, jnp.int32))
+    for out, rows, group_sizes in zip(outs, lhs, sizes):
+        np.testing.assert_array_equal(np.asarray(out, np.float32), _product_by_tile(rows, rhs, group_sizes, transposed))
+    assert _digest(outs) == RING_DIGESTS[f"vmap{axis_size}.{face}"]
+
+
+def test_tiles_and_fetches_counts_what_a_call_multiplies_and_fetches():
+    """The counter behind PERF.md's "tiles over fetches": a numpy count, group
+    by group, for every routing the ring's cases use and a drawn one."""
+    drawn = np.random.default_rng(0).integers(0, 700, 64).tolist()
+    for sizes in [*RING_SIZES.values(), drawn]:
+        for tile in (8, 128):
+            tiles = [-(-size // tile) for size in sizes]
+            got = tiles_and_fetches(jnp.asarray(sizes, jnp.int32), tile)
+            assert [int(v) for v in got] == [sum(tiles), sum(t > 0 for t in tiles)]
 
 
 # ---- the router ----------------------------------------------------------------
