@@ -1337,8 +1337,7 @@ def config6_heterogeneous_algorithms() -> None:
     scaffold_split["vs_matched_fedavg_x"] = round(
         scaffold_split["fused_ci_sec_per_round"] / times["fedavg_sgd"], 3
     )
-    scaffold_profile = fed.profile_round(epochs=1)
-    log(f"config6 scaffold split {scaffold_split} profile {scaffold_profile}")
+    log(f"config6 scaffold split {scaffold_split}")
     del fed
     jax.clear_caches()
 
@@ -1384,7 +1383,6 @@ def config6_heterogeneous_algorithms() -> None:
         # attribution (train / correction / aggregate), and the 5-local-
         # epoch drift regime where the correction earns its keep
         "scaffold_fast_path": scaffold_split,
-        "scaffold_profile": scaffold_profile,
         "local_epochs_5": {
             **ep5,
             "scaffold_vs_fedavg_sgd_final": round(

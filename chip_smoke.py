@@ -78,69 +78,33 @@ def check(cond: bool, msg: str) -> None:
         raise RuntimeError(f"chip_smoke check failed: {msg}")
 
 
-class CompileClock:
-    """Records JAX's compile-path events so a phase can split wall time into
-    compile and run without a second, warm call. ``backend`` is XLA/Mosaic
-    compilation or, on a persistent-cache hit, retrieval. A jit traced inside
-    another's trace reports both spans, so a phase's compile time is the
-    length of the UNION of its spans; the per-kind sums keep the nesting."""
-
-    KINDS = {
-        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-        "/jax/core/compile/backend_compile_duration": "backend_s",
-    }
-
-    def __init__(self) -> None:
-        self.spans: list[tuple[float, float, str]] = []  # (start, end, kind)
-        self.cache_events: list[str] = []
-        jax.monitoring.register_event_time_span_listener(self._on_span)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_span(self, event: str, start: float, end: float, **_kw) -> None:
-        kind = self.KINDS.get(event)
-        if kind is not None:
-            self.spans.append((start, end, kind))
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event.startswith("/jax/compilation_cache/cache_"):
-            self.cache_events.append(event.rsplit("/", 1)[1])
-
-    def mark(self) -> tuple[int, int]:
-        return len(self.spans), len(self.cache_events)
-
-    def since(self, mark: tuple[int, int]) -> dict:
-        spans, cache = self.spans[mark[0]:], self.cache_events[mark[1]:]
-        union, edge = 0.0, float("-inf")
-        for start, end, _ in sorted(spans):
-            union += max(0.0, end - max(start, edge))
-            edge = max(edge, end)
-        out = {"compile_s": union, "backend_n": sum(k == "backend_s" for *_, k in spans)}
-        for kind in self.KINDS.values():
-            out[kind] = sum(e - s for s, e, k in spans if k == kind)
-        out["cache_hits"] = cache.count("cache_hits")
-        out["cache_writes"] = cache.count("cache_misses")  # recorded when an entry is written
-        return {k: round(v, 2) for k, v in out.items()}
-
-
 def device_memory(key: str) -> list:
     """``memory_stats()[key]`` per device (None where the backend reports none)."""
     return [(d.memory_stats() or {}).get(key) for d in jax.devices()]
 
 
-def run_phase(name: str, clock: CompileClock, fn, **kwargs) -> dict:
+def run_phase(name: str, fn, **kwargs) -> dict:
+    """Run one phase and split its wall time into compile and run without a
+    second, warm call: compile is the union of the flight recorder's
+    ``"compile"`` spans inside the phase (``compile_cache``'s bridge, installed
+    by ``configure_compile_cache()``); a jit traced inside another's trace
+    reports both spans, so the per-kind sums keep the nesting. ``backend`` is
+    XLA/Mosaic compilation or, on a persistent-cache hit, retrieval;
+    ``cache_misses`` are entries written."""
+    from p2pfl_tpu.management.telemetry import telemetry
+
     say(f"== phase {name} ==")
-    mark = clock.mark()
-    t0 = time.monotonic()
+    t0_ns = time.monotonic_ns()
     out = fn(**kwargs)
-    wall = time.monotonic() - t0
-    split = clock.since(mark)
-    compile_s = split.pop("compile_s")
+    t1_ns = time.monotonic_ns()
+    split = telemetry.startup_report(since_ns=t0_ns, until_ns=t1_ns)["compile"]
+    wall, compile_s = (t1_ns - t0_ns) / 1e9, split.pop("all_s")
     out.update(
         wall_s=round(wall, 2),
-        compile_s=compile_s,
+        compile_s=round(compile_s, 2),
         run_s=round(wall - compile_s, 2),
-        compile_split=split,
+        # short_*: the dropped traces are counted since the process started, not within a phase
+        compile_split={k: round(v, 2) for k, v in split.items() if not k.startswith("short_")},
         peak_bytes_in_use=device_memory("peak_bytes_in_use"),
     )
     say(f"phase {name}: {json.dumps(out)}")
@@ -449,25 +413,24 @@ def main() -> int:
         f"{settings.Settings.WEIGHTS_PLANE!r})"
     )
 
-    clock = CompileClock()
     t0 = time.monotonic()
     report = {}
     report["kernels"] = run_phase(
-        "kernels", clock, phase_kernels, shapes=FLASH_SHAPES, interpret=False, scan_shapes=SCAN_SHAPES
+        "kernels", phase_kernels, shapes=FLASH_SHAPES, interpret=False, scan_shapes=SCAN_SHAPES
     )
     sources = {c["config_source"] for c in report["kernels"]["checks"]}
     check(sources == {"defaults"}, f"flash config from outside the checkout: {sources}")
     report["A"] = run_phase(
-        "A", clock, phase_spmd, data=FederatedDataset.mnist(**HARD_TASK),
+        "A", phase_spmd, data=FederatedDataset.mnist(**HARD_TASK),
         n_nodes=64, batch_size=64, chunk=5, min_acc=0.9,
     )
     report["B"] = run_phase(
-        "B", clock, phase_lora, widths=TINYLLAMA, seq_len=SEQ_LEN,
+        "B", phase_lora, widths=TINYLLAMA, seq_len=SEQ_LEN,
         n_nodes=max(4, n_dev), node_chunk=4, steps_per_round=4, n_test=8,
         interpret=False,
     )
     report["C"] = run_phase(
-        "C", clock, phase_nodes,
+        "C", phase_nodes,
         data=FederatedDataset.synthetic_mnist(n_train=4096, n_test=1024),
         rounds=2, batch_size=64, timeout=300.0,
     )

@@ -9,6 +9,7 @@ in TensorBoard/Perfetto.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading as _threading
 import time
 from typing import Iterator, Optional
@@ -228,13 +229,56 @@ def dispatch_span(site: str, node: str = "", **attrs) -> Iterator[None]:
     The count lands only when the body SUCCEEDS: a failed fused-round
     dispatch falls back to the staged path, and counting both would
     inflate dispatches_per_round with a program that never ran to
-    completion (the span still records, with the error in its attrs)."""
+    completion (the span still records, with the error in its attrs).
+
+    A dispatch under which a program reached the backend — the first call,
+    a changed static argument, a new shape — leaves with ``compiled=<n>`` in
+    its span's attrs (``compile_cache``'s bridge writes it on the span open
+    on the compiling thread) and counts once in ``("compile", "",
+    "<site>:compiled")``: a round that recompiled no longer looks like a
+    slow round."""
     from p2pfl_tpu.management.telemetry import telemetry
 
-    with telemetry.span(node, site, kind="dispatch", attrs=attrs or None):
+    with telemetry.span(node, site, kind="dispatch", attrs=attrs or None) as span:
         with host_annotation(site):
             yield
+    if span is not None and "compiled" in span.attrs:
+        telemetry.inc("compile", "", f"{site}:compiled")
     record_dispatch(site, node)
+
+
+def setup_span(name: str):
+    """Decorator: a federation's method as one phase of its start
+    (``fed_init``, ``data_put``, ``stage_state``, ``reset``) — a ``"setup"``
+    span in the process ring, ``fed=id(self)`` in its attrs (the round
+    dispatches carry the same, so a reader can tell one federation's start
+    from another's in the same process). No profiler annotation: the
+    benchmark counts device idle outside every ``p2pfl:*`` annotation, and
+    set-up is not a round's host work."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            from p2pfl_tpu.management.telemetry import PROCESS_NODE, telemetry
+
+            with telemetry.span(PROCESS_NODE, name, kind="setup", attrs={"fed": id(self)}):
+                return method(self, *args, **kwargs)
+
+        return run
+
+    return wrap
+
+
+def note_placed(nodes: int, *trees) -> None:
+    """Write on the set-up span open on this thread how many nodes' worth
+    of arrays it put on the device and their bytes — from ``nbytes``, so
+    nothing waits for the device."""
+    from p2pfl_tpu.management.telemetry import telemetry
+
+    span = telemetry.current_span()
+    if span is not None:
+        placed = sum(leaf.nbytes for leaf in jax.tree.leaves(trees))
+        span.attrs.update(nodes=nodes, bytes=span.attrs.get("bytes", 0) + placed)
 
 
 class Stopwatch:

@@ -41,8 +41,18 @@ with the wire context as parent. A frame without the field decodes exactly
 as before (old wire format stays valid).
 
 Setting ``P2PFL_TELEMETRY_DUMP=<dir>`` dumps ``trace.json`` + per-round
-``round_reports.json`` at process exit — CI uploads these as artifacts when
-a chaos run fails, so every failure is self-explaining.
+``round_reports.json`` + ``startup_report.json`` at process exit — CI uploads
+these as artifacts when a chaos run fails, so every failure is self-explaining.
+
+**What happens before the first round** is recorded on the same registry, in a
+ring of its own (node :data:`PROCESS_NODE`, so a long run's dispatch spans
+cannot push the start of the run out of the recorder): kind ``"setup"`` — a
+federation's ``fed_init`` ⊃ ``data_put``, ``stage_state``, and ``reset``
+(``profiling.setup_span``) — and kind ``"compile"`` — one span per JAX
+compile-path event, ``trace`` / ``lower`` / ``backend`` with the program's
+``fun_name``, committed by ``compile_cache``'s bridge under the span that was
+open on the compiling thread (:meth:`Telemetry.record_span`). Neither kind
+emits a profiler annotation. :meth:`Telemetry.startup_report` reduces them.
 """
 
 from __future__ import annotations
@@ -53,7 +63,8 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from pathlib import Path
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from p2pfl_tpu.settings import Settings
 
@@ -67,8 +78,16 @@ PLANES: Dict[str, int] = {
     "dispatch": 4,
     "retry": 5,
     "fault": 6,
+    "setup": 7,
+    "compile": 8,
 }
 _OTHER_PLANE = 9
+
+#: the ring (and Chrome-trace process) of set-up and compile spans
+PROCESS_NODE = "process"
+#: the kinds whose spans are the program's own work, as opposed to JAX's
+#: compile-path events committed under them
+_PROGRAM_KINDS = ("setup", "dispatch")
 
 #: the round FSM's top-level stage names — RoundReport attributes per-stage
 #: time from these only, so nested sub-spans (aggregation_wait, diffusion)
@@ -92,6 +111,35 @@ _proc_tag = f"{os.getpid():x}-{os.urandom(3).hex()}"
 
 def _new_id(prefix: str = "s") -> str:
     return f"{prefix}{_proc_tag}-{next(_seq):x}"
+
+
+
+def process_start_ns() -> Optional[int]:
+    """The kernel's record of when this process began, on
+    ``time.monotonic_ns``'s clock: ``/proc/self/stat`` field 22 (clock ticks
+    after boot) against ``CLOCK_BOOTTIME`` now. ``None`` where there is no
+    ``/proc`` to read."""
+    try:
+        ticks = int(Path("/proc/self/stat").read_text().rsplit(")", 1)[1].split()[19])
+        began_ns = ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        age_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - began_ns
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic_ns() - age_ns
+
+
+def union_ns(intervals: Iterable[Tuple[int, int]]) -> int:
+    """Length of the union of ``(start, end)`` intervals: nested and
+    overlapping spans (a jit traced inside another's trace) count once."""
+    total, edge = 0, None
+    for start, end in sorted(intervals):
+        if edge is None or start > edge:
+            total += end - start
+            edge = end
+        elif end > edge:
+            total += end - edge
+            edge = end
+    return total
 
 
 class Span:
@@ -309,6 +357,9 @@ class Telemetry:
         self._hists: Dict[Tuple[str, str], LatencyHistogram] = {}
         # (node, name) → ValueHistogram (e.g. async staleness per merge)
         self._value_hists: Dict[Tuple[str, str], ValueHistogram] = {}
+        # (monotonic_ns, time_ns) pairs: spans are on the monotonic clock, a
+        # profiler capture on the realtime clock (:meth:`anchor_clock`)
+        self.clock_anchors: List[Tuple[int, int]] = []
         self._tls = threading.local()
 
     # ---- span API ----
@@ -363,18 +414,7 @@ class Telemetry:
         until exit) — or a no-op handle when telemetry is off."""
         if not self.enabled():
             return _NOOP
-        parent_id: Optional[str] = None
-        if parent is not None:
-            tid = parent[0]
-            parent_id = parent[1]
-        else:
-            stack = self._stack()
-            if stack:
-                top = stack[-1]
-                tid = top.trace_id
-                parent_id = top.span_id
-            else:
-                tid = _new_id("t")
+        tid, parent_id = parent if parent is not None else self._parent_ctx()
         if trace_id is not None:
             tid = trace_id
         return _SpanHandle(self, Span(node, name, kind, tid, parent_id, attrs))
@@ -392,22 +432,58 @@ class Telemetry:
         up on that edge's timeline."""
         if not self.enabled():
             return
-        stack = self._stack()
-        if stack:
-            top = stack[-1]
-            tid, parent_id = top.trace_id, top.span_id
-        else:
-            tid, parent_id = _new_id("t"), None
-        span = Span(node, name, kind, tid, parent_id, attrs)
+        span = Span(node, name, kind, *self._parent_ctx(), attrs)
         self._ring(node).append(span)
+
+    def record_span(
+        self,
+        node: str,
+        name: str,
+        kind: str,
+        t0_ns: int,
+        t1_ns: int,
+        attrs: Optional[dict] = None,
+    ) -> Optional[Span]:
+        """Commit a span that has already ended — one whose start is known
+        only at its end, as JAX reports a compilation — under this thread's
+        current span. ``t0_ns`` / ``t1_ns`` are on ``time.monotonic_ns``'s
+        clock; a start converted from another clock is held inside the
+        parent's interval. Returns the span, or ``None`` with telemetry off."""
+        if not self.enabled():
+            return None
+        span = Span(node, name, kind, *self._parent_ctx(), attrs)
+        top = self.current_span()
+        span.t0_ns = t0_ns if top is None else max(t0_ns, top.t0_ns)
+        span.t1_ns = max(t1_ns, span.t0_ns)
+        self._commit(span)
+        return span
+
+    def current_span(self) -> Optional[Span]:
+        """The calling thread's innermost open span (its ``attrs`` may be
+        added to until it exits), or ``None``."""
+        stack = getattr(self._tls, "stack", None)
+        return stack[-1] if stack else None
+
+    def _parent_ctx(self) -> Tuple[str, Optional[str]]:
+        """``(trace_id, parent_span_id)`` for a span opened now on this
+        thread: the current span's, or a fresh trace with no parent."""
+        top = self.current_span()
+        return (top.trace_id, top.span_id) if top is not None else (_new_id("t"), None)
 
     def current_ctx(self) -> Optional[TraceCtx]:
         """The calling thread's active ``(trace_id, span_id)`` — what
         ``build_msg``/``build_weights`` stamp onto outgoing envelopes."""
-        stack = getattr(self._tls, "stack", None)
-        if stack:
-            return stack[-1].ctx
-        return None
+        top = self.current_span()
+        return top.ctx if top is not None else None
+
+    def anchor_clock(self) -> Tuple[int, int]:
+        """Record and return one ``(monotonic_ns, time_ns)`` pair, read back
+        to back: what lays a flight record (monotonic) on a profiler capture
+        (realtime). Taken when the compile bridge is installed and at every
+        :meth:`export_chrome_trace`."""
+        pair = (time.monotonic_ns(), time.time_ns())
+        self.clock_anchors.append(pair)
+        return pair
 
     def spans(self, node: Optional[str] = None) -> List[Span]:
         """Snapshot of the recorded spans (all nodes, or one)."""
@@ -570,7 +646,13 @@ class Telemetry:
                 base["ph"] = "i"
                 base["s"] = "t"  # thread-scoped instant
             events.append(base)
-        doc = {"traceEvents": events, "displayTimeUnit": "ms"}
+        # timestamps are monotonic; the anchors say what realtime that was
+        self.anchor_clock()
+        doc = {
+            "traceEvents": events,
+            "displayTimeUnit": "ms",
+            "otherData": {"clock_anchors_mono_ns_time_ns": [list(pair) for pair in self.clock_anchors]},
+        }
         if path is not None:
             with open(path, "w") as f:
                 json.dump(doc, f)
@@ -713,6 +795,133 @@ class Telemetry:
         return sorted(seen, key=lambda er: (er[0] or "", er[1]))
 
 
+    # ---- what happened before the first round ----
+
+    def startup_report(
+        self, since_ns: Optional[int] = None, until_ns: Optional[int] = None
+    ) -> dict:
+        """The set-up, dispatch and compile spans that lie in ``[since_ns,
+        until_ns]`` (monotonic ns; spans across an edge are cut at it),
+        reduced. Seconds are floats, unrounded.
+
+        - ``process_start_ns``: :func:`process_start_ns`;
+        - ``phases``: every set-up span, and every dispatch span under which
+          something compiled, in start order — ``duration_s``, ``self_s``
+          (duration less what its child spans cover) and ``compile_s`` (the
+          union of the compile spans anywhere under it);
+        - ``programs``: one row a ``fun_name`` — times traced and brought to
+          the backend, ``trace_s`` / ``lower_s`` / ``backend_s`` summed, the
+          persistent cache's answers, the spans it compiled under — costliest
+          first;
+        - ``compile``: the unions — ``all_s``, ``in_program_s`` (under a
+          program span) and ``outside_s`` (under none: a jit the caller made
+          outside every span) — beside the per-kind sums and counts, and the
+          traces the bridge dropped as too short to keep (``short_traces_n``,
+          ``short_trace_s``: counted since the process started, not cut at the
+          edges — what the unions can be short of);
+        - ``spans``: the rows themselves, for a reader that wants another cut.
+        """
+        lo = since_ns if since_ns is not None else -(1 << 62)
+        hi = until_ns if until_ns is not None else 1 << 62
+        rows = []
+        for sp in self.spans():
+            if sp.kind != "compile" and sp.kind not in _PROGRAM_KINDS:
+                continue
+            t0, t1 = max(sp.t0_ns, lo), min(sp.t1_ns, hi)
+            if t1 < t0:
+                continue
+            rows.append({
+                "id": sp.span_id, "parent": sp.parent_id, "kind": sp.kind, "name": sp.name,
+                "t0_ns": t0, "t1_ns": t1, "attrs": dict(sp.attrs),
+            })
+        by_id = {r["id"]: r for r in rows}
+        children: Dict[str, list] = {}
+        under: Dict[str, list] = {}  # program span → compile rows anywhere below it
+        compiles = [r for r in rows if r["kind"] == "compile"]
+        for r in rows:
+            if r["parent"] in by_id:
+                children.setdefault(r["parent"], []).append(r)
+        for r in compiles:
+            up = r["parent"]
+            while up in by_id:
+                under.setdefault(up, []).append(r)
+                up = by_id[up]["parent"]
+
+        def within(row: dict, others: list) -> int:
+            return union_ns(
+                (max(o["t0_ns"], row["t0_ns"]), min(o["t1_ns"], row["t1_ns"]))
+                for o in others
+                if o["t1_ns"] > row["t0_ns"] and o["t0_ns"] < row["t1_ns"]
+            )
+
+        def name_of(span_id: Optional[str]) -> Optional[str]:
+            return by_id[span_id]["name"] if span_id in by_id else None
+
+        phases = []
+        for r in rows:
+            if r["kind"] == "setup" or (r["kind"] == "dispatch" and "compiled" in r["attrs"]):
+                duration = r["t1_ns"] - r["t0_ns"]
+                phases.append({
+                    "name": r["name"], "kind": r["kind"], "id": r["id"], "parent": name_of(r["parent"]),
+                    "t0_ns": r["t0_ns"], "duration_s": duration / 1e9,
+                    "self_s": (duration - within(r, children.get(r["id"], []))) / 1e9,
+                    "compile_s": within(r, under.get(r["id"], [])) / 1e9,
+                    "attrs": r["attrs"],
+                })
+
+        table: Dict[str, dict] = {}
+        for r in compiles:
+            row = table.setdefault(str(r["attrs"].get("fun_name", "?")), {
+                "traced_n": 0, "backend_n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                "cache": set(), "parents": set(),
+            })
+            row[r["name"] + "_s"] += (r["t1_ns"] - r["t0_ns"]) / 1e9
+            row["parents"].add(name_of(r["parent"]) or "")
+            if r["name"] == "trace":
+                row["traced_n"] += 1
+            elif r["name"] == "backend":
+                row["backend_n"] += 1
+                row["cache"].add(r["attrs"].get("cache", "off"))
+        programs = [
+            {
+                "fun_name": fun, **row, "cache": "+".join(sorted(row["cache"])),
+                "parents": sorted(row["parents"]),
+                "total_s": row["trace_s"] + row["lower_s"] + row["backend_s"],
+            }
+            for fun, row in table.items()
+        ]
+        programs.sort(key=lambda row: row["total_s"], reverse=True)
+
+        def spans_of(picked: list) -> list:
+            return [(r["t0_ns"], r["t1_ns"]) for r in picked]
+
+        inside = [r for r in compiles if r["parent"] is not None]
+        backends = [r for r in compiles if r["name"] == "backend"]
+        summary = {
+            "all_s": union_ns(spans_of(compiles)) / 1e9,
+            "in_program_s": union_ns(spans_of(inside)) / 1e9,
+            "outside_s": union_ns(spans_of([r for r in compiles if r["parent"] is None])) / 1e9,
+            "backend_n": len(backends),
+            "cache_hits": sum(r["attrs"].get("cache") == "hit" for r in backends),
+            "cache_misses": sum(r["attrs"].get("cache") == "miss" for r in backends),
+        }
+        dropped = self.counters("compile", "")
+        summary["short_traces_n"] = int(dropped.get("short_traces", 0))
+        summary["short_trace_s"] = float(dropped.get("short_trace_s", 0.0))
+        for kind in ("trace", "lower", "backend"):
+            summary[kind + "_s"] = sum(r["t1_ns"] - r["t0_ns"] for r in compiles if r["name"] == kind) / 1e9
+        return {
+            "process_start_ns": process_start_ns(),
+            "since_ns": since_ns,
+            "until_ns": until_ns,
+            "clock_anchors": [list(pair) for pair in self.clock_anchors],
+            "phases": phases,
+            "programs": programs,
+            "compile": summary,
+            "spans": rows,
+        }
+
+
 class RoundReport:
     """One round's wall-clock attribution (see :meth:`Telemetry.round_report`)."""
 
@@ -825,7 +1034,8 @@ telemetry = Telemetry()
 
 
 def dump_flight_record(out_dir: str) -> List[str]:
-    """Write ``trace.json`` + ``round_reports.json`` under ``out_dir``."""
+    """Write ``trace.json``, ``round_reports.json`` and
+    ``startup_report.json`` under ``out_dir``."""
     os.makedirs(out_dir, exist_ok=True)
     paths = []
     trace_path = os.path.join(out_dir, "trace.json")
@@ -839,6 +1049,10 @@ def dump_flight_record(out_dir: str) -> List[str]:
     with open(report_path, "w") as f:
         json.dump(reports, f, indent=1)
     paths.append(report_path)
+    startup_path = os.path.join(out_dir, "startup_report.json")
+    with open(startup_path, "w") as f:
+        json.dump(telemetry.startup_report(), f, indent=1)
+    paths.append(startup_path)
     # async runs: the per-node staleness distribution (empty dict on sync
     # runs — written only when something was observed, keeping sync-mode
     # artifacts byte-stable)

@@ -43,7 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import _loss, _prox_term, adam, ce_eval, sgd
-from p2pfl_tpu.management.profiling import dispatch_span, host_annotation, scope
+from p2pfl_tpu.management.profiling import dispatch_span, host_annotation, note_placed, scope, setup_span
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.settings import Settings
 
@@ -775,6 +775,7 @@ class SpmdFederation:
     (``TransformerConfig.remat`` / ``remat_policy``).
     """
 
+    @setup_span("fed_init")
     def __init__(
         self,
         model: FlaxModel,
@@ -891,8 +892,8 @@ class SpmdFederation:
         self.active_mask = np.ones(self.n, dtype=np.float32)
         self.round = 0
         self.history: list[dict] = []
-        self.last_profile: Optional[dict] = None
 
+    @setup_span("reset")
     def reset(self, seed: int = 0) -> None:
         """Back to round 0 with fresh state, keeping mesh/data/executables.
 
@@ -907,6 +908,7 @@ class SpmdFederation:
         self.history = []
         self._stage_state()
 
+    @setup_span("stage_state")
     def _stage_state(self) -> None:
         # jitted with out_shardings: the broadcast + init run ON DEVICE and
         # land directly in the mesh layout (a host-side device_put would
@@ -945,6 +947,7 @@ class SpmdFederation:
             )
             self.opt_m = zeros
             self.opt_v = jax.tree.map(jnp.copy, zeros)
+        note_placed(n, self.params, self.opt_state)
 
     def _default_mesh(self) -> Mesh:
         from p2pfl_tpu.parallel.mesh import federation_mesh
@@ -955,6 +958,7 @@ class SpmdFederation:
             slots -= 1
         return federation_mesh(n_nodes=slots, devices=devices[:slots])
 
+    @setup_span("data_put")
     def _stage_data(self) -> None:
         # node shards are padded (wrap-around) to a common static length so
         # they stack into one [N, S, ...] array, but each node's per-round
@@ -975,6 +979,7 @@ class SpmdFederation:
         self._sizes = staged["sizes"]
         self._tr_size = len(staged["x"][0])
         self._nb = staged["nb"]
+        note_placed(self.n, self.x_all, self.y_all, self.x_test, self.y_test, self._samples)
 
     # ---- election (host control plane — reference vote semantics) ----
 
@@ -1066,16 +1071,12 @@ class SpmdFederation:
             )
         return jax.device_put(jax.random.split(root, self.n), self._shard)
 
-    def run_round(self, epochs: int = 1, eval: bool = False, profile: bool = False) -> dict:  # noqa: A002
+    def run_round(self, epochs: int = 1, eval: bool = False) -> dict:  # noqa: A002
         if self._vote and (self.round == 0 or Settings.VOTE_EVERY_ROUND):
             self.train_mask = self.elect_train_set()
-        if profile:
-            # per-phase breakdown of the round about to run (train /
-            # correction / aggregate) — stashed on self.last_profile
-            self.profile_round(epochs)
         perm, mask, sel_idx = self._round_inputs(epochs)
         try:
-            with dispatch_span("spmd_round", "spmd", nodes=self.n, epochs=epochs):
+            with dispatch_span("spmd_round", "spmd", nodes=self.n, epochs=epochs, fed=id(self)):
                 result = spmd_round(
                     self.params,
                     self.opt_state,
@@ -1118,116 +1119,6 @@ class SpmdFederation:
             entry["test_acc"] = result[-1]  # acc is last (scaffold adds outputs)
         self.history.append(entry)
         return entry
-
-    def profile_round(self, epochs: int = 1, iters: int = 3) -> dict:
-        """Per-phase wall-clock attribution of one round (no state change).
-
-        Times three compiled programs on the federation's real inputs:
-
-        - ``train_s`` — the matched PLAIN round (scaffold math stripped,
-          same ``tx``/mask/perm shapes): local epochs + aggregate + diffuse;
-        - ``total_s`` — the round as configured (with SCAFFOLD correction
-          and variate updates when ``scaffold=True``);
-        - ``aggregate_s`` — the masked weighted reduce + diffusion alone;
-        - ``correction_s`` — the residual ``total − train``: what the
-          per-step correction adds + both variate updates cost together.
-
-        Donated inputs are re-copied per timed call (copies materialized
-        BEFORE the timer starts), so profiling consumes nothing the
-        federation still needs. Medians over ``iters`` calls. Sets
-        ``self.last_profile`` and returns it.
-        """
-        rng_state = self._rng.bit_generator.state
-        try:
-            profile = self._profile_round_body(epochs, iters)
-        finally:
-            # restored on EVERY exit, including a failed probe dispatch:
-            # profiling must never perturb the federation's round stream
-            # (the pre-fix path skipped the restore when a probe raised,
-            # silently desynchronizing every later perm draw)
-            self._rng.bit_generator.state = rng_state
-        self.last_profile = profile
-        return profile
-
-    def _profile_round_body(self, epochs: int, iters: int) -> dict:
-        import time
-
-        from p2pfl_tpu.management.profiling import force_execution
-
-        perm, mask, sel_idx = self._round_inputs(epochs)
-        common = dict(
-            module=self.module, tx=self.tx, agg=self.aggregator, trim=self.trim,
-            clip_tau=self.clip_tau, out_sharding=self._shard,
-            keep_opt_state=self.keep_opt_state,
-        )
-
-        def timed(algo_kw: dict) -> float:
-            def stage_inputs():
-                copies = {
-                    k: jax.tree.map(jnp.copy, v)
-                    for k, v in algo_kw.items()
-                    if k in ("c_global", "c_local", "opt_m", "opt_v") and v is not None
-                }
-                p = jax.tree.map(jnp.copy, self.params)
-                o = jax.tree.map(jnp.copy, self.opt_state)
-                force_execution((p, o, copies))
-                return p, o, {**algo_kw, **copies}
-
-            def call(p, o, kw):
-                return spmd_round(
-                    p, o, self.x_all, self.y_all, perm, mask, self._samples,
-                    sel_idx, dp_keys=self._dp_round_keys(), **common, **kw,
-                )
-
-            force_execution(call(*stage_inputs()))  # compile + warm
-            ts = []
-            for _ in range(iters):
-                p, o, kw = stage_inputs()
-                t0 = time.monotonic()
-                force_execution(call(p, o, kw))
-                ts.append(time.monotonic() - t0)
-            return sorted(ts)[len(ts) // 2]
-
-        full_kw = self._algo_kwargs(self._server_t + 1 if self.server_opt else 0)
-        plain_kw = {
-            **full_kw,
-            "scaffold": False, "c_global": None, "c_local": None,
-            "server_opt": "", "opt_m": None, "opt_v": None, "opt_t": None,
-        }
-        t_total = timed(full_kw)
-        t_train = timed(plain_kw) if (self.scaffold or self.server_opt) else t_total
-
-        @partial(jax.jit, static_argnames=("agg", "trim"))
-        def agg_probe(stacked, mask_, weights, sel, *, agg, trim):
-            agg_p = _aggregate(stacked, mask_, weights, sel, agg, trim)
-            return jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (mask_.shape[0], *a.shape)), agg_p
-            )
-
-        def agg_call():
-            # "clip" needs a center operand the probe doesn't carry; its
-            # reduce+diffuse cost is the fedavg probe's to first order
-            probe_agg = "fedavg" if self.aggregator == "clip" else self.aggregator
-            return agg_probe(
-                self.params, mask, self._samples, sel_idx,
-                agg=probe_agg, trim=self.trim,
-            )
-
-        force_execution(agg_call())
-        ts = []
-        for _ in range(iters):
-            t0 = time.monotonic()
-            force_execution(agg_call())
-            ts.append(time.monotonic() - t0)
-        t_agg = sorted(ts)[len(ts) // 2]
-
-        return {
-            "total_s": round(t_total, 4),
-            "train_s": round(t_train, 4),
-            "correction_s": round(max(t_total - t_train, 0.0), 4),
-            "aggregate_s": round(t_agg, 4),
-            "overhead_x": round(t_total / t_train, 2) if t_train > 0 else None,
-        }
 
     def _recover_donated_state(self) -> None:
         """Failed round dispatch: drop and rebuild any consumed donated state.
@@ -1302,7 +1193,7 @@ class SpmdFederation:
         """
         perms, mask, sel_idx = self._fused_inputs(rounds, epochs)
         try:
-            with dispatch_span("spmd_rounds_fused", "spmd", nodes=self.n, rounds=rounds):
+            with dispatch_span("spmd_rounds_fused", "spmd", nodes=self.n, rounds=rounds, fed=id(self)):
                 result = spmd_rounds_fused(
                     self.params, self.opt_state, self.x_all, self.y_all, perms, mask,
                     self._samples, sel_idx,

@@ -23,7 +23,7 @@ from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.learner import adam, ce_eval
 from p2pfl_tpu.learning.lora import lora_train_epoch as _node_lora_epoch  # noqa: F401 (shared math)
 from p2pfl_tpu.learning.lora import _lm_forward, _lm_loss, merge_params, split_lora
-from p2pfl_tpu.management.profiling import dispatch_span, scope
+from p2pfl_tpu.management.profiling import dispatch_span, note_placed, scope, setup_span
 from p2pfl_tpu.models.base import FlaxModel
 from p2pfl_tpu.parallel.spmd import SpmdFederation, _aggregate
 
@@ -267,6 +267,7 @@ class SpmdLoraFederation(SpmdFederation):
         super().__init__(model, datasets, mesh=mesh, **kwargs)
 
     # node-stacked state = adapters only; base placed separately
+    @setup_span("stage_state")
     def _stage_state(self) -> None:
         n, keep = self.n, self.keep_opt_state
 
@@ -282,6 +283,7 @@ class SpmdLoraFederation(SpmdFederation):
             self.base = shard_transformer(self.mesh, self._base_template)
         else:
             self.base = jax.device_put(self._base_template, self._repl)
+        note_placed(n, self.params, self.opt_state, self.base)
 
     def _round_call(self, epochs: int) -> tuple[tuple, dict]:
         """(args, static kwargs) of the :func:`spmd_lora_round` dispatch for
@@ -304,7 +306,7 @@ class SpmdLoraFederation(SpmdFederation):
         if self._vote and (self.round == 0 or Settings.VOTE_EVERY_ROUND):
             self.train_mask = self.elect_train_set()
         args, statics = self._round_call(epochs)
-        with dispatch_span("spmd_lora_round", "spmd", nodes=self.n, epochs=epochs):
+        with dispatch_span("spmd_lora_round", "spmd", nodes=self.n, epochs=epochs, fed=id(self)):
             self.params, self.opt_state, loss, stats = spmd_lora_round(*args, **statics)
         self.round += 1
         # ``stats``: device scalars the model sowed, averaged over the round's
@@ -332,7 +334,7 @@ class SpmdLoraFederation(SpmdFederation):
         if eval:
             raise ValueError("SpmdLoraFederation.run_fused has no fused eval; call evaluate()")
         perms, mask, sel_idx = self._fused_inputs(rounds, epochs)
-        with dispatch_span("spmd_lora_rounds_fused", "spmd", nodes=self.n, rounds=rounds):
+        with dispatch_span("spmd_lora_rounds_fused", "spmd", nodes=self.n, rounds=rounds, fed=id(self)):
             self.params, self.opt_state, losses = spmd_lora_rounds_fused(
                 self.params, self.opt_state, self.base, self.x_all, self.y_all,
                 perms, mask, self._samples, sel_idx,

@@ -167,14 +167,14 @@ def test_chip_smoke_refuses_cpu(capsys, monkeypatch):
 def test_chip_smoke_phases_at_toy_size():
     """The smoke's control flow on the CPU mesh: tiny widths, interpreted
     kernels, same phase functions and checks ``main()`` runs on the chip."""
-    clock = chip_smoke.CompileClock()
+    compile_cache.install_compile_bridge()  # what configure_compile_cache() does in main(), without its cache
     flash = chip_smoke.check_flash(1, 256, 2, 32, interpret=True)
     assert flash["config_source"] == "defaults" and flash["mosaic_calls"] == 0
     scan = chip_smoke.check_scan(1, 600, 256, 4, interpret=True)
     assert scan["mosaic_calls"] == 0 and set(scan["rel_err"]) >= {"y", "dA", "dB", "dz", "xla_vs_loop"}
 
     a = chip_smoke.run_phase(
-        "A", clock, chip_smoke.phase_spmd,
+        "A", chip_smoke.phase_spmd,
         data=FederatedDataset.synthetic_mnist(n_train=2048, n_test=256),
         n_nodes=8, batch_size=32, chunk=2, min_acc=0.5,
     )
@@ -182,7 +182,7 @@ def test_chip_smoke_phases_at_toy_size():
     assert a["compile_s"] > 0 and a["run_s"] > 0
 
     b = chip_smoke.run_phase(
-        "B", clock, chip_smoke.phase_lora,
+        "B", chip_smoke.phase_lora,
         widths=dict(vocab_size=128, dim=64, n_heads=2, n_kv_heads=1, n_layers=2, ffn_hidden=128),
         seq_len=128, n_nodes=8, node_chunk=4, steps_per_round=2, n_test=8,
         interpret=True,
@@ -190,7 +190,7 @@ def test_chip_smoke_phases_at_toy_size():
     assert b["mosaic_calls_in_round"] == 0 and b["train_loss"][2] < b["train_loss"][0]
 
     c = chip_smoke.run_phase(
-        "C", clock, chip_smoke.phase_nodes,
+        "C", chip_smoke.phase_nodes,
         data=FederatedDataset.synthetic_mnist(n_train=512, n_test=128),
         rounds=2, batch_size=64, timeout=90.0,
     )

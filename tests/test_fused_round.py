@@ -299,23 +299,6 @@ class TestFailureHygiene:
         finally:
             node.stop()
 
-    def test_spmd_profile_round_restores_rng_on_failure(self, monkeypatch):
-        from p2pfl_tpu.parallel.spmd import SpmdFederation
-
-        full = FederatedDataset.synthetic_mnist(n_train=256, n_test=64)
-        fed = SpmdFederation.from_dataset(
-            mlp(), full, n_nodes=2, batch_size=64, vote=False, seed=5
-        )
-        rng_before = fed._rng.bit_generator.state
-        monkeypatch.setattr(
-            fed, "_profile_round_body", lambda *a, **k: (_ for _ in ()).throw(
-                RuntimeError("probe died")
-            )
-        )
-        with pytest.raises(RuntimeError):
-            fed.profile_round()
-        assert fed._rng.bit_generator.state == rng_before
-
     def test_spmd_failed_round_rebuilds_donated_state(self, monkeypatch):
         import p2pfl_tpu.parallel.spmd as spmd
         from p2pfl_tpu.parallel.spmd import SpmdFederation

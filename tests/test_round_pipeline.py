@@ -99,46 +99,6 @@ def test_scaffold_fused_ci_partial_train_set_keeps_zero_variates():
         Settings.TRAIN_SET_SIZE = old
 
 
-def test_profile_round_breakdown_keys_and_state():
-    """profile_round attributes the round per phase and leaves the
-    federation's round state (round counter, rng stream, params) intact —
-    the next round must be byte-for-byte what it would have been."""
-    data = FederatedDataset.synthetic_mnist(n_train=512, n_test=128)
-    fed = _scaffold_fed(data)
-    fed.run_round(epochs=1)
-
-    twin = _scaffold_fed(data)
-    twin.run_round(epochs=1)
-
-    prof = fed.profile_round(epochs=1, iters=1)
-    assert prof is fed.last_profile
-    for key in ("total_s", "train_s", "correction_s", "aggregate_s"):
-        assert key in prof and prof[key] >= 0.0, prof
-    # nominally >= 1.0 (per-phase probing re-runs the round's pieces), but
-    # both sides are single-shot wall-clock measurements on a shared CPU —
-    # scheduler noise has been observed to dip the ratio to ~0.88 in a
-    # loaded full-suite run, so assert with a noise margin: the real
-    # contract is "profiling is not pathologically slower or faster"
-    assert prof["overhead_x"] is None or prof["overhead_x"] >= 0.6
-
-    # profiling consumed nothing: the profiled fed and its unprofiled twin
-    # produce identical next rounds (same rng draws, same params)
-    e1 = fed.run_round(epochs=1)
-    e2 = twin.run_round(epochs=1)
-    assert float(e1["train_loss"]) == float(e2["train_loss"])
-    assert _max_diff(fed.params, twin.params) == 0.0
-
-
-def test_run_round_profile_flag_stashes_breakdown():
-    data = FederatedDataset.synthetic_mnist(n_train=256, n_test=64)
-    fed = SpmdFederation.from_dataset(
-        mlp(), data, n_nodes=2, batch_size=64, vote=False, seed=3
-    )
-    assert fed.last_profile is None
-    fed.run_round(epochs=1, profile=True)
-    assert set(fed.last_profile) >= {"total_s", "train_s", "correction_s", "aggregate_s"}
-
-
 def test_spmd_rejects_secure_aggregation():
     """Design pin (docs/design.md, "Secure aggregation and the SPMD
     runtime"): one mesh is one trust domain — SECURE_AGGREGATION is a
