@@ -54,10 +54,10 @@ DEVICE_SCOPES = (
     "short_conv",  # ShortConvMixer: both gate products and the taps, between its two projections
     "qk_norm",  # Attention: the per-head norms of q and k before RoPE
     "mla",  # MLAttention: the glue between its five projections (split, RoPE, concatenate, broadcast)
-    "moe_route",  # ExpertFFN: router, top-k, sort indices, group sizes
-    "moe_experts",  # ExpertFFN: gather into sorted order, both grouped matmuls, the activation
+    "moe_route",  # ExpertFFN: router, top-k, the counted layout (each assignment's row, group sizes)
+    "moe_experts",  # ExpertFFN: gather into expert rows (and its cotangent: k slabs summed), both grouped matmuls, the activation
     "moe_gmm",  # ops/grouped_matmul: the product alone (the Mosaic call p2pfl_gmm on a TPU), inside moe_experts
-    "moe_combine",  # ExpertFFN: unsort, weigh, sum over the k, add the shared expert's output
+    "moe_combine",  # ExpertFFN: gather the k rows of each token back, weigh, add the k slabs, add the shared expert's output
     "head",  # CausalLM's logits, and both rules of ops/head_loss (the training loss: logits by block, statistics, dX)
 )
 

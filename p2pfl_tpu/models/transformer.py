@@ -548,24 +548,68 @@ def routing_weights(s, chosen, scale: float):
     return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scale
 
 
+@jax.custom_batching.custom_vmap
+def _rows_at(a, index):
+    """``a[index]`` along the first axis for an ``index`` the layout made — in
+    range by construction, and said so (``jnp.take`` checks every index and
+    selects a fill value over the whole result)."""
+    return a.at[index].get(mode="promise_in_bounds")
+
+
+@_rows_at.def_vmap
+def _rows_at_vmap(axis_size, in_batched, a, index):
+    """The whole batch as ONE gather over ``[B · N, …]``, element ``b``'s rows
+    ``b · N`` further down. ``gather``'s own batching rule keeps the batch as a
+    dimension of the operand, and on the chip that is another, slower gather:
+    in both expert cells, under the node-chunk ``vmap``, a ``[20352, 2048]``
+    bfloat16 result took 0.47-0.59 ms that way and takes 0.13 ms flat (PERF.md,
+    PR 36)."""
+    a_batched, index_batched = in_batched
+    if not a_batched:
+        return _rows_at(a, index), True
+    if not index_batched:
+        index = jnp.broadcast_to(index, (axis_size, *index.shape))
+    n = a.shape[1]
+    first = (n * jnp.arange(axis_size, dtype=index.dtype)).reshape((axis_size,) + (1,) * (index.ndim - 1))
+    return _rows_at(a.reshape(axis_size * n, *a.shape[2:]), index + first), True
+
+
+def _token_rows(x, token_of_row):
+    """``x[token_of_row]``, zeros where ``token_of_row`` is ``S`` (a padding
+    row): the row appended here."""
+    return _rows_at(jnp.pad(x, ((0, 1), (0, 0))), token_of_row)
+
+
+def _slab_sum(rows, row_of_assignment, weights=None):
+    """``Σ_j weights[j] · rows[row_of_assignment[j]]`` in float32: ``k`` gathers
+    of an ``[S, D]`` slab each, added as they lie — no ``[S, k, D]`` array, whose
+    ``k`` would sit in the sublanes of a tile."""
+    total = None
+    for j in range(row_of_assignment.shape[0]):
+        slab = _rows_at(rows, row_of_assignment[j]).astype(jnp.float32)
+        if weights is not None:
+            slab = weights[j][:, None] * slab
+        total = slab if total is None else total + slab
+    return total
+
+
 @jax.custom_vjp
 def _to_expert_rows(x, token_of_row, row_of_assignment):
     """``[S, D]`` tokens -> ``[rows, D]`` in the grouped layout (padding rows
     zero). A gather forward AND backward: the cotangent of a token is the sum
-    of its ``k`` rows', read back through ``row_of_assignment``."""
+    of its ``k`` rows', read back through ``row_of_assignment`` (``[k, S]``,
+    assignment-major)."""
     del row_of_assignment
-    return jnp.take(x, token_of_row, axis=0, mode="fill", fill_value=0)
+    return _token_rows(x, token_of_row)
 
 
 def _to_expert_rows_fwd(x, token_of_row, row_of_assignment):
-    return _to_expert_rows(x, token_of_row, row_of_assignment), (row_of_assignment, x.shape[0])
+    return _to_expert_rows(x, token_of_row, row_of_assignment), row_of_assignment
 
 
-def _to_expert_rows_bwd(res, g):
-    row_of_assignment, s = res
+def _to_expert_rows_bwd(row_of_assignment, g):
     with scope("moe_experts"):
-        mine = jnp.take(g, row_of_assignment, axis=0).reshape(s, -1, g.shape[-1])
-        return jnp.sum(mine.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+        return _slab_sum(g, row_of_assignment).astype(g.dtype), None, None
 
 
 _to_expert_rows.defvjp(_to_expert_rows_fwd, _to_expert_rows_bwd)
@@ -573,13 +617,11 @@ _to_expert_rows.defvjp(_to_expert_rows_fwd, _to_expert_rows_bwd)
 
 @jax.custom_vjp
 def _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
-    """``y[s] = Σ_j weights[s, j] · rows[row of (s, j)]`` — float32 sum, result
-    in ``rows``' dtype. Gathers both ways: a row's cotangent is its token's,
-    times its weight (zero for padding rows)."""
+    """``y[s] = Σ_j weights[s, j] · rows[row_of_assignment[j, s]]`` — float32
+    sum, result in ``rows``' dtype. Gathers both ways: a row's cotangent is its
+    token's, times its weight (zero for padding rows)."""
     del token_of_row, assignment_of_row
-    s, k = weights.shape
-    mine = jnp.take(rows, row_of_assignment, axis=0).reshape(s, k, rows.shape[-1])
-    return jnp.sum(weights[..., None] * mine.astype(jnp.float32), axis=1).astype(rows.dtype)
+    return _slab_sum(rows, row_of_assignment, weights.T).astype(rows.dtype)
 
 
 def _from_expert_rows_fwd(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
@@ -588,14 +630,16 @@ def _from_expert_rows_fwd(rows, weights, row_of_assignment, token_of_row, assign
 
 
 def _from_expert_rows_bwd(res, g):
+    """``rows`` is read ONCE, in row order: a weight's cotangent
+    ``⟨rows[row of (s, j)], g[s]⟩`` is the row's own dot with its token's
+    cotangent — ``g_rows``, made for ``d_rows`` anyway — gathered as a scalar."""
     rows, weights, row_of_assignment, token_of_row, assignment_of_row = res
-    s, k = weights.shape
     with scope("moe_combine"):
-        mine = jnp.take(rows, row_of_assignment, axis=0).reshape(s, k, rows.shape[-1])
-        d_weights = jnp.sum(mine.astype(jnp.float32) * g.astype(jnp.float32)[:, None, :], axis=-1)
         weight_of_row = jnp.take(weights.reshape(-1), assignment_of_row, mode="fill", fill_value=0)
-        g_rows = jnp.take(g, token_of_row, axis=0, mode="fill", fill_value=0)
-        d_rows = (weight_of_row[:, None] * g_rows.astype(jnp.float32)).astype(rows.dtype)
+        g_rows = _token_rows(g, token_of_row).astype(jnp.float32)
+        d_rows = (weight_of_row[:, None] * g_rows).astype(rows.dtype)
+        dot_of_row = jnp.sum(rows.astype(jnp.float32) * g_rows, axis=-1)
+        d_weights = _rows_at(dot_of_row, row_of_assignment).T
     return d_rows, d_weights.astype(weights.dtype), None, None, None
 
 
@@ -608,9 +652,16 @@ class ExpertFFN(nn.Module):
     computed, at any imbalance — no capacity, no ``[S, E, C]`` tensor::
 
         s = router_scores(x);  experts = choose_experts(s, bias);  w = routing_weights(s, experts)
-        rows = sort x's k copies by expert               # tile-aligned grouped layout
+        rows = x's k copies, each where its expert's rows lie   # tile-aligned grouped layout, counted
         h = gmm(rows, W13);  h = silu(h[:, :F]) * h[:, F:];  out = gmm(h, W2)
         y = Σ_j w_j · out[row of (token, j)]  +  shared(x)
+
+    Nothing is sorted: ``group_layout`` counts each assignment's row. Whatever
+    is indexed by assignment is held assignment-major — ``row_of_assignment``
+    ``[k, S]``, so a token's ``k`` rows are ``k`` aligned ``[S, D]`` slabs to add
+    in float32, forward (the combine) and backward (the dispatch's cotangent);
+    no ``[S, k, D]`` array exists. Each ``[·, D]`` array is gathered once a
+    pass: the weights' cotangent is a row-order dot read back as scalars.
 
     The bank is two stacked parameters in the dtype a checkpoint stores
     (bfloat16), ``experts_w13`` ``[E, D, 2F]`` (gate | up) and ``experts_w2``
@@ -627,7 +678,7 @@ class ExpertFFN(nn.Module):
     even share ``S k / E`` — from the group sizes the matmul takes anyway, and
     ``moe_routing/chosen``, the ``[S, k]`` experts themselves.
     The routing choice is kept across remat (``moe_chosen``): the re-forward
-    lays out and weighs the forward's own assignments."""
+    counts the layout again and weighs the forward's own assignments."""
 
     cfg: TransformerConfig
 
@@ -656,21 +707,23 @@ class ExpertFFN(nn.Module):
             chosen = checkpoint_name(choose_experts(s_, bias, k), "moe_chosen")
             weights = routing_weights(s_, chosen, cfg.routed_scale)
             layout = group_layout(chosen.reshape(-1), e, cfg.expert_tile_m)
+            # assignment-major: whatever is indexed by assignment is k slabs of S, never [S, k, D]
+            row_of_assignment = layout.slot_of_assignment.reshape(s, k).T
             token_of_row = jnp.where(
                 layout.assignment_of_slot < s * k, layout.assignment_of_slot // k, s
-            )  # s: out of range, filled with zeros
+            )  # s: a padding row reads the zero row `_token_rows` appends
             load = jnp.max(layout.group_sizes).astype(jnp.float32) / (s * k / e)
         self.sow("moe_stats", "load_max_over_mean", load)
         self.sow("moe_routing", "chosen", chosen)  # for whoever compares assignments (tests, the benchmark's check)
         with scope("moe_experts"):
-            rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, layout.slot_of_assignment)
+            rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, row_of_assignment)
             h = gmm(rows, w13, layout.group_sizes)
             h = nn.silu(h[:, :f]) * h[:, f:]
             out = gmm(h, w2, layout.group_sizes)
         shared = MLP(cfg, cfg.shared_experts * f, name="shared")(x) if cfg.shared_experts else None
         with scope("moe_combine"):
             y = _from_expert_rows(
-                out, weights, layout.slot_of_assignment, token_of_row, layout.assignment_of_slot
+                out, weights, row_of_assignment, token_of_row, layout.assignment_of_slot
             ).reshape(b, t, d)
             return y if shared is None else y + shared
 

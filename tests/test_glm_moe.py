@@ -21,8 +21,8 @@ from benchmark.reference import fedavg, glm_moe_lm
 from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.lora import _lm_forward, _lm_loss, merge_params, split_lora
 from p2pfl_tpu.models.transformer import (
-    CausalLM, ExpertFFN, MLAttention, TransformerConfig, choose_experts, layer_runs, router_scores, routing_weights,
-    tiny_transformer,
+    CausalLM, ExpertFFN, MLAttention, TransformerConfig, _from_expert_rows, _rows_at, _to_expert_rows, choose_experts,
+    layer_runs, router_scores, routing_weights, tiny_transformer,
 )
 from p2pfl_tpu.ops import grouped_matmul as gmm_ops
 from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles, tiles_and_fetches
@@ -177,6 +177,156 @@ def test_grouped_matmul_under_vmap_keeps_each_elements_groups(axis_size):
     want = jnp.stack([one(x, gr, "xla") for x, gr in zip(xs, groups)])
     for impl in ("xla", "pallas"):
         np.testing.assert_allclose(jax.vmap(lambda x, gr: one(x, gr, impl))(xs, groups), want, rtol=1e-5, atol=1e-5)
+
+
+# ---- the counted layout, and the gathers through it --------------------------------
+
+
+def _layout_by_sorting(group_of, n_groups: int, tile: int):
+    """numpy, a stable sort: what ``group_layout`` computed until PR 36."""
+    group_of = np.asarray(group_of)
+    m = group_of.shape[0]
+    sizes = np.bincount(group_of, minlength=n_groups)
+    tiles = -(-sizes // tile)
+    padded_start, sorted_start = tile * (np.cumsum(tiles) - tiles), np.cumsum(sizes) - sizes
+    order = np.argsort(group_of, kind="stable")
+    slot_sorted = padded_start[group_of[order]] + np.arange(m) - sorted_start[group_of[order]]
+    slot_of_assignment = np.zeros(m, np.int64)
+    slot_of_assignment[order] = slot_sorted
+    assignment_of_slot = np.full(tile * n_row_tiles(m, n_groups, tile), m)
+    assignment_of_slot[slot_sorted] = order
+    return sizes, slot_of_assignment, assignment_of_slot
+
+
+LAYOUT_CASES = {  # (assignments, groups, tile): [elements, M] group ids, another routing each element
+    "random": lambda key: jax.random.randint(key, (3, 1000), 0, 8),
+    "all_in_one_group": lambda key: jnp.tile(jnp.array([[5], [0], [7]], jnp.int32), (1, 300)),
+    "one_empty_group": lambda key: (jax.random.randint(key, (3, 300), 0, 7) + jnp.array([[1], [3], [7]])) % 8,
+    "fewer_than_a_tile": lambda key: jax.random.randint(key, (3, 11), 0, 8),
+}
+
+
+@pytest.mark.parametrize("mapped", ["alone", "under_vmap"])
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_group_layout_is_the_stable_sorts_on_every_field(case, mapped):
+    """The counted layout against a numpy stable sort, element for element —
+    alone, and under ``vmap`` with each element's own groups (the node chunk)."""
+    groups = LAYOUT_CASES[case](jax.random.PRNGKey(4)).astype(jnp.int32)
+    tile = 16
+    if mapped == "alone":
+        got = [group_layout(g, 8, tile) for g in groups]
+        sizes, slot_of, a_of = (np.stack([np.asarray(lay[i]) for lay in got]) for i in range(3))
+        rows = got[0].rows
+    else:
+        sizes, slot_of, a_of = (np.asarray(f) for f in jax.vmap(lambda g: group_layout(g, 8, tile)[:3])(groups))
+        rows = a_of.shape[1]
+    assert rows == tile * n_row_tiles(groups.shape[1], 8, tile) == a_of.shape[1]
+    assert sizes.dtype == slot_of.dtype == a_of.dtype == np.int32
+    for i, g in enumerate(groups):
+        for got_field, want in zip((sizes[i], slot_of[i], a_of[i]), _layout_by_sorting(g, 8, tile)):
+            np.testing.assert_array_equal(got_field, want)
+    if case == "one_empty_group":
+        assert (sizes == 0).sum(axis=1).tolist() == [1, 1, 1]
+
+
+def _dispatch_operands(k: int):
+    """37 tokens x ``k`` assignments over 6 groups in tiles of 8 (padding rows in
+    every group), the index arrays as ``ExpertFFN`` makes them."""
+    s, d, g, tile = 37, 24, 6, 8
+    keys = jax.random.split(jax.random.PRNGKey(k), 5)
+    chosen = jnp.argsort(jax.random.uniform(keys[0], (s, g)), axis=-1)[:, :k].astype(jnp.int32)  # k distinct groups a token
+    layout = group_layout(chosen.reshape(-1), g, tile)
+    token_of_row = jnp.where(layout.assignment_of_slot < s * k, layout.assignment_of_slot // k, s)
+    row_of = np.asarray(layout.slot_of_assignment).reshape(s, k)
+    assert layout.rows > s * k and (np.asarray(layout.assignment_of_slot) == s * k).any()
+    place = np.zeros((s, k, layout.rows), np.float32)  # place[s, j, r] = 1: row r holds assignment (s, j)
+    np.put_along_axis(place, row_of[..., None], 1.0, axis=-1)
+    return layout, token_of_row, jnp.asarray(row_of.T), place, keys, (s, d)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_to_expert_rows_and_its_cotangent_match_the_dense_formulas(k):
+    """``rows = placeᵀ x`` (padding rows zero) and ``dx[s] = Σ_j g[row of (s, j)]``
+    as float32 einsums over the one-hot placement."""
+    layout, token_of_row, row_of, place, keys, (s, d) = _dispatch_operands(k)
+    x = jax.random.normal(keys[1], (s, d), jnp.float32)
+    g = jax.random.normal(keys[2], (layout.rows, d), jnp.float32)
+    rows, pull = jax.vjp(lambda x_: _to_expert_rows(x_, token_of_row, row_of), x)
+    np.testing.assert_allclose(rows, np.einsum("sjr,sd->rd", place, np.asarray(x)), rtol=1e-6, atol=1e-6)
+    assert not np.asarray(rows)[np.asarray(layout.assignment_of_slot) == s * k].any()
+    np.testing.assert_allclose(pull(g)[0], np.einsum("sjr,rd->sd", place, np.asarray(g)), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_from_expert_rows_and_its_cotangents_match_the_dense_formulas(k):
+    """``y[s] = Σ_j w[s, j] rows[row of (s, j)]``, ``d_rows[r] = w(r) g[token of r]``
+    (zero for padding rows) and ``d_w[s, j] = ⟨rows[row of (s, j)], g[s]⟩`` — the
+    last read in ROW order by the code, by assignment here."""
+    layout, token_of_row, row_of, place, keys, (s, d) = _dispatch_operands(k)
+    rows = jax.random.normal(keys[1], (layout.rows, d), jnp.float32)  # padding rows NOT zero: nothing may read them
+    weights = jax.random.uniform(keys[3], (s, k), jnp.float32, 0.1, 1.0)
+    g = jax.random.normal(keys[4], (s, d), jnp.float32)
+    y, pull = jax.vjp(lambda r, w: _from_expert_rows(r, w, row_of, token_of_row, layout.assignment_of_slot), rows, weights)
+    d_rows, d_weights = pull(g)
+    rows_, weights_, g_ = np.asarray(rows), np.asarray(weights), np.asarray(g)
+    np.testing.assert_allclose(y, np.einsum("sjr,sj,rd->sd", place, weights_, rows_), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(d_rows, np.einsum("sjr,sj,sd->rd", place, weights_, g_), rtol=1e-5, atol=1e-5)
+    assert not np.asarray(d_rows)[np.asarray(layout.assignment_of_slot) == s * k].any()
+    np.testing.assert_allclose(d_weights, np.einsum("sjr,rd,sd->sj", place, rows_, g_), rtol=1e-5, atol=1e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (``pjit``, ``custom_vjp`` rules, loops) too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("mapped", ["rows_and_index", "index_only", "rows_only"])
+@pytest.mark.parametrize("axis_size", [1, 3])
+def test_row_gather_under_vmap_is_one_flat_gather_with_each_elements_rows(axis_size, mapped):
+    """``_rows_at`` batches itself (one gather over ``[B · N, D]``, element ``b``'s
+    index ``b · N`` further down — the node-chunk ``vmap`` of every cell): equal
+    to the loop over the elements, whichever operand is mapped, ``[k, S]``
+    indices included; and the batched jaxpr gathers from two dimensions, not three."""
+    a = jax.random.normal(jax.random.PRNGKey(0), (axis_size, 40, 16), jnp.float32)
+    index = jax.random.randint(jax.random.PRNGKey(1), (axis_size, 2, 37), 0, 40)
+    axes = {"rows_and_index": (0, 0), "index_only": (None, 0), "rows_only": (0, None)}[mapped]
+    pick = lambda x, axis, b: x[0] if axis is None else x[b]  # noqa: E731
+    operands = tuple(x[0] if axis is None else x for x, axis in zip((a, index), axes))
+    got = jax.vmap(_rows_at, in_axes=axes)(*operands)
+    want = jnp.stack([pick(a, axes[0], b)[pick(index, axes[1], b)] for b in range(axis_size)])
+    np.testing.assert_array_equal(got, want)
+    gathers = [eqn for eqn in _equations(jax.make_jaxpr(jax.vmap(_rows_at, in_axes=axes))(*operands).jaxpr) if eqn.primitive.name == "gather"]
+    assert [len(eqn.invars[0].aval.shape) for eqn in gathers] == [2]
+
+
+def test_the_expert_layer_sorts_nothing_and_gathers_each_row_array_once():
+    """Structure: the lowered layout and one ``ExpertFFN``'s lowered gradient
+    hold no ``stablehlo.sort`` (the layout is counted), the layout ONE scatter
+    (``assignment_of_slot``; the parent's three beside its sort) and no gather.
+    The gradient gathers ``D``-wide rows four times — every row of the layout
+    for the dispatch and for the combine's ``g_rows``, every assignment for the
+    combine and for the dispatch's cotangent; the parent's combine read ``out``
+    by assignment a SECOND time for the weights' cotangent, now a gather of
+    scalars. No gathered array holds ``k`` between ``S`` and ``D``. Counted in
+    the jaxpr: the lowered text holds one function however many calls share it."""
+    layout = jax.jit(lambda g: group_layout(g, 8, 8)[:3]).lower(jnp.zeros((74,), jnp.int32)).as_text()
+    assert "stablehlo.sort" not in layout and "stablehlo.gather" not in layout and layout.count('"stablehlo.scatter"') == 1
+    cfg = config(expert_impl="xla", shared_experts=0)
+    layer, params, h = _expert_layer(cfg)
+    (s, k), rows = (h.shape[1], cfg.experts_per_token), 8 * n_row_tiles(37 * 2, 8, 8)
+    grad = jax.grad(lambda p, h_: jnp.sum(layer.apply({"params": p}, h_)), argnums=(0, 1))
+    assert "stablehlo.sort" not in jax.jit(grad).lower(params, h).as_text()
+    gathered = [tuple(eqn.outvars[0].aval.shape) for eqn in _equations(jax.make_jaxpr(grad)(params, h).jaxpr) if eqn.primitive.name == "gather"]
+    wide_rows = sum(int(np.prod(shape[:-1])) for shape in gathered if len(shape) > 1 and shape[-1] == cfg.dim)
+    parents_wide_rows = 2 * rows + 3 * s * k
+    assert wide_rows == 2 * rows + 2 * s * k < parents_wide_rows, gathered
+    assert not any(len(shape) == 3 and shape[1] == k and shape[2] == cfg.dim for shape in gathered), gathered  # no [S, k, D]
 
 
 # the kernel's ring of matrix blocks, case by case: group sizes in tiles of 8 over
