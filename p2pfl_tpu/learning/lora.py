@@ -6,7 +6,12 @@ for the default tiny config that's <1% of the parameters, and for a
 TinyLlama-scale model it turns a ~2 GB gossip payload into a few MB.
 
 Works with any module whose adapter params carry the ``lora_`` name prefix
-(:class:`~p2pfl_tpu.models.transformer.LoRADense`).
+(:class:`~p2pfl_tpu.models.transformer.LoRADense`). Whatever carries none is
+base: frozen, node-resident and in no payload — the expert banks, a router,
+convolution taps, and an untied output head (``lm_head``, which
+``CausalLM(head=False)`` hands to the loss in the embedding's place). An
+attention output gate (``wg``) is a ``LoRADense`` like the four projections
+beside it, so it is adapted and exchanged with them.
 """
 
 from __future__ import annotations
@@ -87,6 +92,7 @@ def _lm_forward(lora, base, module, x, y):
     forward — a comparison must not take them from another program: a TPU
     rounds a near-tie differently from one compiled program to the next)."""
     params = merge_params(base, lora)
+    # `embedding`: the head's matrix, the embedding itself or an untied `lm_head`
     (hidden, embedding), mut = module.apply(
         {"params": params}, x, head=False, mutable=["moe_losses", "moe_stats", "moe_routing"]
     )

@@ -48,6 +48,10 @@ DEVICE_SCOPES = (
     "adapter",  # LoRADense: (x @ A) @ B
     "flash_fwd",  # ops/flash_attention: the forward Mosaic call
     "flash_bwd",  # ops/flash_attention: the backward Mosaic call(s)
+    "flash_win_fwd",  # ops/flash_attention: the forward call of a SLIDING layer (window=…: blocks outside it skipped)
+    "flash_win_bwd",  # ops/flash_attention: the backward call(s) of a sliding layer
+    "attn_gate",  # Attention: the output gate, sigmoid(x W_g) times the attention output, before wo
+    "post_norm",  # Block: the second norm of the sandwich, on the mixer's and the feed-forward's output
     "ssm_conv",  # MambaMixer: the causal depthwise convolution and its silu
     "ssm_scan_fwd",  # ops/selective_scan: everything the op runs forward (kernel and glue)
     "ssm_scan_bwd",  # ops/selective_scan: everything its backward runs

@@ -3,8 +3,11 @@
 BASELINE config 5: federated LoRA fine-tuning — nodes train and exchange
 ONLY the low-rank adapters, so a round's gossip payload drops from the full
 model to a few MB. A block is pre-RMSNorm → sequence mixer → residual, then
-pre-RMSNorm → SwiGLU (or MoE) → residual; all matmuls in bfloat16 on the MXU,
-norms, softmax statistics and the state-space recurrence in float32.
+pre-RMSNorm → SwiGLU (or MoE) → residual (``post_norms``: a second RMSNorm on
+each sublayer's OUTPUT before its residual — the sandwich); all matmuls in
+bfloat16 on the MXU, norms, softmax statistics and the state-space recurrence
+in float32. The output head is the embedding transposed unless ``tie_head`` is
+off, when it is a matrix of its own (``lm_head``, frozen under LoRA).
 
 Sequence mixers — ``TransformerConfig.layer_pattern`` names one per layer of a
 period, and the stack repeats the period:
@@ -21,7 +24,12 @@ period, and the stack repeats the period:
   then N expert layers" is one period of two runs;
 - ``"conv_dense"`` / ``"conv_experts"``: the gated short convolution
   (:class:`ShortConvMixer`, the LFM2 operator) before the dense SwiGLU or the
-  expert layer; ``"attention_experts"``: plain attention before the expert layer.
+  expert layer; ``"attention_experts"``: plain attention before the expert layer;
+- ``"swa_dense"`` / ``"swa_experts"`` / ``"full_experts"``:
+  sliding-window and full attention in ONE stack (the ``afmoe`` block of
+  Trinity): a ``swa`` layer rotates at ``rope_theta`` and sees the last
+  ``attn_window`` keys only, a ``full`` layer sees every earlier key and is NOT
+  rotated — rotation and window belong to the layer kind, not to the config.
 
 ``TransformerConfig.leading_pattern`` names layers that run ONCE before the
 periodic stack ("two dense layers, then periods of expert layers").
@@ -29,12 +37,16 @@ periodic stack ("two dense layers, then periods of expert layers").
 Attention backends — pick with ``tiny_transformer(attn=...)``:
 
 - ``"dense"`` (default): fused XLA causal attention (``ops/attention.py``);
+  a sliding layer masks the ``[T, T]`` logits to its window;
 - ``"flash"``: the Pallas flash kernel with its Pallas backward
-  (``ops/flash_attention.py``) — O(T·D) memory in both directions;
-- ``"ring"``: ring attention over a mesh axis (pass ``mesh=``) — the
-  sequence is sharded across chips, K/V rotate via ``ppermute``.
+  (``ops/flash_attention.py``) — O(T·D) memory in both directions; a sliding
+  layer SKIPS the key blocks outside its window;
+- ``"ring"`` / ``"ring_flash"``: ring attention over a mesh axis (pass
+  ``mesh=``) — the sequence is sharded across chips, K/V rotate via
+  ``ppermute``. No sliding window: a model with ``swa`` layers is refused.
 
-Power users can instead pass any ``attn_fn(q, k, v) -> out`` directly.
+Power users can instead pass any ``attn_fn(q, k, v) -> out`` directly; a model
+with sliding layers calls it as ``attn_fn(q, k, v, window=W)`` on those.
 """
 
 from __future__ import annotations
@@ -74,6 +86,11 @@ LAYER_KINDS = {
     "attention_experts": ("attention", "experts"),
     "conv_dense": ("short_conv", "mlp"),
     "conv_experts": ("short_conv", "experts"),
+    # attention whose rotation and window are the KIND's: "swa" rotates at
+    # cfg.rope_theta under cfg.attn_window, "full" does neither
+    "swa_dense": ("swa", "mlp"),
+    "swa_experts": ("swa", "experts"),
+    "full_experts": ("full", "experts"),
 }
 
 
@@ -109,6 +126,9 @@ class TransformerConfig:
     n_heads: int = 8
     n_kv_heads: int = 4
     ffn_hidden: int = 688  # ~8/3 * dim rounded
+    # width of one attention head; None = dim // n_heads (every model whose
+    # heads tile the residual width). Trinity: 32 heads of 128 on 2048.
+    head_dim: Optional[int] = None
     # None = no positional rotation at all (Jamba's attention layers: the
     # state-space layers around them carry position)
     rope_theta: Optional[float] = 10000.0
@@ -123,6 +143,20 @@ class TransformerConfig:
     # per-head RMSNorm (its own scale, over ``head_dim``) on q and k before
     # RoPE (``"attention"`` mixers only)
     qk_norm: bool = False
+    # keys a ``"swa_*"`` layer's query sees, itself included (row i: i - W < j <= i);
+    # ``"full_*"`` layers and every other kind take no notice of it
+    attn_window: Optional[int] = None
+    # sigmoid output gate of ``Attention``: ``(P v) * sigmoid(x W_g)`` before
+    # ``wo``; ``wg`` is a fifth LoRADense (``dim -> n_heads * head_dim``)
+    attn_gate: bool = False
+    # sandwich norms: an RMSNorm on the mixer's and on the feed-forward's
+    # OUTPUT, before each residual add, beside the two pre-norms
+    post_norms: bool = False
+    # factor on the input embedding (muP models: sqrt(dim)); 1 = none
+    embed_scale: float = 1.0
+    # False: the output head is ``lm_head`` ``[vocab, dim]``, a base leaf of its
+    # own — frozen under LoRA and outside its FedAvg — not the embedding
+    tie_head: bool = True
     # taps of the gated short convolution (``"conv_*"`` layers only)
     conv_taps: int = 3
     # Mamba-1 widths (used by ``"mamba"`` layers only): inner width
@@ -215,6 +249,23 @@ class TransformerConfig:
     routed_scale: float = 1.0
     expert_tile_m: int = 128
     expert_impl: Optional[str] = None
+    # A HELD SHARE of the routed experts (expert parallelism seen from one
+    # chip): this program holds experts ``[first_expert, first_expert +
+    # experts_held)`` of every layer — banks ``[.., experts_held, ..]`` — while
+    # the router keeps ``routed_experts`` outputs, its top-k and weights
+    # normalised over ALL the chosen. Only assignments to a held expert get a
+    # row, a product and a term in the combine; nothing is exchanged and nothing
+    # stands in for the absent experts' results. None = all of them.
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+
+    @property
+    def head_width(self) -> int:
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def held_experts(self) -> int:
+        return self.routed_experts if self.experts_held is None else self.experts_held
 
     def __post_init__(self) -> None:
         pattern = tuple(self.layer_pattern)
@@ -230,6 +281,15 @@ class TransformerConfig:
             raise ValueError(
                 f"n_layers {self.n_layers} is not {len(leading)} leading layer(s) (leading_pattern) and a whole "
                 f"number of periods of {len(pattern)} layers (layer_pattern)"
+            )
+        if any(LAYER_KINDS[kind][0] == "swa" for kind in leading + pattern) and not self.attn_window:
+            raise ValueError("a 'swa_*' layer needs attn_window (the keys a query sees, itself included)")
+        if self.experts_held is not None and not (
+            0 < self.experts_held and 0 <= self.first_expert and self.first_expert + self.experts_held <= self.routed_experts
+        ):
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert} + {self.experts_held}) are no share of "
+                f"{self.routed_experts} routed experts"
             )
         if self.remat_policy is not None:
             _remat_policy(self.remat_policy)  # raises on an unknown name
@@ -298,9 +358,19 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _flash_attend(q, k, v, window=None, *, config, window_config, interpret):
+    """Flash attention whose sliding calls run under a schedule of their own."""
+    from p2pfl_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(
+        q, k, v, causal=True, config=config if window is None else window_config, interpret=interpret, window=window
+    )
+
+
 def _attend_fn(cfg: TransformerConfig, attn_fn: Optional[Callable]) -> Callable:
     """The ``(q, k, v) -> out`` of an attention module: the explicit callable,
-    else the config's pinned flash schedule, else fused dense causal attention."""
+    else the config's pinned flash schedule, else fused dense causal attention.
+    A sliding layer calls it with ``window=``; all three take one."""
     if attn_fn is not None:
         return attn_fn
     if cfg.flash_config is not None:
@@ -319,13 +389,23 @@ def _attend_fn(cfg: TransformerConfig, attn_fn: Optional[Callable]) -> Callable:
 
 
 class Attention(nn.Module):
+    """Grouped-query causal attention. ``mixer`` is the layer kind's
+    (:data:`LAYER_KINDS`): ``"attention"`` rotates by the config-wide rule
+    (``rope_theta`` unless it is ``None``) and sees every earlier key; ``"swa"``
+    rotates at ``rope_theta`` and sees ``attn_window`` keys; ``"full"`` is not
+    rotated and sees every earlier key. ``attn_gate``: the output is
+    ``(P v) * sigmoid(x W_g)`` before ``wo``."""
+
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None  # (q, k, v) -> out; default fused causal
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        head_dim = cfg.dim // cfg.n_heads
+        head_dim = cfg.head_width
+        rotate = cfg.rope_theta is not None and self.mixer != "full"
+        window = cfg.attn_window if self.mixer == "swa" else None
         dense = partial(LoRADense, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
         q = dense(cfg.n_heads * head_dim, name="wq")(x)
         k = dense(cfg.n_kv_heads * head_dim, name="wk")(x)
@@ -338,7 +418,7 @@ class Attention(nn.Module):
             with scope("qk_norm"):
                 q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
                 k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
-        if cfg.rope_theta is not None:
+        if rotate:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         # selective-remat tags: saved pre-GQA-repeat (kv_heads wide, the
         # repeat is a cheap broadcast to recompute)
@@ -349,7 +429,13 @@ class Attention(nn.Module):
         rep = cfg.n_heads // cfg.n_kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-        out = _attend_fn(cfg, self.attn_fn)(q, k, v).reshape(b, t, cfg.dim)
+        attend = _attend_fn(cfg, self.attn_fn)
+        out = attend(q, k, v) if window is None else attend(q, k, v, window=window)
+        out = out.reshape(b, t, cfg.n_heads * head_dim)
+        if cfg.attn_gate:
+            gate = dense(cfg.n_heads * head_dim, name="wg")(x)
+            with scope("attn_gate"):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(cfg.dtype)
         return dense(cfg.dim, name="wo")(out)
 
 
@@ -674,8 +760,20 @@ class ExpertFFN(nn.Module):
     under LoRA and outside its FedAvg. The shared expert is :class:`MLP`
     (adapters where ``lora_mlp``).
 
-    Sows ``moe_stats/load_max_over_mean`` — rows on the fullest expert over the
-    even share ``S k / E`` — from the group sizes the matmul takes anyway, and
+    A held share (``cfg.experts_held`` of ``cfg.routed_experts``, from
+    ``cfg.first_expert``): the router, its top-k and the weights — normalised
+    over ALL ``k`` chosen — are the whole model's; the banks are ``[experts_held,
+    ...]``; only an assignment to a held expert gets a row. The layout keeps the
+    static worst case (every assignment held) plus ONE spare row tile that no
+    group owns: an absent assignment reads its last row, zero going in (padding),
+    zero coming out of the matmul (a tile past the used count) and with a zero
+    cotangent both ways, so it costs no tile and needs no mask. Nothing is
+    exchanged: the partial sum (+ the shared expert, whole) is the layer's output.
+
+    Sows ``moe_stats/load_max_over_mean`` — rows on the fullest (held) expert over the
+    even share ``S k / E`` — from the group sizes the matmul takes anyway,
+    ``moe_stats/held_share`` — assignments computed here over ``S k`` (1 when
+    every expert is held) — where a share is held, and
     ``moe_routing/chosen``, the ``[S, k]`` experts themselves.
     The routing choice is kept across remat (``moe_chosen``): the re-forward
     counts the layout again and weighs the forward's own assignments."""
@@ -688,6 +786,8 @@ class ExpertFFN(nn.Module):
 
         cfg = self.cfg
         e, k, f = cfg.routed_experts, cfg.experts_per_token, cfg.expert_hidden
+        held = cfg.held_experts
+        share = (cfg.first_expert, True) if held < e else (0, False)  # (first group, a spare tile)
         b, t, d = x.shape
         s = b * t
         xs = x.reshape(s, d)
@@ -695,8 +795,8 @@ class ExpertFFN(nn.Module):
         bias = self.param("router_bias", nn.initializers.zeros, (e,))
         if bank is None:
             layer = None
-            w13 = self.param("experts_w13", _bank_init, (e, d, 2 * f))
-            w2 = self.param("experts_w2", _bank_init, (e, f, d))
+            w13 = self.param("experts_w13", _bank_init, (held, d, 2 * f))
+            w2 = self.param("experts_w2", _bank_init, (held, f, d))
         else:
             layer, w13, w2 = bank
         gmm = partial(grouped_matmul, layer=layer, tile_m=cfg.expert_tile_m, impl=cfg.expert_impl)
@@ -706,7 +806,7 @@ class ExpertFFN(nn.Module):
             # re-forward all follow the forward's choice
             chosen = checkpoint_name(choose_experts(s_, bias, k), "moe_chosen")
             weights = routing_weights(s_, chosen, cfg.routed_scale)
-            layout = group_layout(chosen.reshape(-1), e, cfg.expert_tile_m)
+            layout = group_layout(chosen.reshape(-1), held, cfg.expert_tile_m, *share)
             # assignment-major: whatever is indexed by assignment is k slabs of S, never [S, k, D]
             row_of_assignment = layout.slot_of_assignment.reshape(s, k).T
             token_of_row = jnp.where(
@@ -714,6 +814,8 @@ class ExpertFFN(nn.Module):
             )  # s: a padding row reads the zero row `_token_rows` appends
             load = jnp.max(layout.group_sizes).astype(jnp.float32) / (s * k / e)
         self.sow("moe_stats", "load_max_over_mean", load)
+        if held < e:
+            self.sow("moe_stats", "held_share", jnp.sum(layout.group_sizes).astype(jnp.float32) / (s * k))
         self.sow("moe_routing", "chosen", chosen)  # for whoever compares assignments (tests, the benchmark's check)
         with scope("moe_experts"):
             rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, row_of_assignment)
@@ -844,18 +946,26 @@ class Block(nn.Module):
         cfg = self.cfg
         mixer, ffn_kind = LAYER_KINDS[self.kind]
         norm = partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+
+        def post(name, y):  # the sandwich's second norm, on a sublayer's output
+            if not cfg.post_norms:
+                return y
+            with scope("post_norm"):
+                return norm(name=name)(y)
+
         if mixer == "mamba":
-            x = x + MambaMixer(cfg, name="mamba")(norm(name="mamba_norm")(x))
+            x = x + post("mamba_post_norm", MambaMixer(cfg, name="mamba")(norm(name="mamba_norm")(x)))
         elif mixer == "short_conv":
-            x = x + ShortConvMixer(cfg, name="conv")(norm(name="conv_norm")(x))
+            x = x + post("conv_post_norm", ShortConvMixer(cfg, name="conv")(norm(name="conv_norm")(x)))
+        elif mixer == "mla":
+            x = x + post("attn_post_norm", MLAttention(cfg, self.attn_fn, name="attn")(norm(name="attn_norm")(x)))
         else:
-            attention = MLAttention if mixer == "mla" else Attention
-            x = x + attention(cfg, self.attn_fn, name="attn")(norm(name="attn_norm")(x))
+            x = x + post("attn_post_norm", Attention(cfg, self.attn_fn, mixer, name="attn")(norm(name="attn_norm")(x)))
         h = norm(name="mlp_norm")(x)
         if ffn_kind == "experts":
-            return x + ExpertFFN(cfg, name="mlp")(h, bank)
+            return x + post("mlp_post_norm", ExpertFFN(cfg, name="mlp")(h, bank))
         ffn = MLP if ffn_kind == "mlp" or cfg.n_experts == 0 else MoEMLP
-        return x + ffn(cfg, name="mlp")(h)
+        return x + post("mlp_post_norm", ffn(cfg, name="mlp")(h))
 
 
 class _ScanBlock(nn.Module):
@@ -974,20 +1084,26 @@ def sown_by_layer(cfg: TransformerConfig, sown) -> jax.Array:
 
 
 def tied_logits(hidden, embedding):
-    """``[B, T, dim] x [vocab, dim] -> [B, T, vocab]`` float32: the tied head,
-    multiplied in the hidden states' dtype."""
+    """``[B, T, dim] x [vocab, dim] -> [B, T, vocab]`` float32: the head —
+    ``embedding`` is the embedding itself (tied) or ``lm_head`` — multiplied in
+    the hidden states' dtype."""
     return jnp.dot(hidden, embedding.T.astype(hidden.dtype)).astype(jnp.float32)
 
 
 class CausalLM(nn.Module):
-    """Decoder-only LM with the output head tied to the embedding.
+    """Decoder-only LM: embedding (times ``cfg.embed_scale``), the layer stack
+    (``leading_pattern`` once, then periods of ``layer_pattern``), a final
+    RMSNorm, and the output head — the embedding transposed, or with
+    ``cfg.tie_head`` off a matrix of its own, ``lm_head`` ``[vocab, dim]``
+    (no ``lora_`` prefix: a base leaf, frozen under LoRA and in no FedAvg payload).
 
     ``__call__(tokens)`` gives float32 logits ``[B, T, vocab]`` — what
     evaluation, generation and ``SpmdLmFederation`` read. ``head=False`` stops
     before the head and hands out what it would take, ``(final norm's output
     [B, T, dim], the head's matrix [vocab, dim])``: a training loss that needs
     no logits whole takes both to :func:`p2pfl_tpu.ops.head_loss.head_loss`
-    (``learning/lora.py::_lm_forward``)."""
+    (``learning/lora.py::_lm_forward``) — the same function whichever matrix
+    the head is."""
 
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
@@ -998,7 +1114,10 @@ class CausalLM(nn.Module):
         emb = self.param(
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim)
         )
-        x = emb[tokens].astype(cfg.dtype)
+        if cfg.embed_scale == 1.0:
+            x = emb[tokens].astype(cfg.dtype)
+        else:
+            x = (emb[tokens] * cfg.embed_scale).astype(cfg.dtype)
         pattern, leading = cfg.layer_pattern, cfg.leading_pattern
         if cfg.scan_layers:
             if cfg.n_experts > 0 and any(LAYER_KINDS[kind][1] is None for kind in leading + pattern):
@@ -1016,7 +1135,7 @@ class CausalLM(nn.Module):
             bank_shapes = {"w13": (cfg.dim, 2 * cfg.expert_hidden), "w2": (cfg.expert_hidden, cfg.dim)}
             banks = {
                 f"run{i}_{kind}": tuple(
-                    self.param(f"experts_{w}_run{i}", _bank_init, (periods * count, cfg.routed_experts, *shape))
+                    self.param(f"experts_{w}_run{i}", _bank_init, (periods * count, cfg.held_experts, *shape))
                     for w, shape in bank_shapes.items()
                 )
                 for i, (kind, count) in enumerate(layer_runs(pattern)) if _is_expert_run(kind)
@@ -1035,10 +1154,13 @@ class CausalLM(nn.Module):
                 kind = leading[i] if i < len(leading) else pattern[(i - len(leading)) % len(pattern)]
                 x = _rematted(Block, cfg, kind, in_scan=False)(cfg, self.attn_fn, kind, name=f"layer_{i}")(x)
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="final_norm")(x)
+        head_matrix = emb if cfg.tie_head else self.param(
+            "lm_head", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim)
+        )
         if not head:
-            return x, emb
+            return x, head_matrix
         with scope("head"):
-            return tied_logits(x, emb)
+            return tied_logits(x, head_matrix)
 
 
 def pick_attention(seq_len: int, backend: Optional[str] = None) -> str:
@@ -1070,8 +1192,11 @@ def resolve_attention(
     seq_len: Optional[int] = None,
     block_bwd: Optional[int] = None,
     config: Optional[FlashConfig] = None,
+    window_config: Optional[FlashConfig] = None,
 ) -> Optional[Callable]:
     """Map an attention backend name to an ``(q, k, v) -> out`` callable.
+    ``window_config`` (flash only): the schedule of the calls a sliding layer
+    makes with ``window=``; without it they run under ``config`` too.
 
     ``config`` pins the full static kernel schedule
     (:class:`~p2pfl_tpu.ops.flash_attention.FlashConfig`); the legacy
@@ -1096,6 +1221,8 @@ def resolve_attention(
 
         # Pallas runs natively on TPU; anywhere else use interpret mode
         interpret = jax.default_backend() != "tpu"
+        if window_config is not None:
+            return partial(_flash_attend, config=config, window_config=window_config, interpret=interpret)
         return partial(
             flash_attention, causal=True, config=config, interpret=interpret
         )
@@ -1131,6 +1258,9 @@ def tiny_transformer(
     cfg = cfg or TransformerConfig()
     if attn == "auto":
         attn = pick_attention(seq_len)
+    sliding = any(LAYER_KINDS[kind][0] == "swa" for kind in cfg.leading_pattern + cfg.layer_pattern)
+    if attn_fn is None and sliding and attn in ("ring", "ring_flash"):
+        raise ValueError(f"attn={attn!r} has no sliding window; a model with 'swa_*' layers runs 'dense' or 'flash'")
     if attn_fn is None:
         # flash blocks must divide the attended length: the GLOBAL sequence
         # for attn="flash", but the PER-DEVICE shard for "ring_flash" (each
@@ -1164,13 +1294,18 @@ def tiny_transformer(
             from p2pfl_tpu.ops import autotune
             from p2pfl_tpu.settings import Settings
 
-            head_dim = cfg.dim // cfg.n_heads
+            head_dim = cfg.head_width
             flash_cfg = cfg.flash_config
             if flash_cfg is None and Settings.FLASH_AUTOTUNE:
                 flash_cfg = autotune.autotune_flash(basis, head_dim, dtype=cfg.dtype)
             if flash_cfg is None:
                 flash_cfg = autotune.get_flash_config(basis, head_dim, dtype=cfg.dtype)
-            attn_fn = resolve_attention(attn, mesh=mesh, config=flash_cfg)
+            window_cfg = None
+            if sliding and cfg.attn_window < basis and cfg.flash_config is None:
+                # a sliding layer's calls are keyed (and tuned) with their window
+                tune = autotune.autotune_flash if Settings.FLASH_AUTOTUNE else autotune.get_flash_config
+                window_cfg = tune(basis, head_dim, dtype=cfg.dtype, window=cfg.attn_window)
+            attn_fn = resolve_attention(attn, mesh=mesh, config=flash_cfg, window_config=window_cfg)
         else:
             attn_fn = resolve_attention(attn, mesh=mesh)
     module = CausalLM(cfg, attn_fn)

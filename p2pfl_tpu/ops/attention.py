@@ -24,13 +24,17 @@ from jax import lax
 NEG_INF = -1e30
 
 
-def causal_attention(q, k, v, scale: Optional[float] = None) -> jax.Array:
+def causal_attention(q, k, v, scale: Optional[float] = None, window: Optional[int] = None) -> jax.Array:
     """Plain fused causal attention. q,k,v: [B, T, H, D] (k/v may have fewer
-    heads — GQA — already repeated by the caller). Returns [B, T, H, D]."""
+    heads — GQA — already repeated by the caller). Returns [B, T, H, D].
+    ``window``: query ``i`` sees keys ``i - window < j <= i`` only (``window``
+    keys, itself included); the ``[T, T]`` logits are still formed whole."""
     b, t, h, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    if window is not None and window < t:
+        mask = mask & ~jnp.tril(jnp.ones((t, t), dtype=bool), -window)
     logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -142,7 +146,7 @@ def _ring_flash_sharded(q, k, v, *, axis_name: str, config, interpret: bool):
 
 def ring_attention(
     q, k, v, mesh, axis_name: str, causal: bool = True, impl: str = "dense",
-    block: int = 128, flash_config=None,
+    block: int = 128, flash_config=None, window: Optional[int] = None,
 ) -> jax.Array:
     """Full-sequence attention with T sharded over ``axis_name`` of ``mesh``.
 
@@ -152,10 +156,17 @@ def ring_attention(
     body's O(T_local²) logits matrix (causal only). ``flash_config`` pins
     the hops' full static kernel schedule
     (:class:`~p2pfl_tpu.ops.flash_attention.FlashConfig`); ``block`` is the
-    square-block shorthand used when no config is given.
+    square-block shorthand used when no config is given. ``window`` exists to
+    be refused: the ring has no sliding window (it raises, and says why).
     """
     from jax.sharding import PartitionSpec as P
 
+    if window is not None:
+        raise NotImplementedError(
+            f"ring_attention takes no sliding window (window={window}): its hops (flash_attention_block, "
+            "_block_attend) mask by global offset only, and a hop wholly outside the window would still be "
+            "sent and attended; run sliding layers with attn='flash' or 'dense' on one chip"
+        )
     spec = P(None, axis_name, None, None)
     if impl == "flash":
         if not causal:

@@ -9,8 +9,9 @@ pure lookup safe to run at trace time:
 3. **On-disk cache** — JSON at ``Settings.FLASH_TUNE_CACHE`` (default
    ``~/.cache/p2pfl_tpu/flash_tune.json``), loaded once per process.
    Entries are keyed on **device kind** (``TPU v4`` / ``TPU v5 lite`` /
-   ``cpu`` …) plus (head_dim, seq_len, dtype, causal), so a cache written
-   on one platform never mis-tunes another.
+   ``cpu`` …) plus (head_dim, seq_len, dtype, causal) and, for a windowed
+   call, the sliding window (a key without a window is what it has always
+   been), so a cache written on one platform never mis-tunes another.
 4. **Shipped defaults tables** (:data:`DEFAULTS`) — the measured
    per-device-family block recipes, clamped to divide the actual sequence
    length.
@@ -63,8 +64,9 @@ def _dtype_tag(dtype) -> str:
     return jnp.dtype(dtype).name
 
 
-def _key(kind: str, d: int, t: int, dtype, causal: bool) -> str:
-    return f"{kind}|d={d}|t={t}|{_dtype_tag(dtype)}|{'causal' if causal else 'full'}"
+def _key(kind: str, d: int, t: int, dtype, causal: bool, window: Optional[int] = None) -> str:
+    key = f"{kind}|d={d}|t={t}|{_dtype_tag(dtype)}|{'causal' if causal else 'full'}"
+    return key if window is None else f"{key}|w={window}"
 
 
 def cache_path() -> Path:
@@ -92,18 +94,20 @@ def _clamped(t: int, block_q: int, block_k: int, q_span: int = 1, **kw) -> Flash
     return FlashConfig(block_q=bq, block_k=bk, q_span=_fit_q_span(t, bq, q_span), **kw)
 
 
-# Shipped per-device-family recipes (functions of (t, d) → FlashConfig).
+# Shipped per-device-family recipes (functions of (t, d, window) → FlashConfig).
 # v4/v5e numbers come from the bench config-7 sweeps (block 512 beat 256 at
 # every measured length; fused bwd keeps the forward's blocks); narrow heads
 # (D <= 64) take q_span=2 — each program owning two q sub-tiles amortizes
 # the grid bookkeeping that dominates when the per-block matmuls are small,
 # while per-sub-tile causal frontiers keep the masked-work fraction of the
 # single-block schedule. CPU/interpret keeps small blocks so the unrolled
-# interpret grid stays compilable.
+# interpret grid stays compilable. A sliding window keeps the same recipe (the
+# band it streams, window + block_q - 1 keys wide, is several blocks at every
+# shipped shape).
 DEFAULTS = {
-    "v4": lambda t, d: _clamped(t, 512, 512, q_span=2 if d <= 64 else 1),
-    "v5e": lambda t, d: _clamped(t, 512, 512, q_span=2 if d <= 64 else 1),
-    "cpu": lambda t, d: _clamped(t, 128, 128),
+    "v4": lambda t, d, window=None: _clamped(t, 512, 512, q_span=2 if d <= 64 else 1),
+    "v5e": lambda t, d, window=None: _clamped(t, 512, 512, q_span=2 if d <= 64 else 1),
+    "cpu": lambda t, d, window=None: _clamped(t, 128, 128),
 }
 
 
@@ -129,11 +133,12 @@ def _family(kind: str) -> str:
 
 
 def default_flash_config(
-    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None
+    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> FlashConfig:
     """The shipped defaults-table config for this shape (no caches)."""
     del dtype, causal  # tables are currently shape-driven only
-    return DEFAULTS[_family(kind or device_kind())](t, d)
+    return DEFAULTS[_family(kind or device_kind())](t, d, window)
 
 
 def _load_disk(path: Path) -> None:
@@ -176,20 +181,21 @@ def clear_memory_cache() -> None:
 
 def pin_flash_config(
     t: int, d: int, config: FlashConfig, dtype=jnp.bfloat16, causal: bool = True,
-    kind: Optional[str] = None,
+    kind: Optional[str] = None, window: Optional[int] = None,
 ) -> None:
     """Pin an explicit config for a shape — wins over tuned/default.
     Session-only: pins are never written to the on-disk tuning cache."""
-    _PINNED[_key(kind or device_kind(), d, t, dtype, causal)] = config
+    _PINNED[_key(kind or device_kind(), d, t, dtype, causal, window)] = config
 
 
 def flash_config_source(
-    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None
+    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> tuple[FlashConfig, str]:
     """Trace-safe config lookup plus where it came from:
     ``"pin"`` → ``"tune"`` (memory → disk) → ``"defaults"``."""
     kind = kind or device_kind()
-    key = _key(kind, d, t, dtype, causal)
+    key = _key(kind, d, t, dtype, causal, window)
     got = _PINNED.get(key)
     if got is not None:
         return got, "pin"
@@ -198,14 +204,15 @@ def flash_config_source(
     got = _MEM_CACHE.get(key)
     if got is not None:
         return got, "tune"
-    return default_flash_config(t, d, dtype, causal, kind), "defaults"
+    return default_flash_config(t, d, dtype, causal, kind, window), "defaults"
 
 
 def get_flash_config(
-    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None
+    t: int, d: int, dtype=jnp.bfloat16, causal: bool = True, kind: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> FlashConfig:
     """Trace-safe config lookup: pinned → tuned (memory → disk) → defaults."""
-    return flash_config_source(t, d, dtype, causal, kind)[0]
+    return flash_config_source(t, d, dtype, causal, kind, window)[0]
 
 
 def candidate_configs(t: int, d: int, max_candidates: int = 12) -> list[FlashConfig]:
@@ -260,7 +267,7 @@ def amortize_iters(t: int) -> int:
 
 def time_flash_fwd(
     q, k, v, config: FlashConfig, *, causal: bool = True,
-    interpret: bool = False, iters: int = 1, repeats: int = 2,
+    interpret: bool = False, iters: int = 1, repeats: int = 2, window: Optional[int] = None,
 ) -> float:
     """Seconds per forward execution: ``iters`` data-chained kernel calls
     inside ONE jitted scan, min over ``repeats``, ending on a device fetch.
@@ -273,7 +280,7 @@ def time_flash_fwd(
     @jax.jit
     def many(q, k, v):
         def body(q, _):
-            o = flash_attention(q, k, v, causal, config, interpret)
+            o = flash_attention(q, k, v, causal, config, interpret, window)
             # data-dependent chain (a *0.0 chain folds to identity and the
             # loop gets DCE'd — measured 0.0 ms in bench_suite)
             return q + (o * 1e-30).astype(q.dtype), None
@@ -286,7 +293,7 @@ def time_flash_fwd(
 
 def time_flash_train(
     q, k, v, config: FlashConfig, *, causal: bool = True,
-    interpret: bool = False, iters: int = 1, repeats: int = 2,
+    interpret: bool = False, iters: int = 1, repeats: int = 2, window: Optional[int] = None,
 ) -> float:
     """Seconds per fwd+bwd execution (grad of a scalar loss), chained and
     timed like :func:`time_flash_fwd`. The loss is sum(out²), NOT sum(out):
@@ -298,7 +305,7 @@ def time_flash_train(
     from p2pfl_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal, config, interpret)
+        o = flash_attention(q, k, v, causal, config, interpret, window)
         return jnp.sum(o * o)  # dO = 2·out: data-dependent cotangent
 
     grad = jax.grad(loss, argnums=(0, 1, 2))
@@ -336,8 +343,9 @@ def autotune_flash(
     cache: bool = True,
     force: bool = False,
     kind: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> FlashConfig:
-    """Sweep kernel schedules for one (T, D, dtype, causal) shape and cache
+    """Sweep kernel schedules for one (T, D, dtype, causal[, window]) shape and cache
     the winner. An existing tuned entry (in-process or on-disk) is returned
     WITHOUT re-sweeping unless ``force=True`` — so FLASH_AUTOTUNE model
     builds pay the sweep once per shape per cache lifetime, not per build.
@@ -355,7 +363,7 @@ def autotune_flash(
     iters = iters if iters is not None else amortize_iters(t)
 
     if cache and not force:
-        key = _key(kind, d, t, dtype, causal)
+        key = _key(kind, d, t, dtype, causal, window)
         _load_disk(cache_path())
         got = _PINNED.get(key) or _MEM_CACHE.get(key)
         if got is not None:
@@ -369,13 +377,13 @@ def autotune_flash(
     def fwd_time(cfg: FlashConfig) -> float:
         return time_flash_fwd(
             q, k, v, cfg, causal=causal, interpret=interpret,
-            iters=iters, repeats=repeats,
+            iters=iters, repeats=repeats, window=window,
         )
 
     def train_time(cfg: FlashConfig) -> float:
         return time_flash_train(
             q, k, v, cfg, causal=causal, interpret=interpret,
-            iters=iters, repeats=repeats,
+            iters=iters, repeats=repeats, window=window,
         )
 
     cands = list(candidates) if candidates is not None else candidate_configs(t, d)
@@ -400,7 +408,7 @@ def autotune_flash(
         _, best = min(((train_time(c), c) for c in bwd_cands), key=lambda x: x[0])
 
     if cache:
-        _MEM_CACHE[_key(kind, d, t, dtype, causal)] = best
+        _MEM_CACHE[_key(kind, d, t, dtype, causal, window)] = best
         # merge existing on-disk entries before writing: a force=True tune
         # skips the read path above, and saving bare _MEM_CACHE would clobber
         # every other shape/device entry the file holds (_load_disk's

@@ -118,13 +118,15 @@ class FlashConfig:
             raise ValueError("block_q/block_k/q_span must be >= 1")
 
 
-def _resolve(config: Optional[FlashConfig], t: int, d: int, dtype, causal: bool) -> FlashConfig:
-    """``config=None`` → the tuned/default config for this shape."""
+def _resolve(
+    config: Optional[FlashConfig], t: int, d: int, dtype, causal: bool, window: Optional[int] = None
+) -> FlashConfig:
+    """``config=None`` → the tuned/default config for this shape (and window)."""
     if config is not None:
         return config
     from p2pfl_tpu.ops.autotune import get_flash_config
 
-    return get_flash_config(t, d, dtype=dtype, causal=causal)
+    return get_flash_config(t, d, dtype=dtype, causal=causal, window=window)
 
 
 def _pallas_call(kernel, *, scope_name: str, name: str, **kw):
@@ -149,7 +151,52 @@ def _compiler_params(*dims: str, vmem_limit_bytes: Optional[int] = None) -> pltp
     return pltpu.CompilerParams(dimension_semantics=dims, vmem_limit_bytes=vmem_limit_bytes)
 
 
-def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
+def _visible(rows, cols, window):
+    """Causal mask of global ``rows`` x ``cols`` (int32 iotas), and under a
+    sliding ``window`` only the ``window`` keys up to the row itself:
+    ``row - window < col <= row``."""
+    if window is None:
+        return rows >= cols
+    return (rows >= cols) & (cols > rows - window)
+
+
+def _q_side_bounds(qi, block_q, block_k, window):
+    """K-block loop bounds of q block ``qi`` under causal attention, ``(lo,
+    lo_full, n_full, n_all)``: blocks ``[lo, lo_full)`` straddle the window's
+    lower edge (masked), ``[lo_full, n_full)`` are wholly visible (no mask),
+    ``[n_full, n_all)`` straddle the diagonal (masked). Blocks before ``lo`` lie
+    wholly outside ``(row - window, row]`` of every row and are SKIPPED. Without a
+    window ``lo = lo_full = 0``: the two loops every causal program has had."""
+    n_full = lax.div(qi * block_q, block_k)
+    n_all = lax.div((qi + 1) * block_q + block_k - 1, block_k)
+    if window is None:
+        return 0, 0, n_full, n_all
+    first = qi * block_q
+    lo = lax.div(jnp.maximum(first - window + 1, 0), block_k)
+    # block j needs no window mask once its first column is inside the LAST row's window
+    lo_full = lax.div(jnp.maximum(first + block_q - window, 0) + block_k - 1, block_k)
+    return lo, jnp.clip(lo_full, lo, n_full), n_full, n_all
+
+
+def _k_side_bounds(kj, block_q, block_k, window, nq):
+    """Q-block loop bounds of k block ``kj``, ``(start, full, hi_full, end)``:
+    q blocks ``[start, full)`` straddle the diagonal (masked), ``[full, hi_full)``
+    see the whole k block (no mask), ``[hi_full, end)`` straddle the window's
+    lower edge (masked); q blocks from ``end`` on no longer reach back to this k
+    block (row >= col + window) and are SKIPPED. Without a window ``hi_full = end
+    = nq``."""
+    start = lax.div(kj * block_k, block_q)
+    full = lax.div((kj + 1) * block_k + block_q - 1, block_q)
+    if window is None:
+        return start, full, nq, nq
+    first = kj * block_k
+    end = jnp.minimum(lax.div(first + block_k + window - 2, block_q) + 1, nq)
+    full = jnp.minimum(full, end)
+    # q block i sees the whole k block while its LAST row is inside the FIRST column's reach
+    return start, full, jnp.clip(lax.div(first + window, block_q), full, end), end
+
+
+def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t, window=None):
     """Online-softmax accumulation of ONE q sub-tile against its visible
     K/V stream. Returns (acc [BQ, D] f32, m [BQ, 1] f32, l [BQ, 1] f32)."""
     dt = q.dtype
@@ -168,7 +215,7 @@ def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
         if masked:
             rows = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = j * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = jnp.where(_visible(rows, cols, window), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         if masked:
@@ -188,9 +235,10 @@ def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
         # split the stream at the causal frontier: blocks fully below the
         # diagonal skip the iota/select mask work (half the VPU ops for the
         # majority of blocks — measured 4× at D=32 where the mask dominates)
-        n_full = lax.div(qi * block_q, block_k)
-        n_all = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        acc, m, l = lax.fori_loop(0, n_full, partial(body, masked=False), (acc, m, l))
+        lo, lo_full, n_full, n_all = _q_side_bounds(qi, block_q, block_k, window)
+        if window is not None:  # the window's lower edge; a row may see nothing of such a block
+            acc, m, l = lax.fori_loop(lo, lo_full, partial(body, masked=True), (acc, m, l))
+        acc, m, l = lax.fori_loop(lo_full, n_full, partial(body, masked=False), (acc, m, l))
         acc, m, l = lax.fori_loop(n_full, n_all, partial(body, masked=True), (acc, m, l))
     else:
         acc, m, l = lax.fori_loop(
@@ -200,7 +248,7 @@ def _fwd_tile(q, k_ref, v_ref, qi, *, block_q, block_k, causal, scale, t):
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, q_span, causal, scale
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, q_span, causal, scale, window=None
 ):
     t = k_ref.shape[0]
     for s in range(q_span):  # static unroll: q_span consecutive sub-tiles
@@ -208,7 +256,7 @@ def _flash_kernel(
         q = q_ref[pl.ds(s * block_q, block_q), :]  # [BQ, D]
         acc, m, l = _fwd_tile(
             q, k_ref, v_ref, qi, block_q=block_q, block_k=block_k,
-            causal=causal, scale=scale, t=t,
+            causal=causal, scale=scale, t=t, window=window,
         )
         o_ref[pl.ds(s * block_q, block_q), :] = (
             acc / jnp.maximum(l, 1e-30)
@@ -227,7 +275,7 @@ def _row(ref, i, block_q):
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, block_q, block_k, causal, scale
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, block_q, block_k, causal, scale, window=None
 ):
     qi = pl.program_id(2)
     t = k_ref.shape[0]
@@ -248,7 +296,7 @@ def _dq_kernel(
         if masked:
             rows = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = j * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = jnp.where(_visible(rows, cols, window), s, NEG_INF)
         p = jnp.exp(s - lse)  # masked entries underflow to 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -259,9 +307,10 @@ def _dq_kernel(
         )
 
     if causal:
-        n_full = lax.div(qi * block_q, block_k)
-        n_all = lax.div((qi + 1) * block_q + block_k - 1, block_k)
-        dq = lax.fori_loop(0, n_full, partial(body, masked=False), dq)
+        lo, lo_full, n_full, n_all = _q_side_bounds(qi, block_q, block_k, window)
+        if window is not None:
+            dq = lax.fori_loop(lo, lo_full, partial(body, masked=True), dq)
+        dq = lax.fori_loop(lo_full, n_full, partial(body, masked=False), dq)
         dq = lax.fori_loop(n_full, n_all, partial(body, masked=True), dq)
     else:
         dq = lax.fori_loop(0, t // block_k, partial(body, masked=False), dq)
@@ -270,7 +319,7 @@ def _dq_kernel(
 
 def _dkv_step(
     i, dk, dv, *, q_ref, do_ref, lse_ref, delta_ref, k, v, kj,
-    block_q, block_k, scale, dt, masked, dq_acc=None,
+    block_q, block_k, scale, dt, masked, dq_acc=None, window=None,
 ):
     """One q-block's contribution to (dK_j, dV_j) — the body shared by the
     split ``_dkv_kernel`` and the fused ``_dkvq_kernel``, which adds only
@@ -285,7 +334,7 @@ def _dkv_step(
     if masked:
         rows = i * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         cols = kj * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        s = jnp.where(_visible(rows, cols, window), s, NEG_INF)
     p = jnp.exp(s - lse)  # [BQ, BK]
     dv = dv + jax.lax.dot_general(
         p.astype(dt), do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -306,7 +355,7 @@ def _dkv_step(
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, block_q, block_k, causal, scale,
+    *, block_q, block_k, causal, scale, window=None,
 ):
     kj = pl.program_id(2)
     t = q_ref.shape[0]
@@ -321,16 +370,17 @@ def _dkv_kernel(
         return _dkv_step(
             i, *carry, q_ref=q_ref, do_ref=do_ref, lse_ref=lse_ref,
             delta_ref=delta_ref, k=k, v=v, kj=kj, block_q=block_q,
-            block_k=block_k, scale=scale, dt=q_ref.dtype, masked=masked,
+            block_k=block_k, scale=scale, dt=q_ref.dtype, masked=masked, window=window,
         )
 
     if causal:
         # q blocks strictly before the frontier never see this K block; q
         # blocks fully past the diagonal band see all of it (no mask needed)
-        start = lax.div(kj * block_k, block_q)
-        full = lax.div((kj + 1) * block_k + block_q - 1, block_q)
+        start, full, hi_full, end = _k_side_bounds(kj, block_q, block_k, window, n_blocks)
         dk, dv = lax.fori_loop(start, full, partial(body, masked=True), (dk, dv))
-        dk, dv = lax.fori_loop(full, n_blocks, partial(body, masked=False), (dk, dv))
+        dk, dv = lax.fori_loop(full, hi_full, partial(body, masked=False), (dk, dv))
+        if window is not None:  # the window's lower edge, seen from the key side
+            dk, dv = lax.fori_loop(hi_full, end, partial(body, masked=True), (dk, dv))
     else:
         dk, dv = lax.fori_loop(0, n_blocks, partial(body, masked=False), (dk, dv))
     dk_ref[:] = dk.astype(dk_ref.dtype)
@@ -339,7 +389,7 @@ def _dkv_kernel(
 
 def _dkvq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_ref,
-    dq_acc, *, block_q, block_k, causal, scale,
+    dq_acc, *, block_q, block_k, causal, scale, window=None,
 ):
     """Single-pass backward: dK/dV per k-block AND dQ in one sweep.
 
@@ -374,14 +424,15 @@ def _dkvq_kernel(
             i, *carry, q_ref=q_ref, do_ref=do_ref, lse_ref=lse_ref,
             delta_ref=delta_ref, k=k, v=v, kj=kj, block_q=block_q,
             block_k=block_k, scale=scale, dt=q_ref.dtype, masked=masked,
-            dq_acc=dq_acc,
+            dq_acc=dq_acc, window=window,
         )
 
     if causal:
-        start = lax.div(kj * block_k, block_q)
-        full = lax.div((kj + 1) * block_k + block_q - 1, block_q)
+        start, full, hi_full, end = _k_side_bounds(kj, block_q, block_k, window, nq)
         dk, dv = lax.fori_loop(start, full, partial(body, masked=True), (dk, dv))
-        dk, dv = lax.fori_loop(full, nq, partial(body, masked=False), (dk, dv))
+        dk, dv = lax.fori_loop(full, hi_full, partial(body, masked=False), (dk, dv))
+        if window is not None:
+            dk, dv = lax.fori_loop(hi_full, end, partial(body, masked=True), (dk, dv))
     else:
         dk, dv = lax.fori_loop(0, nq, partial(body, masked=False), (dk, dv))
     dk_ref[:] = dk.astype(dk_ref.dtype)
@@ -405,15 +456,31 @@ def _specs(block_q, block_k, t, d, q_span: int = 1):
     return qspec, kvfull, lse_row
 
 
-def _flash_fwd_bthd(q, k, v, *, block_q, block_k, q_span, causal, interpret):
+def _windowed(window, **kw) -> dict:
+    """Kernel keywords, with ``window`` only when there is one: a program
+    without a window is built from exactly the partial it has always had."""
+    return kw if window is None else dict(kw, window=window)
+
+
+def _names(window, scope_name: str, name: str) -> dict:
+    """Scope and kernel name of a call; a windowed call carries its own
+    (``flash_win_fwd`` / ``p2pfl_flash_win_fwd``…) so that a trace tells a
+    sliding layer from a full one."""
+    if window is None:
+        return {"scope_name": scope_name, "name": name}
+    return {"scope_name": scope_name.replace("flash_", "flash_win_"), "name": name.replace("flash_", "flash_win_")}
+
+
+def _flash_fwd_bthd(q, k, v, *, block_q, block_k, q_span, causal, interpret, window=None):
     """q,k,v: [B, H, T, D] → (out [B, H, T, D], lse [B, H, 1, T] f32)."""
     b, h, t, d = q.shape
     scale = d ** -0.5
     grid = (b, h, t // (block_q * q_span))
     qspec, kvfull, lse_row = _specs(block_q, block_k, t, d, q_span)
     kernel = partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, q_span=q_span,
-        causal=causal, scale=scale,
+        _flash_kernel, **_windowed(
+            window, block_q=block_q, block_k=block_k, q_span=q_span, causal=causal, scale=scale
+        )
     )
     return _pallas_call(
         kernel,
@@ -428,8 +495,7 @@ def _flash_fwd_bthd(q, k, v, *, block_q, block_k, q_span, causal, interpret):
         # block: the q-group dim must not be megacore-split ('arbitrary')
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-        scope_name="flash_fwd",
-        name="p2pfl_flash_fwd",
+        **_names(window, "flash_fwd", "p2pfl_flash_fwd"),
     )(q, k, v)
 
 
@@ -444,15 +510,16 @@ def _bwd_use_fused(t: int, d: int, mode: str) -> bool:
     return t * d * 4 <= _FUSED_SCRATCH_LIMIT
 
 
-def _fused_vmem_limit(d: int) -> Optional[int]:
-    """Scoped-VMEM limit of the fused backward. Heads up to 128 wide fit the
-    compiler's 16 MiB default at every shipped shape and keep it (their
-    programs do not change). At D = 256 / T = 4096 the resident q, dO and dQ
-    blocks (2 MiB each, double-buffered) and the 4 MiB dQ scratch pass it by
-    0.8 MiB (host-only compile for a v5e), so wider heads ask for 32 MiB of
-    the chip's 128 — which keeps the 5-matmul kernel instead of the 7-matmul
-    split pair."""
-    return 32 * 1024 * 1024 if d > 128 else None
+def _fused_vmem_limit(t: int, d: int) -> Optional[int]:
+    """Scoped-VMEM limit of the fused backward. Heads up to 128 wide at up to
+    4096 tokens fit the compiler's 16 MiB default at every shipped shape and
+    keep it (their programs do not change). At D = 256 / T = 4096 the resident
+    q, dO and dQ blocks (2 MiB each, double-buffered) and the 4 MiB dQ scratch
+    pass it by 0.8 MiB, and at D = 128 / T = 8192 — the same bytes: the scratch
+    is exactly ``_FUSED_SCRATCH_LIMIT`` — by 1.0 MiB (host-only compiles for a
+    v5e), so those ask for 32 MiB of the chip's 128 — which keeps the 5-matmul
+    kernel instead of the 7-matmul split pair."""
+    return 32 * 1024 * 1024 if t * d > 4096 * 128 else None
 
 
 def _dq_scratch(t: int, d: int):
@@ -460,18 +527,17 @@ def _dq_scratch(t: int, d: int):
     return [pltpu.VMEM((t, d), jnp.float32)]
 
 
-def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interpret, bwd_mode):
+def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interpret, bwd_mode, window=None):
     b, h, t, d = q.shape
     scale = d ** -0.5
+    kw = _windowed(window, block_q=block_q, block_k=block_k, causal=causal, scale=scale)
     qspec, kvfull, lse_row = _specs(block_q, block_k, t, d)
     qfull = pl.BlockSpec((None, None, t, d), lambda bi, hi, i: (bi, hi, 0, 0))
     kvspec = pl.BlockSpec((None, None, block_k, d), lambda bi, hi, j: (bi, hi, j, 0))
 
     if _bwd_use_fused(t, d, bwd_mode):
         dk, dv, dq = _pallas_call(
-            partial(
-                _dkvq_kernel, block_q=block_q, block_k=block_k, causal=causal, scale=scale
-            ),
+            partial(_dkvq_kernel, **kw),
             grid=(b, h, t // block_k),
             in_specs=[qfull, kvspec, kvspec, qfull, lse_row, lse_row],
             out_specs=[kvspec, kvspec, qfull],
@@ -486,11 +552,10 @@ def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interp
             # encodes the requirement instead of relying on the default
             # semantics happening to serialize (advisor round-5)
             compiler_params=_compiler_params(
-                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(d)
+                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(t, d)
             ),
             interpret=interpret,
-            scope_name="flash_bwd",
-            name="p2pfl_flash_bwd_fused",
+            **_names(window, "flash_bwd", "p2pfl_flash_bwd_fused"),
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -498,19 +563,18 @@ def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interp
     # full blocks — every grid dim is safely parallel (megacore-splittable)
     split_params = _compiler_params("parallel", "parallel", "parallel")
     dq = _pallas_call(
-        partial(_dq_kernel, block_q=block_q, block_k=block_k, causal=causal, scale=scale),
+        partial(_dq_kernel, **kw),
         grid=(b, h, t // block_q),
         in_specs=[qspec, kvfull, kvfull, qspec, lse_row, lse_row],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=split_params,
         interpret=interpret,
-        scope_name="flash_bwd",
-        name="p2pfl_flash_bwd_dq",
+        **_names(window, "flash_bwd", "p2pfl_flash_bwd_dq"),
     )(q, k, v, do, lse, delta)
 
     dk, dv = _pallas_call(
-        partial(_dkv_kernel, block_q=block_q, block_k=block_k, causal=causal, scale=scale),
+        partial(_dkv_kernel, **kw),
         grid=(b, h, t // block_k),
         in_specs=[qfull, kvspec, kvspec, qfull, lse_row, lse_row],
         out_specs=[kvspec, kvspec],
@@ -520,18 +584,27 @@ def _flash_bwd_bthd(q, k, v, do, lse, delta, *, block_q, block_k, causal, interp
         ],
         compiler_params=split_params,
         interpret=interpret,
-        scope_name="flash_bwd",
-        name="p2pfl_flash_bwd_dkv",
+        **_names(window, "flash_bwd", "p2pfl_flash_bwd_dkv"),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(
     q, k, v, causal: bool = True, config: Optional[FlashConfig] = None,
-    interpret: bool = False,
+    interpret: bool = False, window: Optional[int] = None,
 ):
     """Flash attention. q,k,v: [B, T, H, D] (GQA heads pre-repeated).
+
+    ``window`` (static, causal only): query ``i`` sees keys ``i - window < j <=
+    i`` — ``window`` keys, itself included. K/V blocks wholly outside that band
+    are SKIPPED (forward from the q side, both backwards from the k side) and
+    only the blocks that straddle the band's lower edge or the diagonal are
+    masked, so a sliding layer pays for the pairs it sees. ``window >= T`` is
+    plain causal attention and ``window=None`` IS the program there has always
+    been: the window is a static argument, nothing of it is traced. Windowed
+    calls run under their own scopes and kernel names (``p2pfl.flash_win_fwd``
+    / ``p2pfl_flash_win_fwd``, …) and resolve their own schedule (the window
+    is part of the autotune key).
 
     ``config`` is the STATIC kernel schedule (:class:`FlashConfig` —
     forward/backward block shapes, q ownership, backward mode); it is a
@@ -548,7 +621,19 @@ def flash_attention(
     block-size-independent ``[B, H, 1, T]`` row layout, so the backward
     re-blocks freely without relayout.
     """
-    out, _ = _fwd(q, k, v, causal, config, interpret)
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: a sliding window is causal (window=… with causal=False)")
+        if window < 1:
+            raise ValueError(f"flash_attention: window {window} (a row sees at least itself)")
+        if window >= q.shape[1]:
+            window = None  # every earlier key is inside it
+    return _flash_attention(q, k, v, causal, config, interpret, window)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, config, interpret, window):
+    out, _ = _fwd(q, k, v, causal, config, interpret, window)
     return out
 
 
@@ -565,15 +650,15 @@ def _fit_q_span(t: int, block_q: int, q_span: int) -> int:
     return next(s for s in range(min(q_span, nq), 0, -1) if nq % s == 0)
 
 
-def _fwd(q, k, v, causal, config, interpret):
+def _fwd(q, k, v, causal, config, interpret, window):
     t, d = q.shape[1], q.shape[-1]
-    cfg = _resolve(config, t, d, q.dtype, causal)
+    cfg = _resolve(config, t, d, q.dtype, causal, window)
     block_q, block_k = _clamp_blocks(t, cfg.block_q, cfg.block_k)
     q_span = _fit_q_span(t, block_q, cfg.q_span)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out, lse = _flash_fwd_bthd(
         qt, kt, vt, block_q=block_q, block_k=block_k, q_span=q_span,
-        causal=causal, interpret=interpret,
+        causal=causal, interpret=interpret, window=window,
     )
     return out.transpose(0, 2, 1, 3), (q, k, v, out, lse)
 
@@ -601,10 +686,10 @@ def _bwd_blocks(t: int, d: int, cfg: FlashConfig) -> tuple[int, int]:
     return bq, bk
 
 
-def _bwd(causal, config, interpret, res, g):
+def _bwd(causal, config, interpret, window, res, g):
     q, k, v, out_bhtd, lse = res
     t, d = q.shape[1], q.shape[-1]
-    cfg = _resolve(config, t, d, q.dtype, causal)
+    cfg = _resolve(config, t, d, q.dtype, causal, window)
     bq, bk = _bwd_blocks(t, d, cfg)
     b, h = out_bhtd.shape[:2]
     do = g.transpose(0, 2, 1, 3)  # [B, H, T, D]
@@ -625,11 +710,12 @@ def _bwd(causal, config, interpret, res, g):
         causal=causal,
         interpret=interpret,
         bwd_mode=cfg.bwd_mode,
+        window=window,
     )
     return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dv))
 
 
-flash_attention.defvjp(_fwd, _bwd)
+_flash_attention.defvjp(_fwd, _bwd)
 
 
 # ---- offset-aware variants: flash blocks inside ring attention ----
@@ -965,7 +1051,7 @@ def _fab_bwd(config, interpret, res, cts):
             scratch_shapes=_dq_scratch(t, d),
             # sequential k-block accumulation into dq_acc — see _dkvq_kernel
             compiler_params=_compiler_params(
-                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(d)
+                "parallel", "parallel", "arbitrary", vmem_limit_bytes=_fused_vmem_limit(t, d)
             ),
             interpret=interpret,
             scope_name="flash_bwd",

@@ -91,7 +91,9 @@ def _tiles_per_group(group_sizes: jax.Array, tile_m: int) -> jax.Array:
     return (group_sizes + (tile_m - 1)) // tile_m
 
 
-def group_layout(group_of: jax.Array, n_groups: int, tile_m: int = DEFAULT_TILE_M) -> GroupLayout:
+def group_layout(
+    group_of: jax.Array, n_groups: int, tile_m: int = DEFAULT_TILE_M, first_group: int = 0, spare_tile: bool = False
+) -> GroupLayout:
     """Lay ``group_of`` (``[M]`` int: the group of each assignment) out by group,
     assignments of one group in their order (a stable sort's answer), each group
     padded to whole row tiles. Nothing is sorted — the layout is COUNTED: an
@@ -101,19 +103,40 @@ def group_layout(group_of: jax.Array, n_groups: int, tile_m: int = DEFAULT_TILE_
     unique indices: ``assignment_of_slot``. (The sorted layout's three gathers
     of ``M`` scalars and its three scatters cost 0.74 ms a call on the chip, its
     sort 0.08: PERF.md, PR 36.) Deterministic: the same ``group_of`` gives the
-    same rows, which is what lets remat's re-forward repeat the forward."""
+    same rows, which is what lets remat's re-forward repeat the forward.
+
+    A HELD SHARE: the ``n_groups`` groups laid out are ``first_group ..
+    first_group + n_groups - 1`` of a larger numbering, and an assignment to any
+    other group is ABSENT — it belongs to no group, counts in no size and owns
+    no row. ``spare_tile`` appends one row tile that no group owns; an absent
+    assignment's ``slot_of_assignment`` is its last row, which is padding
+    whatever the split (``assignment_of_slot`` says ``M`` there): zero going in,
+    past the used tiles of the matmul so zero coming out. Without absent
+    assignments neither argument is needed and the layout is what it has been."""
     m = group_of.shape[0]
-    rows = tile_m * n_row_tiles(m, n_groups, tile_m)
-    mine = jnp.arange(n_groups, dtype=jnp.int32)[:, None] == group_of.astype(jnp.int32)[None, :]
+    rows = tile_m * (n_row_tiles(m, n_groups, tile_m) + int(spare_tile))
+    groups = jnp.arange(n_groups, dtype=jnp.int32)
+    if first_group:
+        groups = first_group + groups
+    mine = groups[:, None] == group_of.astype(jnp.int32)[None, :]
     through = jnp.cumsum(mine, axis=1, dtype=jnp.int32)  # [g, a]: assignments of g through a
     sizes = through[:, -1]
     tiles = _tiles_per_group(sizes, tile_m)
     padded_start = tile_m * (jnp.cumsum(tiles) - tiles)
     slot_of_assignment = jnp.sum(jnp.where(mine, padded_start[:, None] + through - 1, 0), axis=0)
-    assignment_of_slot = jnp.full((rows,), m, jnp.int32).at[slot_of_assignment].set(
-        jnp.arange(m, dtype=jnp.int32), unique_indices=True, mode="promise_in_bounds"
+    if not spare_tile:
+        assignment_of_slot = jnp.full((rows,), m, jnp.int32).at[slot_of_assignment].set(
+            jnp.arange(m, dtype=jnp.int32), unique_indices=True, mode="promise_in_bounds"
+        )
+        return GroupLayout(sizes, slot_of_assignment, assignment_of_slot, rows)
+    present = jnp.any(mine, axis=0)
+    # an absent assignment scatters nowhere (past the rows, each to an index of its
+    # own: dropped, and still unique) and reads the spare tile's last row
+    every = jnp.arange(m, dtype=jnp.int32)
+    assignment_of_slot = jnp.full((rows,), m, jnp.int32).at[jnp.where(present, slot_of_assignment, rows + every)].set(
+        every, unique_indices=True, mode="drop"
     )
-    return GroupLayout(sizes, slot_of_assignment, assignment_of_slot, rows)
+    return GroupLayout(sizes, jnp.where(present, slot_of_assignment, rows - 1), assignment_of_slot, rows)
 
 
 def tiles_and_fetches(group_sizes: jax.Array, tile_m: int) -> tuple[jax.Array, jax.Array]:

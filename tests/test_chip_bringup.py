@@ -58,12 +58,13 @@ def test_flash_fwd_bwd_lowers_for_mosaic(t, d):
 
 def test_wide_heads_ask_for_more_scoped_vmem_in_the_fused_backward_only():
     """D = 256 / T = 4096: the fused backward's resident blocks pass the 16 MiB
-    default by 0.8 MiB (host-only compile, PR 31); heads up to 128 keep the
-    default, so their kernels are the ones they were."""
+    default by 0.8 MiB (host-only compile, PR 31), and D = 128 / T = 8192 — the
+    same bytes — by 1.0 MiB (PR 37); heads up to 128 at up to 4096 tokens keep
+    the default, so their kernels are the ones they were."""
     from p2pfl_tpu.ops.flash_attention import _fused_vmem_limit
 
-    assert _fused_vmem_limit(64) is None and _fused_vmem_limit(128) is None
-    assert _fused_vmem_limit(256) == 32 * 1024 * 1024
+    assert _fused_vmem_limit(4096, 64) is None and _fused_vmem_limit(4096, 128) is None and _fused_vmem_limit(8192, 64) is None
+    assert _fused_vmem_limit(4096, 256) == _fused_vmem_limit(8192, 128) == 32 * 1024 * 1024
     assert autotune.flash_config_source(4096, 256, kind="TPU v5 lite")[1] == "defaults"
 
 

@@ -6,6 +6,8 @@ old module-global ``BWD_MODE`` is gone; see test_kernel_config.py for the
 jit cache-key / staleness coverage).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -256,3 +258,141 @@ def test_fused_bwd_offs_matches_split():
                 np.asarray(a), np.asarray(b), atol=1e-5,
                 err_msg=f"offsets ({q_off}, {k_off})",
             )
+
+
+# ---- sliding window: blocks outside it skipped, the two edges masked ---------------
+
+
+def _window_grads(t, window, block_q, block_k, mode, q_span=1, heads=2, kv_heads=None, d=16):
+    """(loss, gradients) of windowed flash and of dense masked attention in
+    float32, against a random cotangent; ``kv_heads``: GQA, K/V repeated as
+    ``Attention`` repeats them."""
+    kv_heads = kv_heads or heads
+    keys = jax.random.split(jax.random.PRNGKey(t + window), 4)
+    q, g = (jax.random.normal(s, (1, t, heads, d), jnp.float32) for s in keys[:2])
+    k, v = (jax.random.normal(s, (1, t, kv_heads, d), jnp.float32) for s in keys[2:])
+    cfg = FlashConfig(block_q=block_q, block_k=block_k, q_span=q_span, bwd_mode=mode)
+    rep = lambda a: jnp.repeat(a, heads // kv_heads, axis=2)  # noqa: E731
+    flash = lambda q_, k_, v_: jnp.sum(flash_attention(q_, rep(k_), rep(v_), True, cfg, True, window) * g)  # noqa: E731
+    dense = lambda q_, k_, v_: jnp.sum(causal_attention(q_, rep(k_), rep(v_), window=window) * g)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(flash, (0, 1, 2))(q, k, v), jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("mode", ["fused", "split"])
+@pytest.mark.parametrize(
+    "t,window,block_q,block_k",
+    [
+        (64, 8, 16, 16),  # W < block
+        (64, 1, 16, 16),  # a row sees itself alone
+        (64, 24, 16, 16),  # W no multiple of a block
+        (64, 16, 16, 16),  # W = one block
+        (64, 17, 16, 32),  # unlike blocks
+        (48, 20, 16, 16),  # T = 3 blocks
+        (32, 5, 16, 16),  # T = 2 blocks
+        (64, 64, 16, 16),  # W = T: plain causal
+        (64, 100, 16, 16),  # W > T: plain causal
+    ],
+)
+def test_windowed_flash_matches_dense_masked_attention(t, window, block_q, block_k, mode):
+    (got, got_grads), (want, want_grads) = _window_grads(t, window, block_q, block_k, mode)
+    assert abs(float(got - want)) < 1e-3
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+@pytest.mark.parametrize("mode", ["fused", "split"])
+def test_windowed_flash_under_gqa_8_to_1_and_a_q_span(mode):
+    (got, got_grads), (want, want_grads) = _window_grads(64, 33, 16, 16, mode, q_span=2, heads=8, kv_heads=1)
+    assert abs(float(got - want)) < 1e-3
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 16), (16, 32), (32, 16), (8, 24)])
+@pytest.mark.parametrize("window", [1, 5, 16, 24, 33, 47])
+def test_window_bounds_skip_every_invisible_block_and_mask_only_the_edges(window, block_q, block_k):
+    """The loop bounds of both sides against a brute-force ``[T, T]`` mask: a
+    block is visited iff a pair of it is visible (nothing outside the window is
+    streamed), and it runs unmasked iff every pair of it is visible — or was
+    conservatively sent to a masked loop, never the other way round."""
+    from p2pfl_tpu.ops.flash_attention import _k_side_bounds, _q_side_bounds
+
+    t = 96
+    rows, cols = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (cols <= rows) & (cols > rows - window)
+    nq, nk = t // block_q, t // block_k
+    tile = lambda i, j: seen[i * block_q:(i + 1) * block_q, j * block_k:(j + 1) * block_k]  # noqa: E731
+    for i in range(nq):
+        lo, lo_full, n_full, n_all = (int(b) for b in _q_side_bounds(jnp.int32(i), block_q, block_k, window))
+        assert lo <= lo_full <= n_full <= n_all
+        assert [j for j in range(nk) if tile(i, j).any()] == list(range(lo, min(n_all, nk)))
+        assert all(tile(i, j).all() for j in range(lo_full, n_full))
+    for j in range(nk):
+        start, full, hi_full, end = (int(b) for b in _k_side_bounds(jnp.int32(j), block_q, block_k, window, nq))
+        assert start <= full <= hi_full <= end <= nq
+        assert [i for i in range(nq) if tile(i, j).any()] == list(range(start, end))
+        assert all(tile(i, j).all() for i in range(full, hi_full))
+    # and the pairs a sliding layer sees are what the benchmark's floor counts
+    from benchmark.flops_window_moe import visible_pairs
+
+    assert int(seen.sum()) == visible_pairs(t, window)
+
+
+def test_a_window_is_causal_static_and_named():
+    q, k, v = _qkv(t=64)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, False, FlashConfig(16, 16), True, 8)
+    with pytest.raises(ValueError, match="itself"):
+        flash_attention(q, k, v, True, FlashConfig(16, 16), True, 0)
+    grad = lambda w: jax.grad(lambda q_: jnp.sum(flash_attention(q_, k, v, True, FlashConfig(16, 16), True, w)))  # noqa: E731
+    names = {w: sorted(set(re.findall(r"p2pfl_flash_[a-z_]+", str(jax.make_jaxpr(grad(w))(q))))) for w in (None, 8, 64)}
+    assert names[None] == names[64] == ["p2pfl_flash_bwd_fused", "p2pfl_flash_fwd"]  # W >= T is plain causal
+    assert names[8] == ["p2pfl_flash_win_bwd_fused", "p2pfl_flash_win_fwd"]  # the scopes: tests/test_scope_trace.py
+
+
+def test_the_window_keys_the_schedule():
+    from p2pfl_tpu.ops import autotune
+
+    autotune.clear_memory_cache()
+    try:
+        assert autotune._key("cpu", 64, 256, jnp.float32, True) == "cpu|d=64|t=256|float32|causal"  # as it has always been
+        assert autotune._key("cpu", 64, 256, jnp.float32, True, 32) == "cpu|d=64|t=256|float32|causal|w=32"
+        pinned = FlashConfig(block_q=64, block_k=32)
+        autotune.pin_flash_config(256, 64, pinned, kind="cpu", window=32)
+        assert autotune.flash_config_source(256, 64, kind="cpu", window=32) == (pinned, "pin")
+        assert autotune.flash_config_source(256, 64, kind="cpu")[1] == "defaults"  # the pin is the window's alone
+        assert autotune.flash_config_source(8192, 128, kind="TPU v5 lite", window=2048)[1] == "defaults"
+    finally:
+        autotune.clear_memory_cache()
+
+
+def test_ring_attention_refuses_a_window():
+    from p2pfl_tpu.ops.attention import ring_attention
+
+    q, k, v = _qkv(t=64)
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        ring_attention(q, k, v, mesh=None, axis_name="model", window=16)
+
+
+@pytest.mark.parametrize("mode", ["fused", "split"])
+def test_without_a_window_the_lowered_text_is_the_parents(mode):
+    """``window=None`` is the program there was: forward and both backwards,
+    interpreted (the kernels' own jaxprs as XLA ops), hash for hash what the
+    parent commit lowered (``tests/fixtures/flash_lowered_parent.json``,
+    recorded on that commit before ``flash_attention.py`` was edited). The
+    Mosaic lowering of the same jaxprs carries source lines in its payload and
+    cannot be compared this way."""
+    import hashlib
+    import json
+    from pathlib import Path
+
+    want = json.loads((Path(__file__).parent / "fixtures" / "flash_lowered_parent.json").read_text())[mode]
+    cfg = FlashConfig(block_q=128, block_k=128, bwd_mode=mode)
+    q = jnp.zeros((1, 512, 2, 64), jnp.bfloat16)
+
+    def loss(q_, k_, v_):
+        return jnp.sum(flash_attention(q_, k_, v_, True, cfg, True).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest(), len(text)) == (want["sha256"], want["chars"])

@@ -25,7 +25,8 @@ ROUND_SCOPES = ("grad", "optimizer", "fold")
 SSM_SCOPES = ("ssm_conv", "ssm_scan_fwd", "ssm_scan_bwd")  # a Mamba layer's; read by readers/scope_named.py
 MOE_SCOPES = ("mla", "moe_route", "moe_experts", "moe_gmm", "moe_combine")  # latent attention's and an expert layer's; same reader
 CONV_SCOPES = ("short_conv", "qk_norm")  # the gated short convolution's, and the per-head q / k norms'; same reader
-LM_SCOPES = ("head",)  # every CausalLM's: the tied head and the loss
+LM_SCOPES = ("head",)  # every CausalLM's: the head and the loss
+WINDOW_SCOPES = ("flash_win_fwd", "flash_win_bwd", "attn_gate", "post_norm")  # a sliding layer's kernels, the output gate, the sandwich's second norms; same reader
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
@@ -82,6 +83,19 @@ def _conv_expert_federation():
     return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
 
 
+def _window_expert_federation():
+    cfg = TransformerConfig(
+        vocab_size=256, dim=64, n_layers=6, n_heads=4, n_kv_heads=2, head_dim=32, ffn_hidden=160, rope_theta=1e4,
+        leading_pattern=("swa_dense", "swa_dense"), layer_pattern=("swa_experts", "full_experts"), qk_norm=True,
+        attn_window=32, attn_gate=True, post_norms=True, embed_scale=8.0, tie_head=False,
+        lora_rank=4, lora_mlp=True, remat=True, scan_layers=True, norm_eps=1e-5, routed_experts=8, experts_held=4,
+        first_expert=4, experts_per_token=2, expert_hidden=32, shared_experts=1, expert_tile_m=8,
+    )
+    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
+    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
+    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
+
+
 def _lower_spmd_round(fed):
     from p2pfl_tpu.parallel.spmd import spmd_round
 
@@ -102,16 +116,20 @@ def compiled_op_names():
         "spmd_lora_hybrid": _hybrid_federation().lower_round(epochs=1),
         "spmd_lora_moe": _expert_federation().lower_round(epochs=1),
         "spmd_lora_conv_moe": _conv_expert_federation().lower_round(epochs=1),
+        "spmd_lora_window_moe": _window_expert_federation().lower_round(epochs=1),
     }
     return {engine: set(OP_NAME.findall(low.compile().as_text())) for engine, low in lowered.items()}
 
 
 @pytest.mark.parametrize(
     "engine,scope",
-    [("spmd", s) for s in ROUND_SCOPES] + [("spmd_lora", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + MOE_SCOPES + CONV_SCOPES]
-    + [("spmd_lora_hybrid", s) for s in DEVICE_SCOPES if s not in MOE_SCOPES + CONV_SCOPES]
-    + [("spmd_lora_moe", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + CONV_SCOPES]
-    + [("spmd_lora_conv_moe", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + ("mla",)],
+    [("spmd", s) for s in ROUND_SCOPES]
+    + [("spmd_lora", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + MOE_SCOPES + CONV_SCOPES + WINDOW_SCOPES]
+    + [("spmd_lora_hybrid", s) for s in DEVICE_SCOPES if s not in MOE_SCOPES + CONV_SCOPES + WINDOW_SCOPES]
+    + [("spmd_lora_moe", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + CONV_SCOPES + WINDOW_SCOPES]
+    + [("spmd_lora_conv_moe", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + ("mla",) + WINDOW_SCOPES]
+    # sliding AND full layers in one period: the windowed kernels' scopes beside the plain ones'
+    + [("spmd_lora_window_moe", s) for s in DEVICE_SCOPES if s not in SSM_SCOPES + ("mla", "short_conv")],
 )
 def test_scope_is_in_the_compiled_round(compiled_op_names, engine, scope):
     assert any(f"p2pfl.{scope}" in name for name in compiled_op_names[engine])
@@ -128,7 +146,7 @@ def test_forward_reforward_backward_fall_out_of_the_grad_scope(compiled_op_names
 def test_scope_names_are_spelled_in_one_place():
     import p2pfl_tpu
 
-    named = (ROUND_SCOPES, scope_reduce.SUB_SHARES, SSM_SCOPES, MOE_SCOPES, CONV_SCOPES, LM_SCOPES)
+    named = (ROUND_SCOPES, scope_reduce.SUB_SHARES, SSM_SCOPES, MOE_SCOPES, CONV_SCOPES, LM_SCOPES, WINDOW_SCOPES)
     assert {s for group in named for s in group} == set(DEVICE_SCOPES)
     sources = Path(p2pfl_tpu.__file__).parent.rglob("*.py")
     assert [p.name for p in sources if '"p2pfl."' in p.read_text()] == ["profiling.py"]
