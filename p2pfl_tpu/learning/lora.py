@@ -86,8 +86,8 @@ def _lm_forward(lora, base, module, x, y):
     ``[T, vocab]`` array whole; the logits the plain way beside it, a dead value
     that the compiler removes from a program that drops them (every training
     round does); what the model sowed into ``"moe_stats"`` —
-    each name's mean over the layers that sowed it, ``{}`` for a model that
-    sows none (an expert layer's ``load_max_over_mean``); and the
+    each name's mean over the layers that sowed it, ``{}`` for a model that sows none
+    (an expert layer's ``load_max_over_mean``, ``rows_used_share``, ``held_share``); and the
     ``"moe_routing"`` collection as sown (the experts each row chose, from THIS
     forward — a comparison must not take them from another program: a TPU
     rounds a near-tie differently from one compiled program to the next)."""

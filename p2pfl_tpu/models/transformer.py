@@ -666,59 +666,116 @@ def _token_rows(x, token_of_row):
     return _rows_at(jnp.pad(x, ((0, 1), (0, 0))), token_of_row)
 
 
-def _slab_sum(rows, row_of_assignment, weights=None):
+# A gather's source of at most this many bytes is one the TPU compiler keeps in VMEM
+# (a v5e has 128 MiB of it): a slab out of a 100 MB source takes 0.053 ms on the chip,
+# out of 123 MB or more 0.286 — the index pattern moves that by a fifth, the source's
+# extent by five times. In the Trinity cell 104 MiB still stayed there in all three
+# passes and 112 MiB fell out in the backward; 96 keeps a margin (PERF.md, PR 38)
+_GATHER_HEAD_BYTES = 96 * 1024 * 1024
+
+
+@jax.custom_batching.custom_vmap
+def _for_all(flag):
+    return flag
+
+
+@_for_all.def_vmap
+def _for_all_vmap(axis_size, in_batched, flag):
+    """ONE answer for the whole batch, unbatched: a ``lax.cond`` on it stays a
+    branch under ``vmap`` (a batched predicate makes it a select: both sides run)."""
+    return jnp.all(flag), False
+
+
+@jax.jit
+def _slabs(source, index):
+    """``k`` gathers of an ``[S, D]`` slab each (``index``: ``[k, S]``). Jitted for
+    the tracer's sake alone: every expert layer, pass and branch of one model asks
+    for the same shapes, and each is traced once."""
+    return tuple(_rows_at(source, index[j]) for j in range(index.shape[0]))
+
+
+def _slab_sum(rows, row_of_assignment, weights=None, in_use=None, tail=0):
     """``Σ_j weights[j] · rows[row_of_assignment[j]]`` in float32: ``k`` gathers
     of an ``[S, D]`` slab each, added as they lie — no ``[S, k, D]`` array, whose
-    ``k`` would sit in the sublanes of a tile."""
-    total = None
-    for j in range(row_of_assignment.shape[0]):
-        slab = _rows_at(rows, row_of_assignment[j]).astype(jnp.float32)
-        if weights is not None:
-            slab = weights[j][:, None] * slab
-        total = slab if total is None else total + slab
-    return total
+    ``k`` would sit in the sublanes of a tile.
+
+    ``in_use`` (a traced count) and ``tail`` (static) say where the indices CAN
+    point: under ``in_use`` or into the last ``tail`` rows — what a layout's used
+    tiles and its spare tile are. Where ``rows`` is larger than
+    ``_GATHER_HEAD_BYTES`` and those two stretches fit inside it, they are copied
+    next to each other first (one pass, into VMEM) and the ``k`` slabs gathered
+    out of the copy: the same rows in the same order, so the same sum to the last
+    bit. Else, and always for a smaller ``rows``, straight out of ``rows``."""
+
+    def sum_from(source, index):
+        total = None
+        for j, slab in enumerate(_slabs(source, index)):
+            slab = slab.astype(jnp.float32)
+            if weights is not None:
+                slab = weights[j][:, None] * slab
+            total = slab if total is None else total + slab
+        return total
+
+    n = rows.shape[0]
+    head = _GATHER_HEAD_BYTES // (rows.shape[1] * rows.dtype.itemsize)
+    if in_use is None or n <= head or head <= tail:
+        return sum_from(rows, row_of_assignment)
+
+    def from_head():
+        # the first `head` rows with the last `tail` rows written over their end: one fused pass
+        # (a concatenation of the two stretches copied the first to HBM before it: 0.44 ms for 0.13)
+        source = jax.lax.dynamic_update_slice(rows[:head], rows[n - tail :], (head - tail, 0))
+        moved = jnp.where(row_of_assignment >= n - tail, row_of_assignment - (n - head), row_of_assignment)
+        return sum_from(source, moved)
+
+    return jax.lax.cond(_for_all(in_use <= head - tail), from_head, lambda: sum_from(rows, row_of_assignment))
 
 
-@jax.custom_vjp
-def _to_expert_rows(x, token_of_row, row_of_assignment):
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _to_expert_rows(x, token_of_row, row_of_assignment, in_use=None, tail=0):
     """``[S, D]`` tokens -> ``[rows, D]`` in the grouped layout (padding rows
     zero). A gather forward AND backward: the cotangent of a token is the sum
     of its ``k`` rows', read back through ``row_of_assignment`` (``[k, S]``,
-    assignment-major)."""
-    del row_of_assignment
+    assignment-major; ``in_use`` / ``tail``: :func:`_slab_sum`)."""
+    del row_of_assignment, in_use, tail
     return _token_rows(x, token_of_row)
 
 
-def _to_expert_rows_fwd(x, token_of_row, row_of_assignment):
-    return _to_expert_rows(x, token_of_row, row_of_assignment), row_of_assignment
+def _to_expert_rows_fwd(x, token_of_row, row_of_assignment, in_use, tail):
+    return _to_expert_rows(x, token_of_row, row_of_assignment, in_use, tail), (row_of_assignment, in_use)
 
 
-def _to_expert_rows_bwd(row_of_assignment, g):
+def _to_expert_rows_bwd(tail, res, g):
+    row_of_assignment, in_use = res
     with scope("moe_experts"):
-        return _slab_sum(g, row_of_assignment).astype(g.dtype), None, None
+        return _slab_sum(g, row_of_assignment, None, in_use, tail).astype(g.dtype), None, None, None
 
 
 _to_expert_rows.defvjp(_to_expert_rows_fwd, _to_expert_rows_bwd)
 
 
-@jax.custom_vjp
-def _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row, in_use=None, tail=0):
     """``y[s] = Σ_j weights[s, j] · rows[row_of_assignment[j, s]]`` — float32
     sum, result in ``rows``' dtype. Gathers both ways: a row's cotangent is its
     token's, times its weight (zero for padding rows)."""
     del token_of_row, assignment_of_row
-    return _slab_sum(rows, row_of_assignment, weights.T).astype(rows.dtype)
+    return _slab_sum(rows, row_of_assignment, weights.T, in_use, tail).astype(rows.dtype)
 
 
-def _from_expert_rows_fwd(rows, weights, row_of_assignment, token_of_row, assignment_of_row):
-    out = _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row)
+def _from_expert_rows_fwd(rows, weights, row_of_assignment, token_of_row, assignment_of_row, in_use, tail):
+    out = _from_expert_rows(rows, weights, row_of_assignment, token_of_row, assignment_of_row, in_use, tail)
     return out, (rows, weights, row_of_assignment, token_of_row, assignment_of_row)
 
 
-def _from_expert_rows_bwd(res, g):
+def _from_expert_rows_bwd(tail, res, g):
     """``rows`` is read ONCE, in row order: a weight's cotangent
     ``⟨rows[row of (s, j)], g[s]⟩`` is the row's own dot with its token's
-    cotangent — ``g_rows``, made for ``d_rows`` anyway — gathered as a scalar."""
+    cotangent — ``g_rows``, made for ``d_rows`` anyway — gathered as a scalar.
+    A row no assignment names may hold anything (the kernel leaves tiles past
+    the used count unwritten): it stays inside its own ``dot_of_row``, which
+    nothing gathers."""
+    del tail
     rows, weights, row_of_assignment, token_of_row, assignment_of_row = res
     with scope("moe_combine"):
         weight_of_row = jnp.take(weights.reshape(-1), assignment_of_row, mode="fill", fill_value=0)
@@ -726,7 +783,7 @@ def _from_expert_rows_bwd(res, g):
         d_rows = (weight_of_row[:, None] * g_rows).astype(rows.dtype)
         dot_of_row = jnp.sum(rows.astype(jnp.float32) * g_rows, axis=-1)
         d_weights = _rows_at(dot_of_row, row_of_assignment).T
-    return d_rows, d_weights.astype(weights.dtype), None, None, None
+    return d_rows, d_weights.astype(weights.dtype), None, None, None, None
 
 
 _from_expert_rows.defvjp(_from_expert_rows_fwd, _from_expert_rows_bwd)
@@ -765,13 +822,21 @@ class ExpertFFN(nn.Module):
     over ALL ``k`` chosen — are the whole model's; the banks are ``[experts_held,
     ...]``; only an assignment to a held expert gets a row. The layout keeps the
     static worst case (every assignment held) plus ONE spare row tile that no
-    group owns: an absent assignment reads its last row, zero going in (padding),
-    zero coming out of the matmul (a tile past the used count) and with a zero
-    cotangent both ways, so it costs no tile and needs no mask. Nothing is
-    exchanged: the partial sum (+ the shared expert, whole) is the layer's output.
+    group owns: an absent assignment reads a row of it — its token's, so a slab's
+    absent indices are spread over the tile — zero going in (padding), zero
+    coming out of the matmul (the call's last tile, the one tile past the used
+    count that the kernel writes) and with a zero cotangent both ways, so it costs
+    no tile and needs no mask. What the static rows cost is paid by rows in use
+    where it can be: the kernel writes no tile nobody owns, and the slab sums
+    gather out of a copy of the used rows and the spare tile while those fit
+    VMEM (:func:`_slab_sum`). Nothing is exchanged: the partial sum (+ the
+    shared expert, whole) is the layer's output.
 
     Sows ``moe_stats/load_max_over_mean`` — rows on the fullest (held) expert over the
-    even share ``S k / E`` — from the group sizes the matmul takes anyway,
+    even share ``S k / E`` — and ``moe_stats/rows_used_share`` — the rows of the
+    used tiles, ``tile_m · Σ_g ceil(size_g / tile_m)``, over the layout's static
+    rows: the share of a grouped-matmul call's tiles that are multiplied and
+    written — both from the group sizes the matmul takes anyway,
     ``moe_stats/held_share`` — assignments computed here over ``S k`` (1 when
     every expert is held) — where a share is held, and
     ``moe_routing/chosen``, the ``[S, k]`` experts themselves.
@@ -782,7 +847,7 @@ class ExpertFFN(nn.Module):
 
     @nn.compact
     def __call__(self, x, bank=None):
-        from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul
+        from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, tiles_and_fetches
 
         cfg = self.cfg
         e, k, f = cfg.routed_experts, cfg.experts_per_token, cfg.expert_hidden
@@ -806,26 +871,30 @@ class ExpertFFN(nn.Module):
             # re-forward all follow the forward's choice
             chosen = checkpoint_name(choose_experts(s_, bias, k), "moe_chosen")
             weights = routing_weights(s_, chosen, cfg.routed_scale)
-            layout = group_layout(chosen.reshape(-1), held, cfg.expert_tile_m, *share)
+            layout = group_layout(chosen.reshape(-1), held, cfg.expert_tile_m, *share, per_token=k)
             # assignment-major: whatever is indexed by assignment is k slabs of S, never [S, k, D]
             row_of_assignment = layout.slot_of_assignment.reshape(s, k).T
             token_of_row = jnp.where(
                 layout.assignment_of_slot < s * k, layout.assignment_of_slot // k, s
             )  # s: a padding row reads the zero row `_token_rows` appends
             load = jnp.max(layout.group_sizes).astype(jnp.float32) / (s * k / e)
+            # rows of the used tiles: every assignment's row lies under it or in the layout's last tile
+            in_use = cfg.expert_tile_m * tiles_and_fetches(layout.group_sizes, cfg.expert_tile_m)[0]
+            reach = (in_use, cfg.expert_tile_m)
         self.sow("moe_stats", "load_max_over_mean", load)
+        self.sow("moe_stats", "rows_used_share", in_use.astype(jnp.float32) / layout.rows)
         if held < e:
             self.sow("moe_stats", "held_share", jnp.sum(layout.group_sizes).astype(jnp.float32) / (s * k))
         self.sow("moe_routing", "chosen", chosen)  # for whoever compares assignments (tests, the benchmark's check)
         with scope("moe_experts"):
-            rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, row_of_assignment)
+            rows = _to_expert_rows(xs.astype(cfg.dtype), token_of_row, row_of_assignment, *reach)
             h = gmm(rows, w13, layout.group_sizes)
             h = nn.silu(h[:, :f]) * h[:, f:]
             out = gmm(h, w2, layout.group_sizes)
         shared = MLP(cfg, cfg.shared_experts * f, name="shared")(x) if cfg.shared_experts else None
         with scope("moe_combine"):
             y = _from_expert_rows(
-                out, weights, row_of_assignment, token_of_row, layout.assignment_of_slot
+                out, weights, row_of_assignment, token_of_row, layout.assignment_of_slot, *reach
             ).reshape(b, t, d)
             return y if shared is None else y + shared
 
@@ -1061,23 +1130,31 @@ class _ScanPeriod(nn.Module):
         return x, None
 
 
-def sown_by_layer(cfg: TransformerConfig, sown) -> jax.Array:
+def sown_by_layer(cfg: TransformerConfig, sown, name: Optional[str] = None) -> jax.Array:
     """What the expert layers of a :class:`CausalLM` sowed under one name —
-    ``mut["moe_routing"]`` or ``mut["moe_stats"]`` as ``apply`` hands it out —
+    ``mut["moe_routing"]`` or ``mut["moe_stats"]`` as ``apply`` hands it out;
+    ``name`` picks one of several a layer sows (``"load_max_over_mean"``) —
     as ONE array ``[expert layers, ...]`` in the order the layers run. Scanned,
     each expert run of the period sows its own leaf, stacked along the period
     scan and, for a run of several layers, its own scan: the runs interleave by
     period. Unrolled, ``layer_<i>`` sows layer ``i``'s."""
+
+    def one(tree):
+        (leaf,) = [
+            leaf for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if name is None or any(getattr(key, "key", None) == name for key in path)
+        ]  # fmt: skip
+        return leaf
+
     if not cfg.scan_layers:
-        at = sorted((int(name.split("_")[1]), name) for name in sown)
-        return jnp.stack([jax.tree.leaves(sown[name])[0] for _, name in at])
+        at = sorted((int(layer.split("_")[1]), layer) for layer in sown)
+        return jnp.stack([one(sown[layer]) for _, layer in at])
     if len(cfg.layer_pattern) == 1:
-        (leaf,) = jax.tree.leaves(sown)
-        return leaf  # [periods, ...]: a period is a layer
+        return one(sown)  # [periods, ...]: a period is a layer
     per_period = []
     for i, (kind, count) in enumerate(layer_runs(cfg.layer_pattern)):
         if _is_expert_run(kind):
-            (leaf,) = jax.tree.leaves(sown["layers"][f"run{i}_{kind}"])
+            leaf = one(sown["layers"][f"run{i}_{kind}"])
             per_period.append(leaf if count > 1 else leaf[:, None])  # [periods, count, ...]
     stacked = jnp.concatenate(per_period, axis=1)
     return stacked.reshape(-1, *stacked.shape[2:])

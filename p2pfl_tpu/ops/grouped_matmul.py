@@ -26,9 +26,15 @@ tile, 6-12 µs, against a 7-15 MB copy of 9-18 µs at 819 GB/s, so every group
 boundary stalled, 10-13 % of a call at both expert cells' shapes.) Which fetch
 each tile multiplies, the group of each fetch and the counts of both
 (:func:`tiles_and_fetches`) are prefetched scalars. Tiles past the used count
-are not multiplied (they are written as zeros) and fetch nothing; a call's
-first block is the one fetch nothing hides. Everywhere else (the CPU tests,
-``impl="xla"``) the product is ``lax.ragged_dot`` over the same layout.
+are not multiplied, fetch nothing and are NOT WRITTEN: every grid step past the
+count names the call's last tile, one resident block that is zeroed once and
+flushed once — the one place past the used tiles that anyone reads (a held
+share's absent assignments: :func:`group_layout`). Until PR 38 each was written
+as zeros: 400 of 544 tiles a call where a chip holds a quarter of the experts,
+a quarter of the kernel's time (PERF.md). A call's first block is the one fetch
+nothing hides. Everywhere else (the CPU tests, ``impl="xla"``) the product is
+``lax.ragged_dot`` over the same layout, which leaves every row past the groups
+zero.
 
 A stack of banks ``[L, G, K, N]`` — the layers of a scanned run — is read in
 place as well: ``layer`` is one more prefetched scalar and the copy's source is
@@ -92,7 +98,12 @@ def _tiles_per_group(group_sizes: jax.Array, tile_m: int) -> jax.Array:
 
 
 def group_layout(
-    group_of: jax.Array, n_groups: int, tile_m: int = DEFAULT_TILE_M, first_group: int = 0, spare_tile: bool = False
+    group_of: jax.Array,
+    n_groups: int,
+    tile_m: int = DEFAULT_TILE_M,
+    first_group: int = 0,
+    spare_tile: bool = False,
+    per_token: int = 1,
 ) -> GroupLayout:
     """Lay ``group_of`` (``[M]`` int: the group of each assignment) out by group,
     assignments of one group in their order (a stable sort's answer), each group
@@ -109,10 +120,15 @@ def group_layout(
     first_group + n_groups - 1`` of a larger numbering, and an assignment to any
     other group is ABSENT — it belongs to no group, counts in no size and owns
     no row. ``spare_tile`` appends one row tile that no group owns; an absent
-    assignment's ``slot_of_assignment`` is its last row, which is padding
-    whatever the split (``assignment_of_slot`` says ``M`` there): zero going in,
-    past the used tiles of the matmul so zero coming out. Without absent
-    assignments neither argument is needed and the layout is what it has been."""
+    assignment's ``slot_of_assignment`` is a row of THAT tile, picked by its
+    token (assignment ``a`` is token ``a // per_token``'s: consecutive tokens
+    read consecutive rows, ``tile_m`` tokens apart the same one). Every row of
+    the spare tile is padding whatever the split (``assignment_of_slot`` says
+    ``M`` there): zero going in, and — the call's last tile — zero coming out of
+    the matmul. (Until PR 38 every absent assignment read the tile's last row:
+    three quarters of a slab's indices at one 4 KB address cost the gather a
+    fifth of its time on the chip, PERF.md.) Without absent assignments none of
+    the three arguments is needed and the layout is what it has been."""
     m = group_of.shape[0]
     rows = tile_m * (n_row_tiles(m, n_groups, tile_m) + int(spare_tile))
     groups = jnp.arange(n_groups, dtype=jnp.int32)
@@ -131,12 +147,13 @@ def group_layout(
         return GroupLayout(sizes, slot_of_assignment, assignment_of_slot, rows)
     present = jnp.any(mine, axis=0)
     # an absent assignment scatters nowhere (past the rows, each to an index of its
-    # own: dropped, and still unique) and reads the spare tile's last row
+    # own: dropped, and still unique) and reads its token's row of the spare tile
     every = jnp.arange(m, dtype=jnp.int32)
     assignment_of_slot = jnp.full((rows,), m, jnp.int32).at[jnp.where(present, slot_of_assignment, rows + every)].set(
         every, unique_indices=True, mode="drop"
     )
-    return GroupLayout(sizes, jnp.where(present, slot_of_assignment, rows - 1), assignment_of_slot, rows)
+    spare_row = rows - tile_m + (every // per_token) % tile_m
+    return GroupLayout(sizes, jnp.where(present, slot_of_assignment, spare_row), assignment_of_slot, rows)
 
 
 def tiles_and_fetches(group_sizes: jax.Array, tile_m: int) -> tuple[jax.Array, jax.Array]:
@@ -222,7 +239,9 @@ def _gmm_kernel(
             lhs_ref[...], ring[lax.rem(f, _RING_SLOTS)].astype(lhs_ref.dtype), contract, preferred_element_type=jnp.float32
         ).astype(out_ref.dtype)
 
-    @pl.when(i >= used)
+    # every step past the used count names the call's LAST tile (`out_map`): one
+    # resident block, zeroed once and flushed once; the tiles between are not written
+    @pl.when(i == used)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -252,6 +271,10 @@ def _gmm_pallas(lhs, rhs, layer, group_sizes, tile_m: int, transpose_rhs: bool, 
         # an unused tile re-names the last used one: no new fetch
         return jnp.minimum(i, jnp.maximum(counts_ref[0] - 1, 0)), 0
 
+    def out_map(j, i, layer_ref, tile_fetch_ref, fetch_group_ref, counts_ref):
+        # a tile nobody owns is not written: the run of unused steps holds the call's last tile
+        return jnp.where(i < counts_ref[0], i, n_tiles - 1), j
+
     return pl.pallas_call(
         partial(_gmm_kernel, transpose_rhs=transpose_rhs, n_cols=out // bn),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -261,7 +284,7 @@ def _gmm_pallas(lhs, rhs, layer, group_sizes, tile_m: int, transpose_rhs: bool, 
             grid=(out // bn, n_tiles),
             # the bank stays in HBM: the kernel copies a matrix block a group into the ring
             in_specs=[pl.BlockSpec((tile_m, contract), lhs_map), pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((tile_m, bn), lambda j, i, *_: (i, j)),
+            out_specs=pl.BlockSpec((tile_m, bn), out_map),
             scratch_shapes=[
                 pltpu.VMEM((_RING_SLOTS, bn, n) if transpose_rhs else (_RING_SLOTS, k, bn), rhs.dtype),
                 pltpu.SemaphoreType.DMA((_RING_SLOTS,)),
@@ -344,13 +367,17 @@ def grouped_matmul(
 ) -> jax.Array:
     """``out[r] = lhs[r] @ rhs[g(r)]`` (``@ rhs[g(r)]ᵀ`` with ``transpose_rhs``)
     for rows in the layout of :func:`group_layout` (same ``tile_m``); padding
-    rows and unused tiles come out zero. ``lhs``: ``[rows, K]`` (``[rows, N]``
+    rows inside a used tile and the call's last tile come out zero; other
+    unused tiles are not written (the kernel; ``lax.ragged_dot`` writes zeros
+    there) and, going in, are not read — whoever consumes the result reads an
+    assignment's row or the last tile, or keeps what it reads of a row inside
+    that row. ``lhs``: ``[rows, K]`` (``[rows, N]``
     transposed), ``rhs``: ``[G, K, N]`` in any float dtype — it is read as it
     is stored, never cast — or a stack ``[L, G, K, N]`` with ``layer`` (an int32
     scalar, traced or not) naming the bank to use. Differentiable in ``lhs``
     only. ``impl``: ``None`` picks the Mosaic kernel on a TPU and
     ``lax.ragged_dot`` elsewhere; ``"xla"`` / ``"pallas"`` force one (the kernel
-    interpreted off-TPU)."""
+    interpreted off-TPU: the interpreter shows an unwritten tile as NaN)."""
     if (rhs.ndim == 4) != (layer is not None):
         raise ValueError("grouped_matmul: a stack of banks [L, G, K, N] comes with `layer`, one bank [G, K, N] without")
     if layer is None:

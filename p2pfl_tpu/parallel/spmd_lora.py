@@ -309,9 +309,9 @@ class SpmdLoraFederation(SpmdFederation):
         with dispatch_span("spmd_lora_round", "spmd", nodes=self.n, epochs=epochs, fed=id(self)):
             self.params, self.opt_state, loss, stats = spmd_lora_round(*args, **statics)
         self.round += 1
-        # ``stats``: device scalars the model sowed, averaged over the round's
-        # steps and trained nodes (an expert model's ``moe_load_max_over_mean``);
-        # ``{}`` otherwise. Nothing here fetches them.
+        # ``stats``: device scalars the model sowed, averaged over the round's steps and trained nodes (an
+        # expert model's ``moe_load_max_over_mean``, ``moe_rows_used_share`` and, of a held share,
+        # ``moe_held_share``); ``{}`` otherwise. Nothing here fetches them.
         entry = {"round": self.round, "train_loss": loss, **stats}
         self.history.append(entry)
         return entry

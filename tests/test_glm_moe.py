@@ -22,7 +22,7 @@ from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.lora import _lm_forward, _lm_loss, merge_params, split_lora
 from p2pfl_tpu.models.transformer import (
     CausalLM, ExpertFFN, MLAttention, TransformerConfig, _from_expert_rows, _rows_at, _to_expert_rows, choose_experts,
-    layer_runs, router_scores, routing_weights, tiny_transformer,
+    layer_runs, router_scores, routing_weights, sown_by_layer, tiny_transformer,
 )
 from p2pfl_tpu.ops import grouped_matmul as gmm_ops
 from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles, tiles_and_fetches
@@ -125,7 +125,9 @@ def test_grouped_matmul_matches_einsum_forward_and_input_cotangent(case, impl):
     out = ours(rows, rhs)
     np.testing.assert_allclose(out[layout.slot_of_assignment], jnp.einsum("mk,mkn->mn", x, dense), rtol=1e-5, atol=1e-5)
     padding = np.setdiff1d(np.arange(layout.rows), np.asarray(layout.slot_of_assignment))
-    assert not np.asarray(out)[padding].any()  # padding rows and unused tiles are zeros, not garbage
+    if impl == "pallas":  # the kernel writes the used tiles and the call's last tile; ragged_dot every row
+        padding = padding[_defined_rows(layout.rows, np.asarray(layout.group_sizes), tile)[padding]]
+    assert not np.asarray(out)[padding].any()  # padding rows of a used tile and the last tile are zeros, not garbage
     probe = jax.random.normal(jax.random.PRNGKey(2), out.shape, jnp.float32)
     d_rows, d_rhs = jax.grad(lambda r, w: jnp.sum(ours(r, w) * probe), argnums=(0, 1))(rows, rhs)
     want = jnp.einsum("mn,mkn->mk", probe[layout.slot_of_assignment], dense)
@@ -364,6 +366,28 @@ def _product_by_tile(lhs, rhs, sizes, face: bool):
     return out
 
 
+def _defined_rows(rows: int, sizes, tile: int) -> np.ndarray:
+    """``[rows]`` bool: the rows ``grouped_matmul``'s contract defines — the used
+    tiles (each group's rows and the zero rows that pad its last tile) and the
+    call's LAST tile (zeros where it is not a used one). From PR 38 the kernel
+    does not write the tiles between: the interpreter leaves NaN there."""
+    used = int(np.sum(-(-np.asarray(sizes) // tile)))
+    defined = np.arange(rows) < used * tile
+    defined[rows - tile:] = True
+    return defined
+
+
+def _defined(out, sizes, tile: int = RING_TILE):
+    """``out`` (``[..., rows, N]``, one ``sizes`` for all or one an element) with
+    the rows outside the contract put to zero — what the parent's kernel wrote there."""
+    out = np.array(out, np.float32)
+    many = np.ndim(sizes) == 2
+    for b, each in enumerate(sizes if many else [sizes]):
+        keep = _defined_rows(out.shape[-2], each, tile)
+        (out[b] if many else out)[..., ~keep, :] = 0.0
+    return out
+
+
 def _digest(out) -> str:
     out = np.asarray(out)
     return hashlib.sha256(str((out.shape, out.dtype.name)).encode() + np.asarray(out, np.float32).tobytes()).hexdigest()
@@ -373,9 +397,12 @@ def _digest(out) -> str:
 @pytest.mark.parametrize("face", ["forward", "cotangent"])
 @pytest.mark.parametrize("case", list(RING_SIZES))
 def test_grouped_matmul_kernel_fetches_each_groups_matrix_for_its_tiles(case, face, blocks, monkeypatch):
-    """The interpreted kernel against a tile-by-tile numpy product, and its whole
-    output — padding rows and unused tiles too — bit-equal to what the parent's
-    step-ahead kernel gave on these operands (``tests/fixtures/gmm_parent_outputs.json``).
+    """The interpreted kernel against a tile-by-tile numpy product, and its
+    output bit-equal to what the parent's step-ahead kernel gave on these
+    operands (``tests/fixtures/gmm_parent_outputs.json``) over the rows the
+    contract still defines: the used tiles — padding rows too — and the call's
+    last tile. The tiles between, which that kernel wrote as zeros and this one
+    does not write, are put to zero before the digest (``_defined``).
     ``column_split``: a matrix block limit of 64 KB, so two column blocks a matrix
     and the ring keyed by (column block, group)."""
     sizes, transposed = RING_SIZES[case], face == "cotangent"
@@ -385,8 +412,9 @@ def test_grouped_matmul_kernel_fetches_each_groups_matrix_for_its_tiles(case, fa
     lhs, rhs = _ring_operands(sizes, transposed)
     out = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), tile_m=RING_TILE, transpose_rhs=transposed, impl="pallas")
     assert out.dtype == lhs.dtype
-    np.testing.assert_array_equal(np.asarray(out, np.float32), _product_by_tile(lhs, rhs, sizes, transposed))
-    assert _digest(out) == RING_DIGESTS[f"{case}.{face}.{blocks}"]
+    defined = _defined_rows(lhs.shape[0], sizes, RING_TILE)
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[defined], _product_by_tile(lhs, rhs, sizes, transposed)[defined])
+    assert _digest(jnp.asarray(_defined(out, sizes), out.dtype)) == RING_DIGESTS[f"{case}.{face}.{blocks}"]
 
 
 @pytest.mark.parametrize("face", ["forward", "cotangent"])
@@ -402,6 +430,7 @@ def test_grouped_matmul_kernel_in_a_scan_reads_the_traced_layers_bank(face):
         return None, out
 
     _, outs = jax.jit(lambda: jax.lax.scan(step, None, jnp.arange(3, dtype=jnp.int32)))()
+    outs = jnp.asarray(_defined(outs, sizes), outs.dtype)  # the rows the contract defines
     for layer in range(3):
         np.testing.assert_array_equal(np.asarray(outs[layer], np.float32), _product_by_tile(lhs, stack[layer], sizes, transposed))
     assert _digest(outs) == RING_DIGESTS[f"scan.{face}"]
@@ -418,6 +447,7 @@ def test_grouped_matmul_kernel_under_vmap_with_an_unmapped_bank(face, axis_size)
     outs = jax.vmap(
         lambda rows, group_sizes: grouped_matmul(rows, rhs, group_sizes, tile_m=RING_TILE, transpose_rhs=transposed, impl="pallas")
     )(lhs, jnp.asarray(sizes, jnp.int32))
+    outs = jnp.asarray(_defined(outs, np.asarray(sizes)), outs.dtype)  # the rows the contract defines, element by element
     for out, rows, group_sizes in zip(outs, lhs, sizes):
         np.testing.assert_array_equal(np.asarray(out, np.float32), _product_by_tile(rows, rhs, group_sizes, transposed))
     assert _digest(outs) == RING_DIGESTS[f"vmap{axis_size}.{face}"]
@@ -432,6 +462,132 @@ def test_tiles_and_fetches_counts_what_a_call_multiplies_and_fetches():
             tiles = [-(-size // tile) for size in sizes]
             got = tiles_and_fetches(jnp.asarray(sizes, jnp.int32), tile)
             assert [int(v) for v in got] == [sum(tiles), sum(t > 0 for t in tiles)]
+
+
+# what the kernel writes, case by case: (group sizes, row tiles of the call) in tiles of 8
+WRITTEN_CASES = {
+    "a_spare_tile_and_unused_tiles_before_it": ([9, 0, 20, 3], n_row_tiles(32, 4, 8) + 1),
+    "no_spare_tile_and_unused_tiles": ([9, 0, 20, 3], n_row_tiles(32, 4, 8)),
+    "every_tile_used": ([8, 8, 8, 8, 5], 5),  # used == n_tiles: the index map is the identity, nothing is zeroed
+    "every_tile_used_but_the_spare": ([8, 8, 8, 8, 5], 6),  # used == n_tiles - 1: the one unused step IS the last tile
+    "one_tile_used": ([0, 0, 3, 0], n_row_tiles(3, 4, 8) + 1),
+    "every_group_empty": ([0, 0, 0, 0], 4),  # used == 0: every step names the last tile
+}
+
+
+@pytest.mark.parametrize("face", ["forward", "cotangent"])
+@pytest.mark.parametrize("case", list(WRITTEN_CASES))
+def test_grouped_matmul_kernel_writes_the_used_tiles_and_the_calls_last_tile(case, face):
+    """The contract from PR 38: the used tiles (padding rows zero) and the call's
+    LAST tile (zero) are ``lax.ragged_dot``'s rows; the tiles between are not
+    written — the interpreter shows them as NaN, which is how this test knows
+    that a step past the used count names the last tile and no other."""
+    sizes, n_tiles = WRITTEN_CASES[case]
+    transposed, tile, g, rows = face == "cotangent", RING_TILE, len(sizes), RING_TILE * n_tiles
+    used = sum(-(-size // tile) for size in sizes)
+    assert used == {"every_tile_used": n_tiles, "every_tile_used_but_the_spare": n_tiles - 1, "one_tile_used": 1, "every_group_empty": 0}.get(case, 6)
+    whole = lambda key, shape: jax.random.randint(jax.random.PRNGKey(key), shape, -2, 3).astype(jnp.bfloat16)  # noqa: E731
+    lhs, rhs = whole(1, (rows, 256)), whole(2, (g, 256, 256))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    got = grouped_matmul(lhs, rhs, group_sizes, tile_m=tile, transpose_rhs=transposed, impl="pallas")
+    want = grouped_matmul(lhs, rhs, group_sizes, tile_m=tile, transpose_rhs=transposed, impl="xla")
+    defined = _defined_rows(rows, sizes, tile)
+    assert defined.sum() == min(used + 1, n_tiles) * tile
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[defined], np.asarray(want, np.float32)[defined])
+    assert not np.asarray(want, np.float32)[used * tile:].any()  # ragged_dot: every row past the used tiles is zero
+    if used < rows // tile:
+        assert not np.asarray(got, np.float32)[rows - tile:].any()  # the last tile: zeros
+    assert np.isnan(np.asarray(got, np.float32)[~defined]).all()  # the tiles between: not written
+
+
+def _poisoned(real, tile):
+    """``grouped_matmul`` with NaN written into every row the contract does not
+    define — of ``lhs`` going in, of the product coming out, and (the same
+    function's derivative) of the cotangent on its way back, before and after
+    the product's own rule."""
+
+    @jax.custom_vjp
+    def poison(x, used_rows):
+        row = jnp.arange(x.shape[0])[:, None]
+        return jnp.where((row >= used_rows) & (row < x.shape[0] - tile), jnp.nan, x)
+
+    poison.defvjp(lambda x, used_rows: (poison(x, used_rows), used_rows), lambda used_rows, g: (poison(g, used_rows), None))
+
+    def product(lhs, rhs, group_sizes, **kw):
+        used_rows = tile * tiles_and_fetches(group_sizes, tile)[0]
+        return poison(real(poison(lhs, used_rows), rhs, group_sizes, **kw), used_rows)
+
+    return product
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("routing", ["seeded", "nothing_held_is_chosen"])
+def test_no_reader_of_an_unwritten_row_under_a_held_share(routing, impl, monkeypatch):
+    """Every row past the used tiles but the last tile's poisoned with NaN — the
+    rows of the first product, ``h`` between the products, both cotangents —
+    through ``ExpertFFN`` holding experts 2..5 of 8: the output, the router's
+    gradient (through ``d_weights``) and the input's cotangent are finite and
+    equal the clean run's (``ragged_dot``, zeros everywhere)."""
+    cfg = config(experts_held=4, first_expert=2, expert_impl=impl, shared_experts=0)
+    layer, params, h = _expert_layer(cfg)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(9), (8,))
+    if routing == "nothing_held_is_chosen":  # used == 0: every assignment reads the spare tile
+        bias = jnp.array([9.0, 9.0, 0, 0, 0, 0, 0, 0])
+    params = dict(params, router_bias=bias)
+    probe = jax.random.normal(jax.random.PRNGKey(5), h.shape)
+
+    def value_and_grads(layer_):
+        loss = lambda p, h_: jnp.sum(layer_.apply({"params": p}, h_) * probe)  # noqa: E731
+        return jax.value_and_grad(loss, argnums=(0, 1))(params, h)
+
+    want, (want_params, want_h) = value_and_grads(ExpertFFN(dataclasses.replace(cfg, expert_impl="xla")))
+    monkeypatch.setattr(gmm_ops, "grouped_matmul", _poisoned(gmm_ops.grouped_matmul, cfg.expert_tile_m))
+    got, (got_params, got_h) = value_and_grads(layer)
+    assert np.isfinite(float(got)) and float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-6)
+    assert np.isfinite(np.asarray(got_params["router"])).all()
+    np.testing.assert_allclose(got_params["router"], want_params["router"], rtol=1e-4, atol=1e-6)
+    assert np.isfinite(np.asarray(got_h)).all()
+    np.testing.assert_allclose(got_h, want_h, rtol=1e-4, atol=1e-6)
+    if routing == "seeded":
+        assert np.asarray(want_params["router"]).any() and np.asarray(want_h).any()
+
+
+@pytest.mark.parametrize("axis_size", [None, 1, 3])
+@pytest.mark.parametrize("reach", ["the_head_holds_them", "rows_in_use_pass_the_head"])
+def test_slab_sum_out_of_a_copied_head_is_the_plain_sum_to_the_bit(reach, axis_size, monkeypatch):
+    """A source over ``_GATHER_HEAD_BYTES`` whose used rows and last tile fit
+    inside it: the slabs come out of a copy of those two stretches — the same
+    rows, the same order, so bit-equal to the straight gathers — and straight out
+    of the source when they do not fit. Under ``vmap`` the choice is still ONE
+    ``cond`` (a batched predicate would make it a select and run both sides)."""
+    from p2pfl_tpu.models import transformer as tf
+
+    s, k, d, tile, n = 37, 4, 16, 8, 160
+    monkeypatch.setattr(tf, "_GATHER_HEAD_BYTES", 64 * d * 4)  # a head of 64 rows
+    batch = axis_size or 1
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    in_use = jnp.full((batch,), 56 if reach == "the_head_holds_them" else 120, jnp.int32)
+    low = jax.random.randint(keys[0], (batch, k, s), 0, in_use[0])
+    last = n - tile + jax.random.randint(keys[1], (batch, k, s), 0, tile)
+    index = jnp.where(jax.random.bernoulli(keys[2], 0.6, (batch, k, s)), last, low).astype(jnp.int32)  # most read the last tile
+    rows = jax.random.normal(keys[1], (batch, n, d), jnp.float32)
+    weights = jax.random.uniform(keys[2], (batch, k, s), jnp.float32, 0.1, 1.0)
+    ours = lambda r, i, w, u: tf._slab_sum(r, i, w, u, tile)  # noqa: E731
+    plain = lambda r, i, w: tf._slab_sum(r, i, w)  # noqa: E731
+    if axis_size is None:  # both compiled: op by op the CPU rounds a product and a sum apart, a fusion together
+        got, want = jax.jit(ours)(rows[0], index[0], weights[0], in_use[0]), jax.jit(plain)(rows[0], index[0], weights[0])
+        jaxpr = jax.make_jaxpr(ours)(rows[0], index[0], weights[0], in_use[0])
+    else:
+        got, want = jax.jit(jax.vmap(ours))(rows, index, weights, in_use), jax.jit(jax.vmap(plain))(rows, index, weights)
+        jaxpr = jax.make_jaxpr(jax.vmap(ours))(rows, index, weights, in_use)
+    np.testing.assert_array_equal(got, want)
+    names = [eqn.primitive.name for eqn in _equations(jaxpr.jaxpr)]
+    assert names.count("cond") == 1
+    # a source under the limit, or no word on where the indices point: no cond at all
+    assert "cond" not in [eqn.primitive.name for eqn in _equations(jax.make_jaxpr(plain)(rows[0], index[0], weights[0]).jaxpr)]
+    monkeypatch.setattr(tf, "_GATHER_HEAD_BYTES", n * d * 4)
+    again = lambda r, i, w, u: tf._slab_sum(r, i, w, u, tile)  # noqa: E731 (a new function: a traced one is cached)
+    assert "cond" not in [eqn.primitive.name for eqn in _equations(jax.make_jaxpr(again)(rows[0], index[0], weights[0], in_use[0]).jaxpr)]
 
 
 # ---- the router ----------------------------------------------------------------
@@ -700,10 +856,16 @@ def test_the_statistic_leaves_the_layer_scan_and_the_loss(glm):
     (chosen,) = jax.tree.leaves(routing)
     assert chosen.shape == (1, 3, 2 * SEQ, 2)  # the experts each row chose, stacked along the period and the run
     _, mut = model.module.apply({"params": merge_params(base, lora)}, x, mutable=["moe_stats"])
-    (per_layer,) = jax.tree.leaves(mut)
-    assert per_layer.shape == (1, 3)  # one period, three expert layers
-    assert set(stats) == {"moe_load_max_over_mean"}
-    assert float(stats["moe_load_max_over_mean"]) == pytest.approx(float(per_layer.mean()))
+    sown = sown_by_layer(model.module.cfg, mut["moe_stats"], "rows_used_share")
+    per_layer = {path[-2].key: leaf for path, leaf in jax.tree_util.tree_leaves_with_path(mut)}  # .../<name>/0: sown values are tuples
+    assert {name: leaf.shape for name, leaf in per_layer.items()} == {"load_max_over_mean": (1, 3), "rows_used_share": (1, 3)}  # one period, three expert layers
+    assert sown.shape == (3,) and set(stats) == {"moe_load_max_over_mean", "moe_rows_used_share"}
+    np.testing.assert_array_equal(sown, per_layer["rows_used_share"].reshape(-1))  # in layer order
+    assert float(stats["moe_load_max_over_mean"]) == pytest.approx(float(per_layer["load_max_over_mean"].mean()))
+    # the tiles a grouped-matmul call writes over the tiles it has: every assignment has a row, a group at most one part-filled tile
+    rows = 8 * n_row_tiles(2 * SEQ * 2, 8, 8)
+    assert 2 * SEQ * 2 / rows <= float(stats["moe_rows_used_share"]) <= 1.0
+    assert float(stats["moe_rows_used_share"]) == pytest.approx(float(per_layer["rows_used_share"].mean()))
     assert float(loss) == pytest.approx(float(_lm_loss(lora, base, model.module, x, y)[0]))
     dense = tiny_transformer(seq_len=SEQ, cfg=TransformerConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, ffn_hidden=128))
     d_lora, d_base = split_lora(dense.params)

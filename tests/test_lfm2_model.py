@@ -263,7 +263,7 @@ def test_statistics_and_routing_come_out_in_layer_order_over_both_expert_runs(lf
     module = scanned if layers == "scanned" else CausalLM(dataclasses.replace(scanned.cfg, scan_layers=False))
     tree = params if layers == "scanned" else unrolled_tree(params)
     _, mut = module.apply({"params": tree}, x, mutable=["moe_stats", "moe_routing"])
-    chosen, load = sown_by_layer(module.cfg, mut["moe_routing"]), sown_by_layer(module.cfg, mut["moe_stats"])
+    chosen, load = sown_by_layer(module.cfg, mut["moe_routing"]), sown_by_layer(module.cfg, mut["moe_stats"], "load_max_over_mean")
     assert chosen.shape == (8, 2 * SEQ, 2) and load.shape == (8,)
     # layer order: the reference's own choice, a layer at a time, is the same choice in float32
     with jax.default_matmul_precision("highest"):
@@ -275,7 +275,7 @@ def test_statistics_and_routing_come_out_in_layer_order_over_both_expert_runs(lf
     assert len({tuple(row) for row in sizes}) > 4  # the layers route differently: an order can be told
     if layers == "scanned":
         stats = _lm_forward(lora, base, scanned, x, y)[2]
-        assert set(stats) == {"moe_load_max_over_mean"}
+        assert set(stats) == {"moe_load_max_over_mean", "moe_rows_used_share"} and 0.5 < float(stats["moe_rows_used_share"]) <= 1.0
         assert float(stats["moe_load_max_over_mean"]) == pytest.approx(float(load.mean()), rel=1e-6)  # 2 + 6 layers, each once
 
 

@@ -23,6 +23,7 @@ from p2pfl_tpu.models.transformer import (
     LAYER_KINDS, Attention, Block, CausalLM, ExpertFFN, TransformerConfig, layer_runs, rope, sown_by_layer,
     tiny_transformer,
 )
+from p2pfl_tpu.ops.grouped_matmul import n_row_tiles
 from p2pfl_tpu.parallel import SpmdLoraFederation
 from tests.test_lfm2_model import _draw  # lora_b perturbed, a router bias that changes choices, norm scales off one
 
@@ -358,31 +359,41 @@ def test_the_shares_routed_parts_add_up_to_the_uncut_layer():
             assert float(mut["moe_stats"]["load_max_over_mean"][0]) >= 0.0
     assert ck.rel_l2(total + shared, want) < 1e-5
     assert sum(shares) == pytest.approx(1.0, abs=1e-6) and len(set(shares)) > 1
-    # uncut, the layer sows no share: the statistics of every older model are what they were
+    # uncut, the layer sows no held share (the rows in use it sows held or not)
     _, mut = uncut.apply({"params": params}, h, mutable=["moe_stats"])
-    assert sorted(mut["moe_stats"]) == ["load_max_over_mean"]
+    assert sorted(mut["moe_stats"]) == ["load_max_over_mean", "rows_used_share"]
 
 
-def test_an_absent_assignment_has_no_row_and_costs_no_tile():
+@pytest.mark.parametrize("k", [2, 8])
+@pytest.mark.parametrize("tile", [8, 16])
+def test_an_absent_assignment_has_no_row_and_costs_no_tile(tile, k):
+    """An absent assignment reads a row of the spare tile — padding whatever the
+    split — picked by its token: consecutive tokens read consecutive rows, so a
+    slab's absent indices are spread over the tile and not one address."""
     from p2pfl_tpu.ops.grouped_matmul import group_layout, n_row_tiles, tiles_and_fetches
 
     rng = np.random.default_rng(0)
-    group_of = jnp.asarray(rng.integers(0, 8, size=96), jnp.int32)
-    tile = 8
-    layout = group_layout(group_of, 3, tile, 2, True)  # groups 2, 3, 4 of 8 are held
+    m = 48 * k
+    group_of = jnp.asarray(rng.integers(0, 8, size=m), jnp.int32)
+    layout = group_layout(group_of, 3, tile, 2, True, per_token=k)  # groups 2, 3, 4 of 8 are held
     sizes = np.bincount(np.asarray(group_of), minlength=8)[2:5]
     np.testing.assert_array_equal(layout.group_sizes, sizes)
-    assert layout.rows == tile * (n_row_tiles(96, 3, tile) + 1)  # the static worst case + the spare tile
+    assert layout.rows == tile * (n_row_tiles(m, 3, tile) + 1)  # the static worst case + the spare tile
     used, fetches = tiles_and_fetches(layout.group_sizes, tile)
     assert int(used) == int(np.sum(-(-sizes // tile))) and int(fetches) == 3  # tiles for the held rows only
     slot, back = np.asarray(layout.slot_of_assignment), np.asarray(layout.assignment_of_slot)
     present = (np.asarray(group_of) >= 2) & (np.asarray(group_of) < 5)
-    assert (slot[~present] == layout.rows - 1).all() and back[layout.rows - 1] == 96  # absent: the spare tile's last row, padding
+    spare = layout.rows - tile
+    assert (slot[~present] >= spare).all() and (back[spare:] == m).all()  # absent: a row of the spare tile, all of it padding
+    row_of_token = spare + np.arange(m // k) % tile  # what an absent assignment of each token reads
+    np.testing.assert_array_equal(slot[~present], np.repeat(row_of_token, k)[~present])
+    assert (row_of_token[1:] != row_of_token[:-1]).all()  # consecutive tokens' absent rows differ
+    assert len(set(slot[~present].tolist())) == tile  # the whole tile is used, not one address
     assert (slot[present] < int(used) * tile).all() and (back[slot[present]] == np.flatnonzero(present)).all()
-    assert (back < 96).sum() == present.sum()  # no row belongs to an absent assignment
+    assert (back < m).sum() == present.sum()  # no row belongs to an absent assignment
     # every group held: the layout every older model has (no spare tile, nothing absent)
-    all_held, same = group_layout(group_of, 8, tile), group_layout(group_of, 8, tile, 0, False)
-    assert all_held.rows == tile * n_row_tiles(96, 8, tile)
+    all_held, same = group_layout(group_of, 8, tile), group_layout(group_of, 8, tile, 0, False, per_token=k)
+    assert all_held.rows == tile * n_row_tiles(m, 8, tile)
     np.testing.assert_array_equal(all_held.slot_of_assignment, same.slot_of_assignment)
 
 
@@ -420,6 +431,9 @@ def test_one_federated_round_carries_both_counters_and_lm_head_is_in_no_payload(
     entry = fed.run_round(epochs=1)
     assert np.isfinite(float(entry["train_loss"])) and 0.3 < float(entry["moe_held_share"]) < 0.7
     assert 0.0 < float(entry["moe_load_max_over_mean"]) <= 8 / 2
+    # the third counter: the share of each grouped-matmul call's tiles that are written (at least the held rows')
+    rows = 8 * (n_row_tiles(2 * SEQ * 2, 4, 8) + 1)
+    assert float(entry["moe_held_share"]) * 2 * SEQ * 2 / rows <= float(entry["moe_rows_used_share"]) < 1.0
     assert np.array_equal(head, np.asarray(fed.base["lm_head"]))  # frozen
     assert all(np.array_equal(np.asarray(leaf[0]), np.asarray(leaf[1])) for leaf in jax.tree.leaves(fed.params))
 
