@@ -87,14 +87,16 @@ def _lm_forward(lora, base, module, x, y):
     that the compiler removes from a program that drops them (every training
     round does); what the model sowed into ``"moe_stats"`` —
     each name's mean over the layers that sowed it, ``{}`` for a model that sows none
-    (an expert layer's ``load_max_over_mean``, ``rows_used_share``, ``held_share``); and the
+    (an expert layer's ``load_max_over_mean``, ``rows_used_share``, ``held_share``) and into
+    ``"kda_stats"`` (a delta-rule layer's ``neg_beta_share``), named ``moe_<name>`` /
+    ``kda_<name>``; and the
     ``"moe_routing"`` collection as sown (the experts each row chose, from THIS
     forward — a comparison must not take them from another program: a TPU
     rounds a near-tie differently from one compiled program to the next)."""
     params = merge_params(base, lora)
     # `embedding`: the head's matrix, the embedding itself or an untied `lm_head`
     (hidden, embedding), mut = module.apply(
-        {"params": params}, x, head=False, mutable=["moe_losses", "moe_stats", "moe_routing"]
+        {"params": params}, x, head=False, mutable=["moe_losses", "moe_stats", "moe_routing", "kda_stats"]
     )
     leaves = jax.tree.leaves(mut.get("moe_losses", {}))
     aux = sum(leaves) if leaves else jnp.zeros((), jnp.float32)
@@ -102,9 +104,10 @@ def _lm_forward(lora, base, module, x, y):
     with scope("head"):
         logits = tied_logits(hidden, embedding)
     found: dict[str, list] = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(mut.get("moe_stats", {})):
-        name = next(k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey))
-        found.setdefault("moe_" + name, []).append(leaf)
+    for collection in ("moe_stats", "kda_stats"):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(mut.get(collection, {})):
+            name = next(k.key for k in reversed(path) if isinstance(k, jax.tree_util.DictKey))
+            found.setdefault(collection.split("_")[0] + "_" + name, []).append(leaf)
     stats = {name: _mean_over_layers(leaves) for name, leaves in found.items()}
     return ce + aux, logits, stats, mut.get("moe_routing", {})
 

@@ -63,6 +63,10 @@ DEVICE_SCOPES = (
     "moe_gmm",  # ops/grouped_matmul: the product alone (the Mosaic call p2pfl_gmm on a TPU), inside moe_experts
     "moe_combine",  # ExpertFFN: gather the k rows of each token back, weigh, add the k slabs, add the shared expert's output
     "head",  # CausalLM's logits, and both rules of ops/head_loss (the training loss: logits by block, statistics, dX)
+    "kda_conv",  # KDAMixer: the three causal convolutions of q, k, v, their silu, the L2 norms of q and k
+    "kda_gate",  # KDAMixer: the channel-wise decay, beta, and the gated output norm
+    "kda_fwd",  # ops/delta_rule: everything the gated delta rule runs forward (kernel and glue)
+    "kda_bwd",  # ops/delta_rule: everything its backward runs
 )
 
 

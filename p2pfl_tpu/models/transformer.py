@@ -29,7 +29,10 @@ period, and the stack repeats the period:
   sliding-window and full attention in ONE stack (the ``afmoe`` block of
   Trinity): a ``swa`` layer rotates at ``rope_theta`` and sees the last
   ``attn_window`` keys only, a ``full`` layer sees every earlier key and is NOT
-  rotated — rotation and window belong to the layer kind, not to the config.
+  rotated — rotation and window belong to the layer kind, not to the config;
+- ``"kda_experts"``: gated delta-rule linear attention (:class:`KDAMixer`, Kimi
+  Delta Attention: a ``head_width`` x ``head_width`` state a head, channel-wise
+  decay, ``ops/delta_rule.py``) before the expert layer.
 
 ``TransformerConfig.leading_pattern`` names layers that run ONCE before the
 periodic stack ("two dense layers, then periods of expert layers").
@@ -91,6 +94,8 @@ LAYER_KINDS = {
     "swa_dense": ("swa", "mlp"),
     "swa_experts": ("swa", "experts"),
     "full_experts": ("full", "experts"),
+    # gated delta-rule linear attention (KDAMixer) before the expert layer
+    "kda_experts": ("kda", "experts"),
 }
 
 
@@ -157,7 +162,8 @@ class TransformerConfig:
     # False: the output head is ``lm_head`` ``[vocab, dim]``, a base leaf of its
     # own — frozen under LoRA and outside its FedAvg — not the embedding
     tie_head: bool = True
-    # taps of the gated short convolution (``"conv_*"`` layers only)
+    # taps of the gated short convolution (``"conv_*"`` layers) and of the delta
+    # rule's q / k / v convolutions (``"kda_*"`` layers)
     conv_taps: int = 3
     # Mamba-1 widths (used by ``"mamba"`` layers only): inner width
     # ``ssm_expand * dim``, state per channel, depthwise-conv kernel, and the
@@ -258,6 +264,8 @@ class TransformerConfig:
     # stands in for the absent experts' results. None = all of them.
     experts_held: Optional[int] = None
     first_expert: int = 0
+    # positions a chunk of the gated delta rule (``"kda_*"`` layers only; ``ops/delta_rule.py``)
+    kda_chunk: int = 64
 
     @property
     def head_width(self) -> int:
@@ -959,6 +967,86 @@ class ShortConvMixer(nn.Module):
         return dense(cfg.dim, name="out_proj")(gated_short_conv(bcu, kernel, cfg.dtype))
 
 
+def _l2_heads(x, heads: int, eps: float = 1e-6):
+    """``x / sqrt(Σ x² + eps)`` over each head's channels of ``[B, T, H · d]``, float32."""
+    b, t, width = x.shape
+    xh = x.astype(jnp.float32).reshape(b, t, heads, width // heads)
+    return (xh * jax.lax.rsqrt(jnp.sum(xh * xh, axis=-1, keepdims=True) + eps)).reshape(b, t, width)
+
+
+def gated_head_norm(o, gate, scale, eps: float):
+    """``RMSNorm(o_h) ⊙ scale ⊙ σ(gate_h)`` for every head ``h`` of ``o`` ``[B, T, H · d]``
+    (``scale``: ``[d]``, one for all heads), float32."""
+    b, t, width = o.shape
+    oh = o.astype(jnp.float32).reshape(b, t, -1, scale.shape[0])
+    oh = oh * jax.lax.rsqrt(jnp.mean(oh * oh, axis=-1, keepdims=True) + eps) * scale
+    return oh.reshape(b, t, width) * jax.nn.sigmoid(gate)
+
+
+def kda_beta(logits):
+    """``β = 2 σ(logits)`` in (0, 2): the transition ``I − β k kᵀ`` may have a
+    negative eigenvalue (``kda_allow_neg_eigval``); never clamped."""
+    return 2.0 * jax.nn.sigmoid(logits)
+
+
+def _a_log_heads_init(key, shape, dtype=jnp.float32):
+    """``A ~ U[1, 16]`` a head (Mamba-2's), kept as its log."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class KDAMixer(nn.Module):
+    """Gated delta-rule linear attention with channel-wise decay (Kimi Delta
+    Attention), ``n_heads`` heads of ``d = head_width``::
+
+        q = L2N(silu(conv(x W_q)))_h / sqrt(d);  k = L2N(silu(conv(x W_k)))_h;  v = silu(conv(x W_v))_h
+        log α = −exp(A_log_h) · softplus((x W_fa) W_fb + dt_bias)_h        (one decay a key channel)
+        β = 2 σ(x W_b)_h                                                  (in (0, 2): negative eigenvalues)
+        S_t = (I − β k kᵀ) Diag(α) S_{t−1} + β k vᵀ;   o = S_tᵀ q      (ops/delta_rule.gated_delta_rule)
+        y = concat_h[RMSNorm(o)_h ⊙ w_norm ⊙ σ((x W_ga) W_gb + b_g)_h] W_o
+
+    ``conv`` is a causal depthwise convolution of ``conv_taps`` taps, no bias; the
+    decay and gate projections are of rank ``d``. ``W_q, W_k, W_v, W_o`` carry
+    adapters; the decay, β and gate projections, the taps, ``A_log``,
+    ``dt_bias``, ``b_g`` and ``w_norm`` are base leaves: frozen under LoRA and
+    outside its FedAvg. Sows ``kda_stats/neg_beta_share``, the share of (token,
+    head) with β > 1."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from p2pfl_tpu.ops.delta_rule import gated_delta_rule
+
+        cfg = self.cfg
+        heads, d = cfg.n_heads, cfg.head_width
+        width = heads * d
+        dense = partial(LoRADense, rank=cfg.lora_rank, alpha=cfg.lora_alpha, dtype=cfg.dtype)
+        plain = partial(LoRADense, rank=0, dtype=cfg.dtype)
+        q, k, v = (dense(width, name=name)(x) for name in ("wq", "wk", "wv"))
+        taps = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0, out_axis=1)
+        q_taps, k_taps, v_taps = (self.param(f"{n}_conv", taps, (cfg.conv_taps, width)) for n in "qkv")
+        decay = plain(width, name="f_b")(plain(d, name="f_a")(x))
+        beta = plain(heads, name="b_proj")(x)
+        gate = plain(width, name="g_b")(plain(d, name="g_a")(x))
+        a_log = self.param("A_log", _a_log_heads_init, (heads,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (width,))
+        gate_bias = self.param("g_bias", nn.initializers.zeros, (width,))
+        norm_scale = self.param("o_norm", nn.initializers.ones, (d,))
+        with scope("kda_conv"):
+            q = _l2_heads(nn.silu(causal_depthwise_conv(q, q_taps)), heads) * d ** -0.5
+            k = _l2_heads(nn.silu(causal_depthwise_conv(k, k_taps)), heads)
+            v = nn.silu(causal_depthwise_conv(v, v_taps))
+        with scope("kda_gate"):
+            rate = jnp.repeat(jnp.exp(a_log), d)  # [H · d]: a head's rate on each of its channels
+            log_alpha = -rate * jax.nn.softplus(decay.astype(jnp.float32) + dt_bias)
+            beta = kda_beta(beta.astype(jnp.float32))
+        self.sow("kda_stats", "neg_beta_share", jnp.mean((beta > 1.0).astype(jnp.float32)))
+        o = gated_delta_rule(q, k, v, log_alpha, beta, chunk=cfg.kda_chunk)
+        with scope("kda_gate"):
+            y = gated_head_norm(o, gate.astype(jnp.float32) + gate_bias, norm_scale, cfg.norm_eps)
+        return dense(cfg.dim, name="wo")(y.astype(cfg.dtype))
+
+
 class MambaMixer(nn.Module):
     """The Mamba-1 sequence mixer as Jamba runs it (inner RMSNorms on the step
     size, ``B`` and ``C``)::
@@ -1028,6 +1116,8 @@ class Block(nn.Module):
             x = x + post("conv_post_norm", ShortConvMixer(cfg, name="conv")(norm(name="conv_norm")(x)))
         elif mixer == "mla":
             x = x + post("attn_post_norm", MLAttention(cfg, self.attn_fn, name="attn")(norm(name="attn_norm")(x)))
+        elif mixer == "kda":
+            x = x + post("attn_post_norm", KDAMixer(cfg, name="attn")(norm(name="attn_norm")(x)))
         else:
             x = x + post("attn_post_norm", Attention(cfg, self.attn_fn, mixer, name="attn")(norm(name="attn_norm")(x)))
         h = norm(name="mlp_norm")(x)
@@ -1081,7 +1171,7 @@ def _scan_over(body, length: int, with_bank: bool = False):
     # what ExpertFFN sows comes out stacked along the scan (only where a caller
     # makes the collection mutable; otherwise nothing is sown)
     return nn.scan(
-        body, variable_axes={"params": 0, "moe_stats": 0, "moe_routing": 0},
+        body, variable_axes={"params": 0, "moe_stats": 0, "moe_routing": 0, "kda_stats": 0},
         split_rngs={"params": True}, length=length, **({"in_axes": (0, nn.broadcast)} if with_bank else {}),
     )
 

@@ -86,6 +86,27 @@ def test_grouped_matmul_lowers_for_mosaic(transpose_rhs):
     assert text.count("tpu_custom_call") == 1 and 'kernel_name = "p2pfl_gmm"' in text
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_delta_rule_kernels_lower_for_mosaic(direction):
+    """Host-only Pallas→Mosaic lowering of ``p2pfl_kda_fwd`` / ``p2pfl_kda_bwd``
+    at the Solar widths (64 heads of 128, 4096 tokens, chunk 64): the chunk and
+    head axes sequential (the states carried in VMEM scratch)."""
+    from p2pfl_tpu.ops import delta_rule_kernel as dk
+
+    t, h, d, c = 4096, 64, 128, 64
+    x = jax.ShapeDtypeStruct((1, t, h * d), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, t, h), jnp.float32)
+    if direction == "fwd":
+        fn, args = jax.jit(lambda *a: dk.kda_fwd(*a, c)), (x, x, x, x, beta)
+    else:
+        starts = jax.ShapeDtypeStruct((1, t // c, h, d, d), jnp.float32)
+        fn, args = jax.jit(lambda *a: dk.kda_bwd(*a, c)), (x, x, x, x, beta, starts, x)
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1 and f'kernel_name = "p2pfl_kda_{direction}"' in text
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    assert b"arbitrary" in base64.b64decode(body)
+
+
 def test_lora_round_with_compiled_flash_lowers_on_a_four_device_mesh():
     """GSPMD refuses to partition a Mosaic kernel ("cannot be automatically
     partitioned"), which the interpreted kernels of the CPU mesh never show:

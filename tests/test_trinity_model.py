@@ -332,27 +332,41 @@ def test_the_short_convolution_expert_round_lowers_the_parents_text():
 # ---- (c) the held share -----------------------------------------------------------
 
 
-def test_the_shares_routed_parts_add_up_to_the_uncut_layer():
+def _share_case(model: str):
+    """(the program's config maker, the reference's keys, the key that counts the
+    held experts, the reference's expert layer) of one configuration."""
+    if model == "trinity":
+        return config, REF, "num_experts", afmoe_lm.experts
+    from benchmark.reference import solar_open2_lm
+    from tests import test_solar_model
+
+    return test_solar_model.config, test_solar_model.REF, "n_routed_experts", solar_open2_lm.experts
+
+
+@pytest.mark.parametrize("model", ["trinity", "solar"])
+def test_the_shares_routed_parts_add_up_to_the_uncut_layer(model):
     """Four chips' shares (experts 0-1, 2-3, 4-5, 6-7 of 8) of ONE expert layer,
     each its program: their routed parts + the shared expert counted once are
     the uncut reference's layer output, their held shares sum to 1 — and each
-    share is what the reference gives under the same share."""
-    cfg = config(experts_held=8, first_expert=0)
+    share is what the reference gives under the same share. Both configurations
+    that hold a share: Trinity's (route_scale 2.826) and Solar's (1, 320 wide)."""
+    make, ref_cfg, count, experts = _share_case(model)
+    cfg = make(experts_held=8, first_expert=0)
     uncut = ExpertFFN(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
     params = _draw(uncut.init(jax.random.PRNGKey(1), h)["params"], 3)
     assert params["experts_w13"].shape == (8, 64, 64)
-    whole = dict(REF, num_experts=8, share={"first_expert": 0, "router_experts": 8})
+    whole = dict(ref_cfg, **{count: 8}, share={"first_expert": 0, "router_experts": 8})
     with jax.default_matmul_precision("highest"):
-        want, want_chosen = afmoe_lm.experts(h[0], params, whole, 2.0)
+        want, want_chosen = experts(h[0], params, whole, 2.0)
         shared = afmoe_lm.swiglu(h[0], params["shared"], 2.0)
         total, shares = jnp.zeros_like(want), []
         for first in (0, 2, 4, 6):
-            part = ExpertFFN(config(experts_held=2, first_expert=first))
+            part = ExpertFFN(make(experts_held=2, first_expert=first))
             mine = dict(params, experts_w13=params["experts_w13"][first:first + 2], experts_w2=params["experts_w2"][first:first + 2])
             y, mut = part.apply({"params": mine}, h, mutable=["moe_stats", "moe_routing"])
             np.testing.assert_array_equal(np.sort(mut["moe_routing"]["chosen"][0], -1), np.sort(want_chosen, -1))  # the whole model's choice
-            ref, _ = afmoe_lm.experts(h[0], mine, dict(REF, num_experts=2, share={"first_expert": first, "router_experts": 8}), 2.0)
+            ref, _ = experts(h[0], mine, dict(ref_cfg, **{count: 2}, share={"first_expert": first, "router_experts": 8}), 2.0)
             assert ck.rel_l2(y[0], ref) < 1e-5
             total = total + (y[0] - shared)
             shares.append(float(mut["moe_stats"]["held_share"][0]))
@@ -456,7 +470,7 @@ def test_the_configuration_file_states_the_published_model_and_the_share():
     assert not [what for what, got, want in flops_window_moe.hand_counts() if got != pytest.approx(want, rel=1e-12)]
     assert f"{flops_window_moe.lora_params(cfg):,}" in cfg["assumed"]["lora.targets"]
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert len(bench["workloads"]) == 7 and sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    assert "trinity_silo4_seq8192" in {w["name"] for w in bench["workloads"]} and sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     traffic = json.loads((ROOT / "benchmark" / "traffic" / "silo4_seq8192.json").read_text())
     assert (traffic["n_nodes"], traffic["local_steps"], traffic["batch_size"], traffic["seq_len"], traffic["participation"]) == (4, 2, 1, 8192, 1.0)
 
