@@ -32,13 +32,20 @@ decay are float32; every product here is float32 at ``HIGHEST`` precision.
 The state is held TRANSPOSED, ``[dv, dk]``: a key channel's decay then scales a
 column, a row vector broadcast over sublanes.
 
+The chunk functions take one head's arrays, or a stack of heads' (a leading
+axis ``m``: ``[m, C, d]``, states ``[m, dv, dk]``) that they compute together,
+op by op — every head's arithmetic the one head's, element for element. In a
+stack the inverses are swept side by side (:func:`unit_lower_inverse`).
+
 ``custom_vjp``: the forward keeps the chunk-boundary states
 (``[B, T / C, H, dv, dk]``); the backward runs back across the chunks from the
 last, recomputing a chunk's interior from its boundary state, with the state's
 cotangent carried. The chunk's own arithmetic, forward and backward, is
-:func:`chunk_forward` / :func:`chunk_backward`: the SAME functions run under
-``lax.scan`` in the XLA path and inside the two Pallas kernels
-(``ops/delta_rule_kernel.py``: ``p2pfl_kda_fwd``, ``p2pfl_kda_bwd``), chosen by
+:func:`chunk_forward` / :func:`chunk_backward`: the SAME functions run on
+stacks of heads (:func:`heads_per_step`, one rule for both paths) under
+``lax.scan`` in the XLA path (``vmap`` over the stacks)
+and inside the two Pallas kernels (``ops/delta_rule_kernel.py``:
+``p2pfl_kda_fwd``, ``p2pfl_kda_bwd``, a grid step's heads one stack), chosen by
 backend as ``ops/selective_scan.py`` chooses. :func:`delta_rule_reference` is
 the recurrence one token at a time, the operation's definition.
 """
@@ -53,26 +60,38 @@ import jax.numpy as jnp
 from jax import lax
 
 from p2pfl_tpu.management.profiling import scope
+from p2pfl_tpu.ops.selective_scan_kernel import LANES
 
 DEFAULT_CHUNK = 64
 SUB = 16  # rows of a sub-block: the pairwise decays' reference is one a row sub-block
+HEADS_PER_STEP = (4, 2, 1)  # the stack sizes heads_per_step tries, in this order
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
 
 
+def _dot(a, b, ca: int, cb: int):
+    """``a · b`` over ``a``'s axis ``ca`` and ``b``'s axis ``cb`` of the last two,
+    float32; a stack's head axis is a batch axis."""
+    heads = tuple(range(a.ndim - 2))
+    lead = len(heads)
+    return lax.dot_general(
+        a, b, (((lead + ca,), (lead + cb,)), (heads, heads)), precision=_HI, preferred_element_type=_F32
+    )
+
+
 def _mm(a, b):
     """``a @ b``, float32."""
-    return lax.dot_general(a, b, (((1,), (0,)), ((), ())), precision=_HI, preferred_element_type=_F32)
+    return _dot(a, b, 1, 0)
 
 
 def _mm_t(a, b):
     """``a @ bᵀ``, float32."""
-    return lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=_HI, preferred_element_type=_F32)
+    return _dot(a, b, 1, 1)
 
 
 def _t_mm(a, b):
     """``aᵀ @ b``, float32."""
-    return lax.dot_general(a, b, (((0,), (0,)), ((), ())), precision=_HI, preferred_element_type=_F32)
+    return _dot(a, b, 0, 0)
 
 
 def _iota(c: int):
@@ -91,27 +110,27 @@ def _off_blocks(g, c: int):
     rows, cols = _iota(c)
     sub = _sub(c)
     for start in range(sub, c, sub):
-        ref = g[start - 1:start, :]
+        ref = g[..., start - 1:start, :]
         mask = (rows >= start) & (rows < start + sub) & (cols < start)
         yield mask, jnp.exp(jnp.minimum(g - ref, 0.0)), jnp.exp(jnp.minimum(ref - g, 0.0))
 
 
 def _block_rows(x, j: int):
     """``[C, d]``: every row of a sub-block replaced by the sub-block's ``j``-th."""
-    c, d = x.shape
+    *heads, c, d = x.shape
     sub = _sub(c)
-    blocks = x.reshape(c // sub, sub, d)
-    return jnp.broadcast_to(blocks[:, j:j + 1, :], blocks.shape).reshape(c, d)
+    blocks = x.reshape(*heads, c // sub, sub, d)
+    return jnp.broadcast_to(blocks[..., j:j + 1, :], blocks.shape).reshape(x.shape)
 
 
 def _to_block_row(x, j: int):
     """``[C, d]``: each sub-block's rows summed into its ``j``-th row, zeros elsewhere
     (the transpose of :func:`_block_rows`)."""
-    c, d = x.shape
+    *heads, c, d = x.shape
     sub = _sub(c)
-    blocks = x.reshape(c // sub, sub, d)
-    rows = lax.broadcasted_iota(jnp.int32, blocks.shape, 1)
-    return jnp.where(rows == j, jnp.sum(blocks, axis=1, keepdims=True), 0.0).reshape(c, d)
+    blocks = x.reshape(*heads, c // sub, sub, d)
+    rows = lax.broadcasted_iota(jnp.int32, blocks.shape, blocks.ndim - 2)
+    return jnp.where(rows == j, jnp.sum(blocks, axis=-2, keepdims=True), 0.0).reshape(x.shape)
 
 
 def _diag_picks(c: int):
@@ -128,7 +147,7 @@ def _pairs(q, k, g):
     """``(L, P)``, ``[C, C]`` each: ``L_ij = Σ_c k_ic k_jc e^{g_ic − g_jc}`` on
     ``j < i`` and ``P_ij = Σ_c q_ic k_jc e^{g_ic − g_jc}`` on ``j ≤ i``, zero
     elsewhere — one pass over the decays for both."""
-    c = q.shape[0]
+    c = q.shape[-2]
     rows, cols = _iota(c)
     ell = p = jnp.zeros((c, c), _F32)
     for mask, down, up in _off_blocks(g, c):
@@ -137,8 +156,8 @@ def _pairs(q, k, g):
         p = p + jnp.where(mask, _mm_t(q * down, ku), 0.0)
     for j, pick in _diag_picks(c):
         ke = _block_rows(k, j) * jnp.exp(jnp.minimum(g - _block_rows(g, j), 0.0))
-        ell = ell + jnp.where(pick, jnp.sum(k * ke, axis=1, keepdims=True), 0.0)
-        p = p + jnp.where(pick, jnp.sum(q * ke, axis=1, keepdims=True), 0.0)
+        ell = ell + jnp.where(pick, jnp.sum(k * ke, axis=-1, keepdims=True), 0.0)
+        p = p + jnp.where(pick, jnp.sum(q * ke, axis=-1, keepdims=True), 0.0)
     return jnp.where(cols < rows, ell, 0.0), jnp.where(cols <= rows, p, 0.0)
 
 
@@ -146,7 +165,7 @@ def _pairs_vjp(q, k, g, d_ell, d_p):
     """``(dq, dk as the row operand, dk as the column operand)`` of :func:`_pairs`
     for the cotangents ``d_ell``, ``d_p``. ``g`` enters only as ``g_i − g_j``,
     so its cotangent is ``q ⊙ dq + k ⊙ dk_rows − k ⊙ dk_cols``."""
-    c = q.shape[0]
+    c = q.shape[-2]
     rows, cols = _iota(c)
     d_ell = jnp.where(cols < rows, d_ell, 0.0)
     d_p = jnp.where(cols <= rows, d_p, 0.0)
@@ -162,8 +181,8 @@ def _pairs_vjp(q, k, g, d_ell, d_p):
     for j, pick in _diag_picks(c):
         e = jnp.exp(jnp.minimum(g - _block_rows(g, j), 0.0))
         ke = _block_rows(k, j) * e
-        d_col_ell = jnp.sum(jnp.where(pick, d_ell, 0.0), axis=1, keepdims=True)
-        d_col_p = jnp.sum(jnp.where(pick, d_p, 0.0), axis=1, keepdims=True)
+        d_col_ell = jnp.sum(jnp.where(pick, d_ell, 0.0), axis=-1, keepdims=True)
+        d_col_p = jnp.sum(jnp.where(pick, d_p, 0.0), axis=-1, keepdims=True)
         dq = dq + d_col_p * ke
         dk_rows = dk_rows + d_col_ell * ke
         dk_cols = dk_cols + _to_block_row((d_col_ell * k + d_col_p * q) * e, j)
@@ -171,21 +190,44 @@ def _pairs_vjp(q, k, g, d_ell, d_p):
 
 
 def unit_lower_inverse(n):
-    """``(I + n)⁻¹`` for a strictly lower-triangular ``n`` ``[C, C]``: forward
-    substitution as a column sweep — after column ``j``, row ``j`` is final."""
-    c = n.shape[0]
-    rows, cols = _iota(c)
-    t = (rows == cols).astype(_F32)
+    """``(I + n)⁻¹`` for a strictly lower-triangular ``n`` ``[C, C]`` (or each
+    block of a stack ``[m, C, C]``): forward substitution as a column sweep —
+    after column ``j``, row ``j`` is final.
+
+    A stack is swept side by side: its blocks laid next to each other in the
+    lanes of ``[C, LANES]`` rows (two blocks a row at ``C = 64``), where one lane
+    gather broadcasts column ``j`` of every block of a row, and the rows in
+    lockstep, one column at a time. Element for element the sweep is the one
+    block's."""
+    if n.ndim == 2:
+        c = n.shape[0]
+        rows, cols = _iota(c)
+        t = (rows == cols).astype(_F32)
+        for j in range(c - 1):
+            t = t - n[:, j:j + 1] * t[j:j + 1, :]
+        return t
+    m, c, _ = n.shape
+    per = max(1, LANES // c)
+    if m % per:
+        per = 1
+    rows = m // per
+    sides = jnp.stack([jnp.concatenate([n[r * per + i] for i in range(per)], axis=1) for r in range(rows)])
+    lanes = lax.broadcasted_iota(jnp.int32, sides.shape, 2)
+    base = lanes // c * c  # each lane's block's first lane
+    t = (lax.broadcasted_iota(jnp.int32, sides.shape, 1) + base == lanes).astype(_F32)
+    flat = sides.reshape(rows * c, per * c)  # a lane gather takes two dimensions
+    flat_base = base.reshape(flat.shape)
     for j in range(c - 1):
-        t = t - n[:, j:j + 1] * t[j:j + 1, :]
-    return t
+        col = jnp.take_along_axis(flat, flat_base + j, axis=1, mode="promise_in_bounds").reshape(sides.shape)
+        t = t - col * t[:, j:j + 1, :]
+    return jnp.stack([t[:, :, i * c:(i + 1) * c] for i in range(per)], axis=1).reshape(m, c, c)
 
 
 def _chunk_parts(q, k, v, g, beta, st):
     """What both directions use: ``L``, ``P``, ``(I + diag(β) L)⁻¹``, ``e^g``,
     the last row of ``g``, ``K e^{g_C − g}``, ``R = V − (K e^g) S_0`` and ``U``."""
     lam = jnp.exp(g)
-    last = g[g.shape[0] - 1:, :]
+    last = g[..., g.shape[-2] - 1:, :]
     ell, p = _pairs(q, k, g)
     inv = unit_lower_inverse(beta * ell)
     r = v - _mm_t(k * lam, st)
@@ -196,8 +238,9 @@ def _chunk_parts(q, k, v, g, beta, st):
 def chunk_forward(st, q, k, v, g, beta, state_dtype=_F32):
     """One chunk: ``(O [C, dv], the state it ends with [dv, dk])`` from the
     state ``st`` ``[dv, dk]`` it starts with. ``q, k, g`` ``[C, dk]``, ``v``
-    ``[C, dv]``, ``beta`` ``[C, 1]``; all float32. ``state_dtype`` rounds the
-    state carried out (tests and the planted fault pass bfloat16)."""
+    ``[C, dv]``, ``beta`` ``[C, 1]``; all float32 (or stacks of heads: a
+    leading axis on each). ``state_dtype`` rounds the state carried out (tests
+    and the planted fault pass bfloat16)."""
     _, p, _, lam, last, kb, _, u = _chunk_parts(q, k, v, g, beta, st)
     o = _mm_t(q * lam, st) + _mm(p, u)
     end = st * jnp.exp(last) + _t_mm(u, kb)
@@ -208,7 +251,7 @@ def chunk_backward(st, q, k, v, g, beta, do, dst):
     """One chunk's gradients, given the cotangents of its output ``do`` and of
     the state it ends with ``dst`` ``[dv, dk]``: ``(d st, dq, dk, dv, dg, dβ)``."""
     ell, p, inv, lam, last, kb, r, u = _chunk_parts(q, k, v, g, beta, st)
-    c = q.shape[0]
+    c = q.shape[-2]
     rows, cols = _iota(c)
     qg, kg = q * lam, k * lam
     d_p = jnp.where(cols <= rows, _mm_t(do, u), 0.0)
@@ -216,7 +259,7 @@ def chunk_backward(st, q, k, v, g, beta, do, dst):
     d_y = _t_mm(inv, d_u)
     d_m = jnp.where(cols < rows, -_mm_t(d_y, u), 0.0)
     d_r = beta * d_y
-    d_beta = jnp.sum(r * d_y, axis=1, keepdims=True) + jnp.sum(d_m * ell, axis=1, keepdims=True)
+    d_beta = jnp.sum(r * d_y, axis=-1, keepdims=True) + jnp.sum(d_m * ell, axis=-1, keepdims=True)
     d_kg = -_mm(d_r, st)
     d_qg = _mm(do, st)
     d_kb = _mm(u, dst)
@@ -225,7 +268,7 @@ def chunk_backward(st, q, k, v, g, beta, do, dst):
     dq = dq_p + d_qg * lam
     dk = dk_rows + dk_cols + d_kg * lam + d_kb * jnp.exp(last - g)
     dg = q * dq_p + k * (dk_rows - dk_cols) + d_qg * qg + d_kg * kg - d_kb * kb
-    d_last = jnp.sum(d_kb * kb, axis=0, keepdims=True) + jnp.exp(last) * jnp.sum(st * dst, axis=0, keepdims=True)
+    d_last = jnp.sum(d_kb * kb, axis=-2, keepdims=True) + jnp.exp(last) * jnp.sum(st * dst, axis=-2, keepdims=True)
     dg = dg + jnp.where(rows[:, :1] == c - 1, d_last, 0.0)
     return d_st, dq, dk, d_r, dg, d_beta
 
@@ -238,42 +281,52 @@ def chunk_cumsum(log_alpha, chunk: int):
     return jnp.cumsum(g, axis=2).reshape(log_alpha.shape)
 
 
-# ---- the XLA path: the same chunk functions under lax.scan ---------------------------
+# ---- the XLA path: the same chunk functions under lax.scan --------------------------
 
 
-def _chunks(x, chunk: int, heads: int):
-    """``[B, T, H · d] -> [T / C, B · H, C, d]`` float32."""
+def heads_per_step(heads: int) -> int:
+    """The heads a stack holds — a kernel grid step's heads, and a stack of the
+    XLA path: the first of ``HEADS_PER_STEP`` that divides ``heads``."""
+    return next(n for n in HEADS_PER_STEP if heads % n == 0)
+
+
+def _chunks(x, chunk: int, heads: int, m: int):
+    """``[B, T, H · d] -> [T / C, B · H / m, m, C, d]`` float32: stacks of ``m``
+    heads of one batch row (``m`` divides ``H``)."""
     b, t = x.shape[:2]
     x = x.astype(_F32).reshape(b, t // chunk, chunk, heads, -1)
-    return x.transpose(1, 0, 3, 2, 4).reshape(t // chunk, b * heads, chunk, x.shape[-1])
+    return x.transpose(1, 0, 3, 2, 4).reshape(t // chunk, b * heads // m, m, chunk, x.shape[-1])
 
 
 def _unchunks(x, b: int):
     """Inverse of :func:`_chunks`."""
-    nc, bh, c, d = x.shape
-    return x.reshape(nc, b, bh // b, c, d).transpose(1, 0, 3, 2, 4).reshape(b, nc * c, bh // b * d)
+    nc, stacks, m, c, d = x.shape
+    h = stacks * m // b
+    return x.reshape(nc, b, h, c, d).transpose(1, 0, 3, 2, 4).reshape(b, nc * c, h * d)
 
 
 def _fwd_xla(q, k, v, g, beta, chunk: int):
     b, h = q.shape[0], beta.shape[2]
     dk, dv = q.shape[2] // h, v.shape[2] // h
-    xs = tuple(_chunks(x, chunk, h) for x in (q, k, v, g, beta))
+    m = heads_per_step(h)
+    xs = tuple(_chunks(x, chunk, h, m) for x in (q, k, v, g, beta))
     one = jax.vmap(chunk_forward)
 
     def step(st, x):
         o, end = one(st, *x)
         return end, (o, st)
 
-    st0 = jnp.zeros((b * h, dv, dk), _F32)
+    st0 = jnp.zeros((b * h // m, m, dv, dk), _F32)
     _, (o, starts) = lax.scan(step, st0, xs)
-    starts = starts.reshape(starts.shape[0], b, h, *starts.shape[2:]).transpose(1, 0, 2, 3, 4)
+    starts = starts.reshape(starts.shape[0], b, h, dv, dk).transpose(1, 0, 2, 3, 4)
     return _unchunks(o, b), starts
 
 
 def _bwd_xla(q, k, v, g, beta, starts, do, chunk: int):
     b, h = q.shape[0], beta.shape[2]
-    xs = tuple(_chunks(x, chunk, h) for x in (q, k, v, g, beta, do))
-    starts = starts.transpose(1, 0, 2, 3, 4).reshape(starts.shape[1], b * h, *starts.shape[3:])
+    m = heads_per_step(h)
+    xs = tuple(_chunks(x, chunk, h, m) for x in (q, k, v, g, beta, do))
+    starts = starts.transpose(1, 0, 2, 3, 4).reshape(starts.shape[1], b * h // m, m, *starts.shape[3:])
     one = jax.vmap(chunk_backward)
 
     def step(dst, x):

@@ -86,11 +86,15 @@ def test_grouped_matmul_lowers_for_mosaic(transpose_rhs):
     assert text.count("tpu_custom_call") == 1 and 'kernel_name = "p2pfl_gmm"' in text
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_delta_rule_kernels_lower_for_mosaic(direction):
-    """Host-only Pallas→Mosaic lowering of ``p2pfl_kda_fwd`` / ``p2pfl_kda_bwd``
-    at the Solar widths (64 heads of 128, 4096 tokens, chunk 64): the chunk and
-    head axes sequential (the states carried in VMEM scratch)."""
+def _kda_body(direction: str) -> str:
+    """The Mosaic body of ``p2pfl_kda_fwd`` / ``_bwd`` lowered host-only at the
+    Solar widths (64 heads of 128, 4096 tokens, chunk 64), deserialized and
+    printed without locations (the serialized body carries source lines)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+
     from p2pfl_tpu.ops import delta_rule_kernel as dk
 
     t, h, d, c = 4096, 64, 128, 64
@@ -104,7 +108,44 @@ def test_delta_rule_kernels_lower_for_mosaic(direction):
     text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 1 and f'kernel_name = "p2pfl_kda_{direction}"' in text
     (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
-    assert b"arbitrary" in base64.b64decode(body)
+    with mlir.make_ir_context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # the serialized dialect's names, until mosaic-serde reads them back
+        module = ir.Module.parse(base64.b64decode(body))
+        PassManager.parse("builtin.module(mosaic-serde{serialize=false})").run(module.operation)
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_delta_rule_kernels_lower_for_mosaic(direction):
+    """Host-only Pallas→Mosaic lowering of ``p2pfl_kda_fwd`` / ``p2pfl_kda_bwd``
+    at the Solar widths: the chunk and head axes sequential (the states carried
+    in VMEM scratch), and the grid ``(1, 64, 64 / n)`` for the ``n`` heads a
+    step the shape rule picks (4)."""
+    from p2pfl_tpu.ops.delta_rule import heads_per_step
+
+    n = heads_per_step(64)
+    assert n == 4
+    body = _kda_body(direction)
+    assert "#tpu.dimension_semantics<arbitrary>" in body
+    assert f"iteration_bounds = array<i64: 1, 64, {64 // n}>" in body
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_delta_rule_kernels_at_one_head_a_step_lower_to_the_parents_text(direction, monkeypatch):
+    """With the shape rule held to one head a step, the kernels are the ones
+    there were, op for op: the body's text hash for hash what the commit before
+    heads were grouped lowered (``tests/fixtures/kda_lowered_parent.json``)."""
+    import hashlib
+    import json
+
+    from p2pfl_tpu.ops import delta_rule_kernel as dk
+
+    want = json.loads((Path(__file__).parent / "fixtures" / "kda_lowered_parent.json").read_text())[direction]
+    monkeypatch.setattr(dk, "heads_per_step", lambda heads: 1)
+    body = _kda_body(direction)
+    assert "iteration_bounds = array<i64: 1, 64, 64>" in body
+    assert (hashlib.sha256(body.encode()).hexdigest(), len(body)) == (want["sha256"], want["chars"])
 
 
 def test_lora_round_with_compiled_flash_lowers_on_a_four_device_mesh():
