@@ -967,10 +967,24 @@ class ShortConvMixer(nn.Module):
         return dense(cfg.dim, name="out_proj")(gated_short_conv(bcu, kernel, cfg.dtype))
 
 
+_F32_SUBLANES = 8  # rows of a float32 tile on the TPU
+
+
+def _head_view(x, d: int):
+    """``[B, T, H · d]`` as heads of ``d`` channels in its last axis: ``[B, T / 8, 8, H, d]``
+    where ``T`` splits at the float32 sublane tile, ``[B, T, H, d]`` where it does not. The
+    split view is the array's own tiled order (tiles of 8 tokens × 128 lanes), so XLA moves
+    nothing for it; ``[B, T, H, d]`` puts the heads between a tile's tokens, and XLA relays
+    the whole array out into that order and back (the reductions over ``d`` are the same)."""
+    b, t, width = x.shape
+    tokens = (t // _F32_SUBLANES, _F32_SUBLANES) if t % _F32_SUBLANES == 0 else (t,)
+    return x.reshape(b, *tokens, width // d, d)
+
+
 def _l2_heads(x, heads: int, eps: float = 1e-6):
     """``x / sqrt(Σ x² + eps)`` over each head's channels of ``[B, T, H · d]``, float32."""
     b, t, width = x.shape
-    xh = x.astype(jnp.float32).reshape(b, t, heads, width // heads)
+    xh = _head_view(x.astype(jnp.float32), width // heads)
     return (xh * jax.lax.rsqrt(jnp.sum(xh * xh, axis=-1, keepdims=True) + eps)).reshape(b, t, width)
 
 
@@ -978,7 +992,7 @@ def gated_head_norm(o, gate, scale, eps: float):
     """``RMSNorm(o_h) ⊙ scale ⊙ σ(gate_h)`` for every head ``h`` of ``o`` ``[B, T, H · d]``
     (``scale``: ``[d]``, one for all heads), float32."""
     b, t, width = o.shape
-    oh = o.astype(jnp.float32).reshape(b, t, -1, scale.shape[0])
+    oh = _head_view(o.astype(jnp.float32), scale.shape[0])
     oh = oh * jax.lax.rsqrt(jnp.mean(oh * oh, axis=-1, keepdims=True) + eps) * scale
     return oh.reshape(b, t, width) * jax.nn.sigmoid(gate)
 
