@@ -166,7 +166,10 @@ def test_message_retry_recovers_transient_failure():
     try:
         g.add_message(Message("me", "vote", ("x", "1")), ["peer"])
         deadline = time.monotonic() + 5.0
-        while len(attempts) < fail_first + 1 and time.monotonic() < deadline:
+        # the delivery is counted after the send returns: wait for the count too
+        while (
+            len(attempts) < fail_first + 1 or not logger.get_comm_metrics("me").get("msg_retry_ok")
+        ) and time.monotonic() < deadline:
             time.sleep(0.02)
         assert len(attempts) == fail_first + 1, "retries never delivered the message"
         m = logger.get_comm_metrics("me")

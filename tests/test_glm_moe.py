@@ -1,14 +1,13 @@
 """Latent attention, the dropless sigmoid-routed expert layer and its grouped
 matmul, and "one dense layer, then a scanned run of expert layers" through
 ``CausalLM`` and ``SpmdLoraFederation`` — against the plain reference
-``benchmark/reference/glm_moe_lm.py`` on seeded weights."""
+``benchmark/reference/glm_moe_lm.py`` on seeded weights. The cell's whole
+reference check runs in tier-1 once: in ``test_the_cell_rehearses``."""
 
 import copy
 import dataclasses
 import hashlib
 import json
-import subprocess
-import sys
 from pathlib import Path
 
 import jax
@@ -28,8 +27,10 @@ from p2pfl_tpu.ops import grouped_matmul as gmm_ops
 from p2pfl_tpu.ops.grouped_matmul import group_layout, grouped_matmul, n_row_tiles, tiles_and_fetches
 from p2pfl_tpu.parallel import SpmdLoraFederation
 from p2pfl_tpu.parallel.spmd import draw_node_perms
+from tests.shared import rehearse
 
 ROOT = Path(__file__).resolve().parent.parent
+REHEARSE_S = 330  # the rehearse case took 118 s under the suite's six workers
 SEQ = 32
 PATTERN = ("mla_dense", "mla_experts", "mla_experts", "mla_experts")
 # the reference reads Hugging Face's keys; unequal low-rank widths, a shared rotary head
@@ -966,21 +967,8 @@ def test_flops_moe_counts_the_published_model():
     assert moved > 2 * 603_979_776  # the bfloat16 bank once, and the rows
 
 
-@pytest.mark.parametrize("module", ["benchmark.selfcheck", "benchmark.rehearse", "benchmark.planted_faults"])
-def test_benchmark_files_resolve_and_the_cell_rehearses(module):
-    """The last case runs the cell's WHOLE reference check at the rehearsal's
-    sizes with the expert layer choosing without its bias: exit 0 = it was seen."""
-    args = {
-        "benchmark.selfcheck": [],
-        "benchmark.rehearse": ["--workload", "glm_silo4_seq4096", "--seconds", "1"],
-        "benchmark.planted_faults": ["--workload", "glm_silo4_seq4096", "--seed", "1", "--fault", "choose_without_bias", "--rehearsal"],
-    }[module]
-    done = subprocess.run(
-        [sys.executable, "-m", module, *args], cwd=ROOT, capture_output=True, text=True, timeout=900,
-        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)},
-    )
-    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
-    if module.endswith("rehearse"):
-        assert '"correct": true' in done.stdout and "rehearsal finished" in done.stdout
-    if module.endswith("planted_faults"):
-        assert "'layer.routing_agreement'" in done.stdout.splitlines()[-1]
+def test_the_cell_rehearses():
+    """``benchmark.rehearse``: build, the cell's whole reference check, warm-up
+    and measure at the rehearsal's sizes (the one run of that check in tier-1;
+    a fault is shown at its layer comparison, above)."""
+    rehearse("glm_silo4_seq4096", timeout=REHEARSE_S)

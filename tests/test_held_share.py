@@ -1,7 +1,7 @@
 """An expert layer that holds a share of the experts, at its edges (PR 38): the
 worst case the layout's static rows are kept for, and what a round lowers to —
-beside ``tests/test_trinity_model.py`` (whose file is the suite's longest: these
-run on another worker), against ``benchmark/reference/afmoe_lm.py``."""
+beside ``tests/test_trinity_model.py``, on the Trinity configuration of
+``tests/shared.py``, against ``benchmark/reference/afmoe_lm.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +15,8 @@ from p2pfl_tpu.learning.lora import merge_params
 from p2pfl_tpu.models.transformer import ExpertFFN
 from p2pfl_tpu.ops.grouped_matmul import n_row_tiles
 from p2pfl_tpu.parallel import SpmdLoraFederation
-from tests.test_trinity_model import REF, SEQ, _perturbed_layer, _same_gradients, config, seeded
+from tests.shared import TRINITY_REF as REF, TRINITY_SEQ as SEQ, perturbed_layer, same_gradients
+from tests.shared import trinity_config as config, trinity_seeded as seeded
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -35,7 +36,7 @@ def test_every_assignment_on_a_held_expert_is_computed_the_layer_is_still_drople
         monkeypatch.setattr(tf, "_GATHER_HEAD_BYTES", head_rows * cfg.dim * 4)
     layer = ExpertFFN(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    lora, base = _perturbed_layer(layer, h)
+    lora, base = perturbed_layer(layer, h)
     probe = jax.random.normal(jax.random.PRNGKey(3), h.shape)
     for routing, bias in (("seeded", base["router_bias"]), ("all_held", base["router_bias"] + jnp.array([0, 0, 9.0, 9.0, 9.0, 9.0, 0, 0]))):
         mine = dict(base, router_bias=bias)
@@ -46,7 +47,7 @@ def test_every_assignment_on_a_held_expert_is_computed_the_layer_is_still_drople
             got, grads = jax.jit(jax.value_and_grad(ours, (0, 1)))(lora, h)
             _, mut = layer.apply({"params": merge_params(mine, lora)}, h, mutable=["moe_stats", "moe_routing"])
         assert float(got) == pytest.approx(float(want), rel=1e-4)
-        _same_gradients(grads[0], want_grads[0])
+        same_gradients(grads[0], want_grads[0])
         assert ck.rel_l2(grads[1], want_grads[1]) < 2e-4
         share, used = float(mut["moe_stats"]["held_share"][0]), float(mut["moe_stats"]["rows_used_share"][0])
         rows = 8 * (n_row_tiles(SEQ * 2, 4, 8) + 1)

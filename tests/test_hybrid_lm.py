@@ -1,13 +1,12 @@
 """A period of unlike layers (``TransformerConfig.layer_pattern``: Mamba-1 and
 attention) through ``CausalLM`` and ``SpmdLoraFederation``, against the plain
 reference ``benchmark/reference/jamba_lm.py`` on seeded weights — and the guard
-that the default pattern still compiles the parent's round."""
+that the default pattern still compiles the parent's round. The cell's whole
+reference check runs in tier-1 once: in ``test_the_cell_rehearses``."""
 
 import copy
 import json
 import re
-import subprocess
-import sys
 from collections import Counter
 from pathlib import Path
 
@@ -23,8 +22,10 @@ from p2pfl_tpu.learning.lora import _lm_loss, split_lora
 from p2pfl_tpu.models.transformer import CausalLM, TransformerConfig, layer_runs, tiny_transformer
 from p2pfl_tpu.parallel import SpmdLoraFederation
 from p2pfl_tpu.parallel.spmd import draw_node_perms
+from tests.shared import rehearse
 
 ROOT = Path(__file__).resolve().parent.parent
+REHEARSE_S = 300  # the rehearse case took 105 s under the suite's six workers
 PATTERN = ("mamba", "mamba", "attention", "mamba")
 SEQ = 80  # two scan chunks (the op's default is 64 steps), the second padded
 # the reference reads Hugging Face's keys
@@ -236,13 +237,7 @@ def test_moe_under_the_layer_scan_is_still_refused():
         CausalLM(cfg).init(jax.random.PRNGKey(0), batch()[0])
 
 
-@pytest.mark.parametrize("module", ["benchmark.selfcheck", "benchmark.rehearse"])
-def test_benchmark_files_resolve_and_the_cell_rehearses(module):
-    args = [] if module.endswith("selfcheck") else ["--workload", "jamba_silo4_seq4096", "--seconds", "1"]
-    done = subprocess.run(
-        [sys.executable, "-m", module, *args], cwd=ROOT, capture_output=True, text=True, timeout=900,
-        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)},
-    )
-    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
-    if module.endswith("rehearse"):
-        assert '"correct": true' in done.stdout and "rehearsal finished" in done.stdout
+def test_the_cell_rehearses():
+    """``benchmark.rehearse``: build, the cell's whole reference check, warm-up
+    and measure at the rehearsal's sizes (the one run of that check in tier-1)."""
+    rehearse("jamba_silo4_seq4096", timeout=REHEARSE_S)

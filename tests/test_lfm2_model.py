@@ -1,14 +1,13 @@
 """The gated short-convolution mixer, per-head q / k norms, layer kinds as a
 product of mixer and feed-forward, and "leading dense layers, then periods of
 expert runs" through ``CausalLM`` and ``SpmdLoraFederation`` — against the plain
-reference ``benchmark/reference/lfm2_moe_lm.py`` on seeded weights."""
+reference ``benchmark/reference/lfm2_moe_lm.py`` on seeded weights. The cell's
+whole reference check runs in tier-1 once: in ``test_the_cell_rehearses``."""
 
 import dataclasses
 import hashlib
 import json
 import re
-import subprocess
-import sys
 from collections import Counter
 from pathlib import Path
 
@@ -25,8 +24,10 @@ from p2pfl_tpu.models.transformer import (
     LAYER_KINDS, Attention, CausalLM, ShortConvMixer, TransformerConfig, layer_runs, sown_by_layer, tiny_transformer,
 )
 from p2pfl_tpu.parallel import SpmdLoraFederation
+from tests.shared import draw, expert_federation, hybrid_federation, lora_federation, perturbed_layer, rehearse
 
 ROOT = Path(__file__).resolve().parent.parent
+REHEARSE_S = 270  # the rehearse case took 93 s under the suite's six workers
 SEQ = 32
 LEADING = ("conv_dense", "conv_dense")
 PATTERN = ("attention_experts", "conv_experts", "conv_experts", "conv_experts")
@@ -50,27 +51,9 @@ def config(**kw):
     return TransformerConfig(**base)
 
 
-def _draw(params, seed):
-    """``lora_b`` perturbed (at its zero start every ``lora_a`` gradient is
-    exactly zero), a router bias that changes choices, norm scales off one."""
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 512))
-
-    def draw(path, a):
-        name = jax.tree_util.keystr(path)
-        if "lora_b" in name:
-            return 0.05 * jax.random.normal(next(keys), a.shape, a.dtype)
-        if "router_bias" in name:
-            return 0.02 * jax.random.normal(next(keys), a.shape, a.dtype)
-        if "norm" in name:
-            return 1.0 + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype)
-        return a
-
-    return jax.tree_util.tree_map_with_path(draw, params)
-
-
 def seeded(cfg, seed=0):
     model = tiny_transformer(seq_len=SEQ, seed=seed, cfg=cfg)
-    model.params = _draw(model.params, seed + 1)
+    model.params = draw(model.params, seed + 1)
     return (model, *split_lora(model.params))
 
 
@@ -106,11 +89,6 @@ def lfm2():
     return seeded(config())
 
 
-def _perturbed_layer(layer, h, seed=1):
-    params = _draw(layer.init(jax.random.PRNGKey(seed), h)["params"], seed + 1)
-    return split_lora(params)
-
-
 # ---- (a) the gated short convolution ---------------------------------------------
 
 
@@ -118,7 +96,7 @@ def test_short_conv_mixer_forward_and_adapter_gradients_match_the_reference():
     cfg = config()
     layer = ShortConvMixer(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    lora, base = _perturbed_layer(layer, h)
+    lora, base = perturbed_layer(layer, h)
     assert base["in_proj"]["kernel"].shape == (64, 192) and base["conv_kernel"].shape == (3, 64)
     assert sorted(lora) == ["in_proj", "out_proj"] and "conv_bias" not in base  # adapters on both projections; no bias
     probe = jax.random.normal(jax.random.PRNGKey(3), h.shape)
@@ -159,7 +137,7 @@ def test_qk_normed_gqa_forward_and_adapter_gradients_match_the_reference():
     cfg = config()  # 4 query heads on 2 key/value heads of 16
     layer = Attention(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    lora, base = _perturbed_layer(layer, h)
+    lora, base = perturbed_layer(layer, h)
     assert base["q_norm"]["scale"].shape == base["k_norm"]["scale"].shape == (16,) and base["wk"]["kernel"].shape == (64, 32)
     probe = jax.random.normal(jax.random.PRNGKey(3), h.shape)
     ours = lambda lo: jnp.sum(layer.apply({"params": merge_params(base, lo)}, h) * probe)  # noqa: E731
@@ -203,7 +181,9 @@ def _system(module, lora, base, x, y):
     return loss, grads, chosen
 
 
-@pytest.mark.parametrize("layers", ["scanned", "unrolled"])
+# unrolled: the scanned case holds the model to the reference, and
+# test_scanned_layers_equal_the_unrolled_layers_on_mapped_parameters the unrolled stack to the scanned one
+@pytest.mark.parametrize("layers", ["scanned", pytest.param("unrolled", marks=pytest.mark.slow)])
 def test_loss_and_every_adapter_gradient_match_the_reference_held_to_the_programs_assignments(lfm2, layers):
     model, lora, base = lfm2
     x, y = batch()
@@ -289,10 +269,8 @@ def test_older_patterns_keep_the_parents_tree_and_lowered_texts(model):
     commit's, text for text (``qk_norm=False``, an empty ``leading_pattern``, a
     convolution WITH its bias): recorded on that commit before
     ``transformer.py`` was edited (``tests/fixtures/lm_rounds_parent.json``)."""
-    from tests.test_scope_trace import _expert_federation, _hybrid_federation, _lora_federation
-
     want = json.loads((ROOT / "tests" / "fixtures" / "lm_rounds_parent.json").read_text())[model]
-    fed = {"dense": _lora_federation, "hybrid": _hybrid_federation, "experts": _expert_federation}[model]()
+    fed = {"dense": lora_federation, "hybrid": hybrid_federation, "experts": expert_federation}[model]()
     assert fed.module.cfg.leading_pattern == () and not fed.module.cfg.qk_norm
     text = fed.lower_round(epochs=1).as_text()
     ops = Counter(re.findall(r"\b(stablehlo\.[a-z_]+|func\.call|sdy\.[a-z_]+)\b", text))
@@ -341,29 +319,8 @@ def test_flops_conv_moe_counts_the_published_model():
     assert flops_conv_moe.gqa_flash_floor_seconds(cfg, 4096, peak) == pytest.approx(3 * (fwd + bwd) / peak["bf16_flops_per_s"])
 
 
-@pytest.mark.parametrize("module", ["benchmark.selfcheck", "benchmark.rehearse", "benchmark.planted_faults", "benchmark.planted_faults_conv"])
-def test_benchmark_files_resolve_and_the_cell_rehearses(module):
-    """``rehearse`` drives the engine's build -> check -> warm -> measure ->
-    finish at the ``rehearsal`` sizes; the last two cases run the cell's WHOLE
-    reference check there with a fault planted: exit 0 = it was seen."""
-    cell = ["--workload", "lfm2_silo4_seq4096"]
-    args = {
-        "benchmark.selfcheck": [],
-        "benchmark.rehearse": [*cell, "--seconds", "1"],
-        "benchmark.planted_faults": [*cell, "--seed", "1", "--fault", "choose_without_bias", "--rehearsal"],
-        "benchmark.planted_faults_conv": [*cell, "--seed", "1", "--fault", "bf16_conv", "--rehearsal"],
-    }[module]
-    done = subprocess.run(
-        [sys.executable, "-m", module, *args], cwd=ROOT, capture_output=True, text=True, timeout=900,
-        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)},
-    )
-    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
-    if module.endswith("selfcheck"):
-        for name in ("cell lfm2_silo4_seq4096", "metric short_conv_ms", "metric gqa_flash_roofline"):
-            assert f"ok: {name}" in done.stdout
-    if module.endswith("rehearse"):
-        assert '"correct": true' in done.stdout and "rehearsal finished" in done.stdout
-    if module.endswith("planted_faults"):
-        assert "layer.routing_agreement'" in done.stdout.splitlines()[-1]
-    if module.endswith("planted_faults_conv"):
-        assert "'conv.scope_rel_l2'" in done.stdout.splitlines()[-1]
+def test_the_cell_rehearses():
+    """``benchmark.rehearse``: build, the cell's whole reference check, warm-up
+    and measure at the rehearsal's sizes (the one run of that check in tier-1;
+    its faults are shown at their layer comparisons, ``tests/test_lfm2_faults.py``)."""
+    rehearse("lfm2_silo4_seq4096", timeout=REHEARSE_S)

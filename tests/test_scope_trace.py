@@ -14,11 +14,12 @@ import jax.numpy as jnp
 import pytest
 
 from benchmark import scope_reduce, trace_reduce
-from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.management.profiling import DEVICE_SCOPES, get_dispatch_counts, reset_dispatch_counts, scope
-from p2pfl_tpu.models.transformer import TransformerConfig, tiny_transformer
-from p2pfl_tpu.parallel import SpmdFederation, SpmdLoraFederation
 from p2pfl_tpu.settings import Settings
+from tests.shared import (
+    conv_expert_federation, expert_federation, hybrid_federation, linear_expert_federation, lora_federation, mlp_federation,
+    window_expert_federation,
+)
 
 FIXTURES = Path(scope_reduce.__file__).resolve().parent / "fixtures"
 ROUND_SCOPES = ("grad", "optimizer", "fold")
@@ -29,84 +30,6 @@ LM_SCOPES = ("head",)  # every CausalLM's: the head and the loss
 WINDOW_SCOPES = ("flash_win_fwd", "flash_win_bwd", "attn_gate", "post_norm")  # a sliding layer's kernels, the output gate, the sandwich's second norms; same reader
 KDA_SCOPES = ("kda_conv", "kda_gate", "kda_fwd", "kda_bwd")  # a delta-rule layer's convolutions, gates and the op's two directions; same reader
 OP_NAME = re.compile(r'op_name="([^"]*)"')
-
-
-def _mlp_federation():
-    from p2pfl_tpu.models import mlp
-
-    data = FederatedDataset.synthetic_mnist(n_train=256, n_test=64)
-    return SpmdFederation.from_dataset(mlp(), data, n_nodes=4, batch_size=16, vote=False)
-
-
-def _lora_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, ffn_hidden=128,
-        lora_rank=4, lora_mlp=True, remat=True, scan_layers=True, remat_policy="mlp_qkv",
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")  # interpret mode off-TPU
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
-
-
-def _hybrid_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=1, ffn_hidden=128, rope_theta=None,
-        layer_pattern=("mamba", "attention"), ssm_dt_rank=8, lora_rank=4, lora_mlp=True, remat=True,
-        scan_layers=True, remat_policy="ssm",
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
-
-
-def _expert_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=3, n_heads=4, n_kv_heads=4, ffn_hidden=160, rope_theta=1e6,
-        layer_pattern=("mla_dense", "mla_experts", "mla_experts"), lora_rank=4, lora_mlp=True, remat=True,
-        scan_layers=True, norm_eps=1e-5, q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=12, qk_rope_dim=4,
-        v_head_dim=16, routed_experts=8, experts_per_token=2, expert_hidden=32, shared_experts=1,
-        routed_scale=1.8, expert_tile_m=8,
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
-
-
-def _conv_expert_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=6, n_heads=4, n_kv_heads=2, ffn_hidden=160, rope_theta=1e6,
-        leading_pattern=("conv_dense", "conv_dense"), layer_pattern=("attention_experts", "conv_experts"), qk_norm=True,
-        lora_rank=4, lora_mlp=True, remat=True, scan_layers=True, norm_eps=1e-5, routed_experts=8,
-        experts_per_token=2, expert_hidden=32, expert_tile_m=8,
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
-
-
-def _window_expert_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=6, n_heads=4, n_kv_heads=2, head_dim=32, ffn_hidden=160, rope_theta=1e4,
-        leading_pattern=("swa_dense", "swa_dense"), layer_pattern=("swa_experts", "full_experts"), qk_norm=True,
-        attn_window=32, attn_gate=True, post_norms=True, embed_scale=8.0, tie_head=False,
-        lora_rank=4, lora_mlp=True, remat=True, scan_layers=True, norm_eps=1e-5, routed_experts=8, experts_held=4,
-        first_expert=4, experts_per_token=2, expert_hidden=32, shared_experts=1, expert_tile_m=8,
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
-
-
-def _linear_expert_federation():
-    cfg = TransformerConfig(
-        vocab_size=256, dim=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, ffn_hidden=160, rope_theta=None,
-        layer_pattern=("full_experts", "kda_experts", "kda_experts", "kda_experts"), attn_gate=True, tie_head=False,
-        lora_rank=4, lora_mlp=True, remat=True, scan_layers=True, norm_eps=1e-5, routed_experts=8, experts_held=4,
-        experts_per_token=2, expert_hidden=32, shared_experts=1, expert_tile_m=8, conv_taps=4, kda_chunk=32,
-    )
-    model = tiny_transformer(seq_len=128, cfg=cfg, attn="flash")
-    data = FederatedDataset.synthetic_lm(vocab_size=256, seq_len=128, n_train=64, n_test=16)
-    return SpmdLoraFederation.from_dataset(model, data, n_nodes=4, batch_size=2, vote=False, node_chunk=2)
 
 
 def _lower_spmd_round(fed):
@@ -125,12 +48,12 @@ def _lower_spmd_round(fed):
 def compiled_op_names():
     """``{engine: the op_names of its compiled round}``, compiled once."""
     lowered = {
-        "spmd": _lower_spmd_round(_mlp_federation()), "spmd_lora": _lora_federation().lower_round(epochs=1),
-        "spmd_lora_hybrid": _hybrid_federation().lower_round(epochs=1),
-        "spmd_lora_moe": _expert_federation().lower_round(epochs=1),
-        "spmd_lora_conv_moe": _conv_expert_federation().lower_round(epochs=1),
-        "spmd_lora_window_moe": _window_expert_federation().lower_round(epochs=1),
-        "spmd_lora_linear_moe": _linear_expert_federation().lower_round(epochs=1),
+        "spmd": _lower_spmd_round(mlp_federation()), "spmd_lora": lora_federation().lower_round(epochs=1),
+        "spmd_lora_hybrid": hybrid_federation().lower_round(epochs=1),
+        "spmd_lora_moe": expert_federation().lower_round(epochs=1),
+        "spmd_lora_conv_moe": conv_expert_federation().lower_round(epochs=1),
+        "spmd_lora_window_moe": window_expert_federation().lower_round(epochs=1),
+        "spmd_lora_linear_moe": linear_expert_federation().lower_round(epochs=1),
     }
     return {engine: set(OP_NAME.findall(low.compile().as_text())) for engine, low in lowered.items()}
 
@@ -309,7 +232,7 @@ def _host_spans(tmp_path: Path, fed, rounds: int) -> list[str]:
 @pytest.mark.parametrize("engine,site", [("spmd", "spmd_round"), ("spmd_lora", "spmd_lora_round")])
 def test_round_phases_are_on_the_host_timeline_and_only_the_dispatch_counts(engine, site, tmp_path, monkeypatch):
     monkeypatch.setattr(Settings, "TELEMETRY_JAX_ANNOTATIONS", True)
-    fed = _mlp_federation() if engine == "spmd" else _lora_federation()
+    fed = mlp_federation() if engine == "spmd" else lora_federation()
     spans = _host_spans(tmp_path, fed, rounds=2)
     for name in ("p2pfl:round_perm", "p2pfl:round_put", f"p2pfl:{site}"):
         assert spans.count(name) == 2, (name, spans)

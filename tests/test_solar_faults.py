@@ -5,13 +5,11 @@ rule alone, a KDA and the GQA mixer alone, the first expert layer — run under
 each fault (the whole check, step and round included, is the chip's; the CPU
 rehearsal reads the same layer comparisons first)."""
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 from benchmark import checks as ck
-from benchmark import engines, run
 from benchmark.planted_faults_kda import FAULTS, planted
+from tests.shared import rehearsal_cell
 
 CELL = "solar_silo4_seq4096"
 # the comparison that names the fault's address
@@ -25,12 +23,7 @@ ADDRESS = {
 
 @pytest.fixture(scope="module")
 def built():
-    _, cell, cfg, traffic = run.resolve(run.load_json(run.ROOT / "BENCHMARK.json"), CELL, rehearsal=True)
-    job = run.Job(CELL, cell, cfg, traffic, 1, False, devices=jax.devices(), checks=ck.Checks(), say=lambda msg: None)
-    engine = engines.load(cell["engine"])
-    state = engine.build(job)
-    tokens = jnp.asarray(state["shards"][0]["x"][:1])
-    return job, engine, state, tokens
+    return rehearsal_cell(CELL)
 
 
 @pytest.mark.parametrize("fault", ["none", *FAULTS])

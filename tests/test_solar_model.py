@@ -14,54 +14,12 @@ import pytest
 
 from benchmark import checks as ck
 from benchmark.reference import solar_open2_lm
-from p2pfl_tpu.learning.lora import _lm_forward, merge_params, split_lora
-from p2pfl_tpu.models.transformer import LAYER_KINDS, CausalLM, KDAMixer, TransformerConfig, sown_by_layer, tiny_transformer
-from tests.test_lfm2_model import _draw
+from p2pfl_tpu.learning.lora import _lm_forward, merge_params
+from p2pfl_tpu.models.transformer import LAYER_KINDS, CausalLM, KDAMixer, sown_by_layer
+from tests.shared import SOLAR_PATTERN as PATTERN, SOLAR_REF as REF, SOLAR_SEQ as SEQ, solar  # noqa: F401 (the fixture)
+from tests.shared import solar_batch as batch, solar_config as config
 
 ROOT = Path(__file__).resolve().parent.parent
-SEQ = 40  # not a whole number of chunks: the op pads
-PATTERN = ("full_experts", "kda_experts", "kda_experts", "kda_experts")
-# Hugging Face's keys: one period GQA, KDA, KDA, KDA; 4 heads of 16 each kind on a residual of 64; experts 2..5 of 8
-REF = {
-    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "rms_norm_eps": 1e-5,
-    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4, "num_kv_heads": None},
-    "kda_allow_neg_eigval": True, "gqa_layers": [0], "num_hidden_layers": 4, "first_k_dense_replace": 0,
-    "moe_intermediate_size": 32, "n_routed_experts": 4, "num_experts_per_tok": 2, "n_shared_experts": 1,
-    "routed_scaling_factor": 1.0, "vocab_size": 256, "share": {"first_expert": 2, "router_experts": 8},
-}
-
-
-def config(**kw):
-    base = dict(
-        vocab_size=256, dim=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, ffn_hidden=160, rope_theta=None,
-        layer_pattern=PATTERN, attn_gate=True, tie_head=False, lora_rank=4, lora_alpha=8.0, lora_mlp=True,
-        dtype=jnp.float32, remat=True, scan_layers=True, norm_eps=1e-5, routed_experts=8, experts_held=4,
-        first_expert=2, experts_per_token=2, expert_hidden=32, shared_experts=1, routed_scale=1.0, expert_tile_m=8,
-        conv_taps=4, kda_chunk=16,
-    )
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-def _decay_up(params, seed):
-    """``A_log`` moved so that decays span weak to strong (a head's rate 1..16 at init)."""
-    key = jax.random.PRNGKey(seed)
-    return jax.tree_util.tree_map_with_path(
-        lambda p, a: a + jax.random.uniform(key, a.shape, a.dtype, -1.0, 2.0) if "A_log" in jax.tree_util.keystr(p) else a,
-        params,
-    )
-
-
-@pytest.fixture(scope="module")
-def solar():
-    model = tiny_transformer(seq_len=SEQ, seed=0, cfg=config())
-    model.params = _decay_up(_draw(model.params, 1), 2)
-    return (model, *split_lora(model.params))
-
-
-def batch(seed=0, n=2):
-    x = jax.random.randint(jax.random.PRNGKey(seed), (n, SEQ + 1), 0, 256)
-    return x[:, :-1], x[:, 1:]
 
 
 def _system(module, lora, base, x, y):

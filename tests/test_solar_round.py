@@ -16,7 +16,8 @@ from p2pfl_tpu.learning.dataset import FederatedDataset
 from p2pfl_tpu.learning.lora import _lm_forward
 from p2pfl_tpu.models.transformer import tiny_transformer
 from p2pfl_tpu.parallel import SpmdLoraFederation
-from tests.test_solar_model import ROOT, batch, config, solar  # noqa: F401 (the fixture)
+from tests.shared import ROOT, solar  # noqa: F401 (the fixture)
+from tests.shared import solar_batch as batch, solar_config as config, window_expert_federation
 
 
 def test_the_kernels_path_is_the_xla_path_in_the_model(solar, monkeypatch):
@@ -52,8 +53,6 @@ def test_the_window_expert_round_lowers_the_parents_text():
     norms, gate, untied head, a held share) is the parent commit's, text for
     text: the delta-rule layer kind, its configuration fields and the
     ``kda_stats`` collection change no other kind's program."""
-    from tests.test_scope_trace import _window_expert_federation
-
     want = json.loads((ROOT / "tests" / "fixtures" / "lm_round_window_parent.json").read_text())["window_experts"]
-    text = _window_expert_federation().lower_round(epochs=1).as_text()
+    text = window_expert_federation().lower_round(epochs=1).as_text()
     assert (hashlib.sha256(text.encode()).hexdigest(), len(text)) == (want["round_sha256"], want["chars"])

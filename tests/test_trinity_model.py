@@ -2,12 +2,12 @@
 sandwich norms, the embedding multiplier, an untied head, and an expert layer
 that holds a share of the experts — through ``CausalLM`` and
 ``SpmdLoraFederation``, against the plain reference
-``benchmark/reference/afmoe_lm.py`` on seeded weights."""
+``benchmark/reference/afmoe_lm.py`` on seeded weights. The cell's rehearsal —
+the one run of its whole reference check in tier-1 — is in
+``tests/test_trinity_cell.py``."""
 
 import dataclasses
 import json
-import subprocess
-import sys
 from pathlib import Path
 
 import jax
@@ -18,48 +18,18 @@ import pytest
 from benchmark import checks as ck
 from benchmark.reference import afmoe_lm
 from p2pfl_tpu.learning.dataset import FederatedDataset
-from p2pfl_tpu.learning.lora import _lm_forward, merge_params, split_lora
+from p2pfl_tpu.learning.lora import _lm_forward, merge_params
 from p2pfl_tpu.models.transformer import (
     LAYER_KINDS, Attention, Block, CausalLM, ExpertFFN, TransformerConfig, layer_runs, rope, sown_by_layer,
     tiny_transformer,
 )
 from p2pfl_tpu.ops.grouped_matmul import n_row_tiles
 from p2pfl_tpu.parallel import SpmdLoraFederation
-from tests.test_lfm2_model import _draw  # lora_b perturbed, a router bias that changes choices, norm scales off one
+from tests.shared import SOLAR_REF, conv_expert_federation, draw, perturbed_layer, same_gradients, solar_config
+from tests.shared import TRINITY_LEADING as LEADING, TRINITY_PATTERN as PATTERN, TRINITY_REF as REF
+from tests.shared import TRINITY_SEQ as SEQ, TRINITY_WINDOW as WINDOW, trinity_config as config, trinity_seeded as seeded
 
 ROOT = Path(__file__).resolve().parent.parent
-SEQ = 48
-WINDOW = 16
-LEADING = ("swa_dense", "swa_dense")
-PATTERN = ("swa_experts", "full_experts", "swa_experts", "swa_experts")
-# the reference reads Hugging Face's keys: two dense sliding layers, then two periods s f s s;
-# 4 heads of 32 on a residual of 64 (heads x head_dim = 2 x hidden, as published); experts 2..5 of 8 held
-REF = {
-    "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 32, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 32, "rms_norm_eps": 1e-5, "rope_theta": 1e4, "sliding_window": WINDOW,
-    "layer_types": ["sliding_attention"] * 2 + ["sliding_attention", "full_attention", "sliding_attention", "sliding_attention"] * 2,
-    "num_hidden_layers": 10, "num_dense_layers": 2, "num_experts": 4, "num_experts_per_tok": 2, "num_shared_experts": 1,
-    "route_scale": 2.826, "mup_enabled": True, "tie_word_embeddings": False, "vocab_size": 256,
-    "share": {"first_expert": 2, "router_experts": 8},
-}
-
-
-def config(**kw):
-    base = dict(
-        vocab_size=256, dim=64, n_layers=10, n_heads=4, n_kv_heads=2, head_dim=32, ffn_hidden=160, rope_theta=1e4,
-        leading_pattern=LEADING, layer_pattern=PATTERN, qk_norm=True, attn_window=WINDOW, attn_gate=True, post_norms=True,
-        embed_scale=8.0, tie_head=False, lora_rank=4, lora_alpha=8.0, lora_mlp=True, dtype=jnp.float32, remat=True,
-        scan_layers=True, remat_policy=None, norm_eps=1e-5, routed_experts=8, experts_held=4, first_expert=2,
-        experts_per_token=2, expert_hidden=32, shared_experts=1, routed_scale=2.826, expert_tile_m=8,
-    )
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-def seeded(cfg, seed=0, attn="dense"):
-    model = tiny_transformer(seq_len=SEQ, seed=seed, cfg=cfg, attn=attn)
-    model.params = _draw(model.params, seed + 1)
-    return (model, *split_lora(model.params))
 
 
 def batch(seed=0, n=2):
@@ -94,18 +64,6 @@ def trinity():
     return seeded(config())
 
 
-def _perturbed_layer(layer, *args, seed=1):
-    params = _draw(layer.init(jax.random.PRNGKey(seed), *args)["params"], seed + 1)
-    return split_lora(params)
-
-
-def _same_gradients(grads, want, tol=2e-4):
-    assert jax.tree.structure(grads) == jax.tree.structure(want)
-    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
-        assert float(jnp.max(jnp.abs(ref))) > 0, jax.tree_util.keystr(path)  # every adapter is reached
-        assert ck.rel_l2(got, ref) < tol, jax.tree_util.keystr(path)
-
-
 # ---- (a) the attention layer: window and rotation by KIND, the gate ---------------
 
 
@@ -118,7 +76,7 @@ def test_sliding_and_full_attention_forward_and_adapter_gradients_match_the_refe
     attn_fn = resolve_attention("flash", config=FlashConfig(16, 16), window_config=FlashConfig(16, 8)) if attn == "flash" else None
     layer = Attention(cfg, attn_fn, mixer)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    lora, base = _perturbed_layer(layer, h)
+    lora, base = perturbed_layer(layer, h)
     assert base["wq"]["kernel"].shape == base["wg"]["kernel"].shape == (64, 128) and base["wo"]["kernel"].shape == (128, 64)
     assert base["wk"]["kernel"].shape == (64, 64) and base["q_norm"]["scale"].shape == (32,)
     assert sorted(lora) == ["wg", "wk", "wo", "wq", "wv"]  # the gate is a fifth adapted projection
@@ -130,7 +88,7 @@ def test_sliding_and_full_attention_forward_and_adapter_gradients_match_the_refe
         want, want_grads = jax.value_and_grad(theirs)(lora)
         got, grads = jax.value_and_grad(ours)(lora)
     assert float(got) == pytest.approx(float(want), rel=1e-4)
-    _same_gradients(grads, want_grads)
+    same_gradients(grads, want_grads)
 
 
 def test_a_sliding_layer_sees_its_window_and_a_full_layer_every_earlier_key():
@@ -193,7 +151,7 @@ def test_each_switch_alone_against_a_hand_computation():
         np.testing.assert_allclose(Attention(cfg, None, "swa").apply({"params": p}, h), attention_by_hand(p, h, True, False), rtol=1e-4, atol=1e-5)
         # the sandwich: x + N(attn(N(x))), then x + N(ffn(N(x)))
         cfg = TransformerConfig(**plain, post_norms=True)
-        p = _draw(Block(cfg).init(jax.random.PRNGKey(1), h)["params"], 5)
+        p = draw(Block(cfg).init(jax.random.PRNGKey(1), h)["params"], 5)
         assert sorted(p) == ["attn", "attn_norm", "attn_post_norm", "mlp", "mlp_norm", "mlp_post_norm"]
         a = h + norm(attention_by_hand(p["attn"], norm(h, p["attn_norm"]["scale"]), True, False), p["attn_post_norm"]["scale"])
         u = norm(a, p["mlp_norm"]["scale"])
@@ -204,7 +162,7 @@ def test_each_switch_alone_against_a_hand_computation():
         tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 12), 0, 64)
         for scale, tied in ((5.0, True), (1.0, False), (5.0, False)):
             cfg = TransformerConfig(**dict(plain, n_layers=0), layer_pattern=("attention",), embed_scale=scale, tie_head=tied)
-            p = _draw(CausalLM(cfg).init(jax.random.PRNGKey(1), tokens)["params"], 7)
+            p = draw(CausalLM(cfg).init(jax.random.PRNGKey(1), tokens)["params"], 7)
             assert ("lm_head" in p) == (not tied)
             head = p["embed"] if tied else p["lm_head"]
             want = norm(scale * p["embed"][tokens], p["final_norm"]["scale"]) @ head.T
@@ -247,9 +205,12 @@ def _system(module, lora, base, x, y):
     return loss, grads, chosen, stats
 
 
+SLOW = pytest.mark.slow  # each switch's both values are held by the two fast cases: (unrolled, no remat, dense), (scanned, remat, flash)
+
+
 @pytest.mark.parametrize("layers,remat,attn", [
-    ("scanned", True, "dense"), ("scanned", False, "dense"), ("unrolled", True, "dense"), ("unrolled", False, "dense"),
-    ("scanned", True, "flash"),
+    pytest.param("scanned", True, "dense", marks=SLOW), pytest.param("scanned", False, "dense", marks=SLOW),
+    pytest.param("unrolled", True, "dense", marks=SLOW), ("unrolled", False, "dense"), ("scanned", True, "flash"),
 ])
 def test_loss_and_every_adapter_gradient_match_the_reference_under_the_same_share(trinity, layers, remat, attn):
     model, lora, base = trinity
@@ -273,7 +234,7 @@ def test_loss_and_every_adapter_gradient_match_the_reference_under_the_same_shar
         want = unrolled_tree(want)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     np.testing.assert_array_equal(np.sort(np.asarray(chosen), -1), np.sort(np.asarray(own), -1))  # float32 both: one choice
-    _same_gradients(grads, want)
+    same_gradients(grads, want)
     names = {jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(lora)}
     assert not any(word in n for n in names for word in ("experts_w", "router", "lm_head", "embed", "norm"))  # frozen base leaves
     assert any("'wg'" in n for n in names) and any("'shared'" in n for n in names)
@@ -322,10 +283,8 @@ def test_the_short_convolution_expert_round_lowers_the_parents_text():
     multiplier, the untied head and the held share all at their defaults."""
     import hashlib
 
-    from tests.test_scope_trace import _conv_expert_federation
-
     want = json.loads((ROOT / "tests" / "fixtures" / "lm_round_conv_parent.json").read_text())["conv_experts"]
-    text = _conv_expert_federation().lower_round(epochs=1).as_text()
+    text = conv_expert_federation().lower_round(epochs=1).as_text()
     assert (hashlib.sha256(text.encode()).hexdigest(), len(text)) == (want["round_sha256"], want["chars"])
 
 
@@ -338,9 +297,8 @@ def _share_case(model: str):
     if model == "trinity":
         return config, REF, "num_experts", afmoe_lm.experts
     from benchmark.reference import solar_open2_lm
-    from tests import test_solar_model
 
-    return test_solar_model.config, test_solar_model.REF, "n_routed_experts", solar_open2_lm.experts
+    return solar_config, SOLAR_REF, "n_routed_experts", solar_open2_lm.experts
 
 
 @pytest.mark.parametrize("model", ["trinity", "solar"])
@@ -354,7 +312,7 @@ def test_the_shares_routed_parts_add_up_to_the_uncut_layer(model):
     cfg = make(experts_held=8, first_expert=0)
     uncut = ExpertFFN(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    params = _draw(uncut.init(jax.random.PRNGKey(1), h)["params"], 3)
+    params = draw(uncut.init(jax.random.PRNGKey(1), h)["params"], 3)
     assert params["experts_w13"].shape == (8, 64, 64)
     whole = dict(ref_cfg, **{count: 8}, share={"first_expert": 0, "router_experts": 8})
     with jax.default_matmul_precision("highest"):
@@ -419,7 +377,7 @@ def test_a_held_share_through_both_grouped_matmul_paths_and_its_gradient(impl):
     cfg = config(expert_impl=impl)
     layer = ExpertFFN(cfg)
     h = jax.random.normal(jax.random.PRNGKey(0), (1, SEQ, cfg.dim), jnp.float32)
-    lora, base = _perturbed_layer(layer, h)
+    lora, base = perturbed_layer(layer, h)
     probe = jax.random.normal(jax.random.PRNGKey(3), h.shape)
     ours = lambda lo, h_: jnp.sum(layer.apply({"params": merge_params(base, lo)}, h_) * probe)  # noqa: E731
     theirs = lambda lo, h_: jnp.sum(afmoe_lm.experts(h_[0], merge_params(base, lo), REF, 2.0)[0] * probe[0])  # noqa: E731
@@ -427,11 +385,11 @@ def test_a_held_share_through_both_grouped_matmul_paths_and_its_gradient(impl):
         want, want_grads = jax.value_and_grad(theirs, (0, 1))(lora, h)
         got, grads = jax.value_and_grad(ours, (0, 1))(lora, h)
     assert float(got) == pytest.approx(float(want), rel=1e-4)
-    _same_gradients(grads[0], want_grads[0])
+    same_gradients(grads[0], want_grads[0])
     assert ck.rel_l2(grads[1], want_grads[1]) < 2e-4  # the input's cotangent: held rows' only
 
 
-# ---- (d) the federation and the benchmark's files ---------------------------------
+# ---- (d) the federation and the benchmark's files (its scripts: tests/test_trinity_cell.py)
 
 
 def test_one_federated_round_carries_both_counters_and_lm_head_is_in_no_payload(trinity):
@@ -473,30 +431,3 @@ def test_the_configuration_file_states_the_published_model_and_the_share():
     assert "trinity_silo4_seq8192" in {w["name"] for w in bench["workloads"]} and sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     traffic = json.loads((ROOT / "benchmark" / "traffic" / "silo4_seq8192.json").read_text())
     assert (traffic["n_nodes"], traffic["local_steps"], traffic["batch_size"], traffic["seq_len"], traffic["participation"]) == (4, 2, 1, 8192, 1.0)
-
-
-@pytest.mark.parametrize("module", ["benchmark.selfcheck", "benchmark.rehearse", "benchmark.planted_faults_window", "benchmark.flops_window_moe"])
-def test_benchmark_files_resolve_and_the_cell_rehearses(module):
-    """``rehearse`` drives the engine's build -> check -> warm -> measure ->
-    finish at the ``rehearsal`` sizes; ``planted_faults_window`` runs the cell's
-    WHOLE reference check there with a fault planted: exit 0 = it was seen."""
-    cell = ["--workload", "trinity_silo4_seq8192"]
-    args = {
-        "benchmark.selfcheck": [],
-        "benchmark.rehearse": [*cell, "--seconds", "1"],
-        "benchmark.planted_faults_window": [*cell, "--seed", "1", "--fault", "held_normalised", "--rehearsal"],
-        "benchmark.flops_window_moe": [],
-    }[module]
-    done = subprocess.run(
-        [sys.executable, "-m", module, *args], cwd=ROOT, capture_output=True, text=True, timeout=900,
-        env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "PYTHONPATH": str(ROOT)},
-    )
-    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
-    if module.endswith("selfcheck"):
-        for name in ("cell trinity_silo4_seq8192", "metric flash_win_roofline", "metric moe_held_share", "metric post_norm_ms"):
-            assert f"ok: {name}" in done.stdout
-    if module.endswith("rehearse"):
-        assert '"correct": true' in done.stdout and "rehearsal finished" in done.stdout
-        assert "held share of the step's assignments" in done.stdout
-    if module.endswith("planted_faults_window"):
-        assert "layer.worst_agreeing_token_rel'" in done.stdout.splitlines()[-1]
